@@ -1,8 +1,2 @@
-"""ButterFly BFS reproduction package.
-
-Importing ``repro`` installs the JAX version-compat shims (see
-:mod:`repro.compat`) so every submodule can target the modern JAX API
-regardless of the pinned toolchain.
-"""
-
-from repro import compat  # noqa: F401  (side effect: installs jax shims)
+"""ButterFly BFS reproduction package (targets the installed JAX; see
+``requirements.txt``)."""

@@ -44,19 +44,21 @@ INF = jnp.iinfo(jnp.int32).max
 
 
 def bfs_reference(g: Graph, root: int) -> np.ndarray:
-    """Sequential frontier BFS — the ground truth for every test."""
+    """Level-synchronous frontier BFS over the host CSR — the ground truth
+    for every test.  Each level gathers the out-edges of the whole frontier
+    at once (vectorised over the frontier, no per-vertex Python loop)."""
     d = np.full(g.n, np.iinfo(np.int32).max, dtype=np.int64)
     d[root] = 0
-    frontier = [root]
+    frontier = np.array([root])
     level = 0
-    while frontier:
-        nxt = []
-        for v in frontier:
-            for u in g.neighbors(v):
-                if d[u] > level + 1:
-                    d[u] = level + 1
-                    nxt.append(u)
-        frontier = nxt
+    while frontier.size:
+        starts = g.row_offsets[frontier]
+        counts = g.row_offsets[frontier + 1] - starts
+        before = np.cumsum(counts) - counts
+        edge = np.repeat(starts - before, counts) + np.arange(counts.sum())
+        nbrs = g.dst[edge]
+        frontier = np.unique(nbrs[d[nbrs] > level + 1])
+        d[frontier] = level + 1
         level += 1
     return d
 
@@ -82,7 +84,9 @@ class BFSConfig:
     alpha: float = 15.0  # Beamer push->pull threshold
     beta: float = 18.0  # Beamer pull->push threshold
     max_levels: Optional[int] = None
-    use_pallas: bool = False  # frontier kernels via Pallas (TPU) vs XLA ops
+    # frontier kernels via Pallas (interpret mode only: the TPU compiler
+    # refuses them, see kernels.ops.PALLAS_REFUSED) vs XLA ops
+    use_pallas: bool = False
     # --- sparse/adaptive sync knobs (DESIGN.md §12) -----------------------
     # max (word_index, word) pairs shipped in the first sparse round;
     # 0 -> auto-size to n_words // 64 (>= 64) at build time.
@@ -139,7 +143,7 @@ def _sync_frontier(words: jax.Array, cfg: BFSConfig) -> jax.Array:
 
 
 def _expand_push(arrays, frontier_words, n_words, use_pallas, meta=None, *,
-                 lanes=False):
+                 lanes=False, interpret=False):
     """Top-down: scatter frontier bits along owned out-edges (paper Alg. 2
     phase 1).  Returns the node's 'global queue' bitmap.
 
@@ -154,7 +158,8 @@ def _expand_push(arrays, frontier_words, n_words, use_pallas, meta=None, *,
                                       "single-source (vertex-packed) only")
         from repro.kernels import ops as kops
 
-        return kops.expand_push_pallas(frontier_words, arrays, meta, n_words)
+        return kops.expand_push_pallas(frontier_words, arrays, meta, n_words,
+                                       interpret=interpret)
     src, dst = arrays["edge_src"], arrays["edge_dst"]
     mask = jnp.arange(src.shape[0], dtype=jnp.int32) < arrays["edge_count"]
     if lanes:
@@ -165,7 +170,7 @@ def _expand_push(arrays, frontier_words, n_words, use_pallas, meta=None, *,
 
 
 def _expand_pull(arrays, frontier_words, visited_words, n_words, use_pallas,
-                 meta=None, *, lanes=False):
+                 meta=None, *, lanes=False, interpret=False):
     """Bottom-up: every unvisited owned vertex probes its in-edges for a
     parent in the frontier (Beamer; paper Sec. 3 'Parallelization Schemes').
     ``lanes=True`` runs the probe per search lane: a vertex can be settled
@@ -176,7 +181,8 @@ def _expand_pull(arrays, frontier_words, visited_words, n_words, use_pallas,
                                       "single-source (vertex-packed) only")
         from repro.kernels import ops as kops
 
-        return kops.expand_pull_pallas(frontier_words, visited_words, arrays, meta, n_words)
+        return kops.expand_pull_pallas(frontier_words, visited_words, arrays,
+                                       meta, n_words, interpret=interpret)
     src, dst = arrays["in_src"], arrays["in_dst"]
     mask = jnp.arange(src.shape[0], dtype=jnp.int32) < arrays["in_count"]
     if lanes:
@@ -192,6 +198,7 @@ def _expand_pull(arrays, frontier_words, visited_words, n_words, use_pallas,
 def build_bfs_fn(
     pg: PartitionedGraph, mesh: jax.sharding.Mesh, cfg: BFSConfig, layout=None,
     *, trace: bool = False, trace_levels: Optional[int] = None,
+    interpret: bool = False,
 ):
     """Compile-ready distributed BFS.
 
@@ -207,12 +214,23 @@ def build_bfs_fn(
     :mod:`repro.core.flightrec`).  ``trace=False`` stages the EXACT
     uninstrumented program — all recording is Python-gated, so the jaxpr
     (hence the compiled HLO) is byte-identical to the pre-§18 seed.
+
+    ``cfg.use_pallas`` runs the Pallas frontier kernels, which only the
+    Pallas interpreter executes: pass ``interpret=True``.  On a mesh of TPU
+    devices it raises, naming the lowerings the chip's compiler refuses.
     """
     n_words = pg.n_words
     vmax = pg.vmax
     wmax = pg.wmax
     max_levels = cfg.max_levels if cfg.max_levels is not None else pg.n
     spec = P(cfg.axes if len(cfg.axes) > 1 else cfg.axes[0])
+    if cfg.use_pallas and mesh.devices.flat[0].platform == "tpu":
+        from repro.kernels import ops as kops
+
+        raise NotImplementedError(
+            "use_pallas=True does not compile for TPU: " + kops.PALLAS_REFUSED
+            + ". Use the XLA frontier path (use_pallas=False)."
+        )
     if cfg.use_pallas and layout is None:
         raise ValueError("use_pallas=True requires a BFSPallasLayout")
     meta = layout.meta if layout is not None else None
@@ -259,12 +277,14 @@ def build_bfs_fn(
             # -- Phase 1: traversal -------------------------------------
             def do_push(_):
                 return _expand_push(
-                    arrays, frontier_words, n_words, cfg.use_pallas, meta
+                    arrays, frontier_words, n_words, cfg.use_pallas, meta,
+                    interpret=interpret,
                 )
 
             def do_pull(_):
                 return _expand_pull(
-                    arrays, frontier_words, visited, n_words, cfg.use_pallas, meta
+                    arrays, frontier_words, visited, n_words, cfg.use_pallas,
+                    meta, interpret=interpret,
                 )
 
             if cfg.mode == "top_down":
@@ -398,15 +418,18 @@ def distributed_bfs(
     mesh: jax.sharding.Mesh,
     root: int,
     cfg: BFSConfig = BFSConfig(),
+    *,
+    interpret: bool = False,
 ) -> Tuple[np.ndarray, int, float]:
-    """End-to-end helper: place arrays, run, assemble global distances."""
+    """End-to-end helper: place arrays, run, assemble global distances.
+    ``interpret`` is :func:`build_bfs_fn`'s (Pallas path only)."""
     layout = None
     if cfg.use_pallas:
         from repro.kernels import blocks
 
         layout = blocks.build_bfs_layout(pg)
     arrays = place_arrays(pg, mesh, cfg.axes, layout)
-    fn = build_bfs_fn(pg, mesh, cfg, layout)
+    fn = build_bfs_fn(pg, mesh, cfg, layout, interpret=interpret)
     d_owned, levels, scanned = fn(arrays, jnp.int32(root))
     d_owned = np.asarray(d_owned)
     levels = int(np.max(levels))

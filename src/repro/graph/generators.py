@@ -16,6 +16,9 @@ benchmark convention for weighted SSSP inputs; DESIGN.md §14).
 
 from __future__ import annotations
 
+import time
+from typing import Dict, Optional
+
 import numpy as np
 
 from repro.graph import csr
@@ -59,26 +62,40 @@ def kronecker(
     *,
     symmetrize: bool = True,
     max_weight: int = 0,
+    timings: Optional[Dict[str, float]] = None,
 ) -> csr.Graph:
-    """RMAT/Kronecker generator, vectorized over all edges at once."""
+    """RMAT/Kronecker generator, vectorized over all edges at once.
+
+    ``timings``, when given, receives the host seconds of each ETL step:
+    ``generate`` (edge draw), then :func:`csr.from_edges`' ``from_edges``
+    (symmetrize/dedup/CSR) and ``validate``."""
+    t0 = time.perf_counter()
     n = 1 << scale
     m = n * edge_factor
     rng = np.random.default_rng(seed)
-    src = np.zeros(m, dtype=np.int64)
-    dst = np.zeros(m, dtype=np.int64)
+    # the draws of one rng.random(m) per bit, into reused buffers and
+    # uint32 accumulators (vertex ids are int32 downstream anyway): no
+    # per-bit int64 temporaries, the host ETL's largest step at scale 21+
+    src = np.zeros(m, dtype=np.uint32)
+    dst = np.zeros(m, dtype=np.uint32)
+    r = np.empty(m)
+    bits = np.empty(m, dtype=np.uint32)
     for bit in range(scale):
-        r = rng.random(m)
-        src_bit = r >= (_A + _B)
-        dst_bit = ((r >= _A) & (r < _A + _B)) | (r >= (_A + _B + _C))
-        src |= src_bit.astype(np.int64) << bit
-        dst |= dst_bit.astype(np.int64) << bit
+        rng.random(out=r)
+        w = np.uint32(1 << bit)
+        np.multiply(r >= (_A + _B), w, out=bits)
+        src |= bits
+        np.multiply(((r >= _A) & (r < _A + _B)) | (r >= (_A + _B + _C)), w,
+                    out=bits)
+        dst |= bits
     # Graph500 permutes vertex labels to break degree-locality correlation.
     perm = rng.permutation(n)
     src, dst = perm[src], perm[dst]
-    return csr.from_edges(
-        src, dst, n, symmetrize=symmetrize,
-        weights=_maybe_weights(src, dst, max_weight, seed),
-    )
+    weights = _maybe_weights(src, dst, max_weight, seed)
+    if timings is not None:
+        timings["generate"] = time.perf_counter() - t0
+    return csr.from_edges(src, dst, n, symmetrize=symmetrize,
+                          weights=weights, timings=timings)
 
 
 def uniform_random(

@@ -26,7 +26,7 @@ def _kernel(stack_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def bitmap_or_reduce(
-    stack: jax.Array, *, block: int = BLOCK_WORDS, interpret: bool = True
+    stack: jax.Array, *, block: int = BLOCK_WORDS, interpret: bool
 ) -> jax.Array:
     """OR-reduce ``uint32[K, W]`` -> ``uint32[W]``; W must divide by block."""
     k, w = stack.shape

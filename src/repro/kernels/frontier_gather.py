@@ -37,7 +37,7 @@ def frontier_gather(
     src_local: jax.Array,
     *,
     ww: int,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Gather frontier bits for edges blocked by source window.
 
@@ -76,7 +76,7 @@ def _full_kernel(words_ref, src_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def frontier_gather_full(
-    words: jax.Array, src: jax.Array, *, interpret: bool = True
+    words: jax.Array, src: jax.Array, *, interpret: bool
 ) -> jax.Array:
     """Gather bits at arbitrary vertex ids; whole bitmap pinned in VMEM.
 

@@ -68,7 +68,7 @@ def frontier_scatter(
     *,
     n_windows: int,
     ww: int,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Scatter-OR active bits into a packed bitmap.
 

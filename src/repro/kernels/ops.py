@@ -1,9 +1,10 @@
 """jit'd wrappers over the Pallas kernels + the BFS-facing expansion ops.
 
-On this CPU container every kernel runs with ``interpret=True`` (Pallas
-executes the kernel body in Python) — identical semantics, same BlockSpec
-tiling, no TPU required.  On a real TPU backend ``interpret`` flips off
-automatically.
+``interpret`` is an explicit argument of every wrapper: ``True`` runs the
+kernel body in the Pallas interpreter (same semantics, same BlockSpec
+tiling, any backend).  Nothing picks it from the backend.  The TPU
+compiler refuses the gather and scatter kernels as written
+(``PALLAS_REFUSED``), so only tests run them, interpreted.
 """
 
 from __future__ import annotations
@@ -19,13 +20,19 @@ from repro.kernels import frontier_gather as _fg
 from repro.kernels import frontier_scatter as _fs
 
 
-def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+# What the TPU compiler says about the Pallas frontier kernels (compiled for
+# a described v5e).  Until a rewrite lands they run under the interpreter.
+PALLAS_REFUSED = (
+    "frontier_gather's ww-word window block is not a multiple of the "
+    "128-word tile; frontier_gather_full and frontier_scatter use (1, eb) "
+    "edge blocks (the last two block dims must divide by (8, 128) or equal "
+    "the array's); with (8, eb) blocks the gathers' vector-indexed load "
+    "words_ref[s >> 5] fails ('Cannot do int indexing on TPU')"
+)
 
 
-def bitmap_or_reduce(stack: jax.Array, *, block: int = 1024, interpret=None) -> jax.Array:
-    if interpret is None:
-        interpret = default_interpret()
+def bitmap_or_reduce(stack: jax.Array, *, block: int = 1024,
+                     interpret: bool) -> jax.Array:
     w = stack.shape[-1]
     block = min(block, w)
     while w % block:
@@ -33,21 +40,16 @@ def bitmap_or_reduce(stack: jax.Array, *, block: int = 1024, interpret=None) -> 
     return _bm.bitmap_or_reduce(stack, block=max(block, 1), interpret=interpret)
 
 
-def frontier_gather(words, block_ws, src_local, *, ww, interpret=None):
-    if interpret is None:
-        interpret = default_interpret()
+def frontier_gather(words, block_ws, src_local, *, ww, interpret: bool):
     return _fg.frontier_gather(words, block_ws, src_local, ww=ww, interpret=interpret)
 
 
-def frontier_gather_full(words, src, *, interpret=None):
-    if interpret is None:
-        interpret = default_interpret()
+def frontier_gather_full(words, src, *, interpret: bool):
     return _fg.frontier_gather_full(words, src, interpret=interpret)
 
 
-def frontier_scatter(active, block_win, block_first, dst_local, *, n_windows, ww, interpret=None):
-    if interpret is None:
-        interpret = default_interpret()
+def frontier_scatter(active, block_win, block_first, dst_local, *, n_windows,
+                     ww, interpret: bool):
     return _fs.frontier_scatter(
         active,
         block_win,
@@ -74,12 +76,14 @@ def _pad_words(words: jax.Array, words_pad: int) -> jax.Array:
 
 
 def expand_push_pallas(
-    frontier_words: jax.Array, arrays: Dict, meta: Dict, n_words: int
+    frontier_words: jax.Array, arrays: Dict, meta: Dict, n_words: int, *,
+    interpret: bool,
 ) -> jax.Array:
     """Top-down expansion via gather + scatter kernels."""
     if meta["gather_full"]:
         active = frontier_gather_full(
-            _pad_words(frontier_words, meta["gather_words_pad"]), arrays["tdg_src"]
+            _pad_words(frontier_words, meta["gather_words_pad"]),
+            arrays["tdg_src"], interpret=interpret,
         )
     else:
         active = frontier_gather(
@@ -87,6 +91,7 @@ def expand_push_pallas(
             arrays["tdg_ws"],
             arrays["tdg_src"],
             ww=meta["gather_ww"],
+            interpret=interpret,
         )
     act_blocked = active.reshape(-1)[arrays["tds_perm"]]
     out = frontier_scatter(
@@ -96,6 +101,7 @@ def expand_push_pallas(
         arrays["tds_dst"],
         n_windows=meta["scatter_windows"],
         ww=meta["scatter_ww"],
+        interpret=interpret,
     )
     return out[:n_words]
 
@@ -106,15 +112,19 @@ def expand_pull_pallas(
     arrays: Dict,
     meta: Dict,
     n_words: int,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     """Bottom-up expansion: parent probe (full gather on unsorted in_src) +
     unvisited mask (windowed gather on sorted in_dst) + windowed scatter."""
     parent = frontier_gather_full(
-        _pad_words(frontier_words, meta["gather_words_pad"]), arrays["in_src_blocks"]
+        _pad_words(frontier_words, meta["gather_words_pad"]),
+        arrays["in_src_blocks"], interpret=interpret,
     )
     if meta["pull_gather_full"]:
         vis = frontier_gather_full(
-            _pad_words(visited_words, meta["pull_gather_words_pad"]), arrays["pug_dst"]
+            _pad_words(visited_words, meta["pull_gather_words_pad"]),
+            arrays["pug_dst"], interpret=interpret,
         )
     else:
         vis = frontier_gather(
@@ -122,6 +132,7 @@ def expand_pull_pallas(
             arrays["pug_ws"],
             arrays["pug_dst"],
             ww=meta["pull_gather_ww"],
+            interpret=interpret,
         )
     # both are in-edge flat order; lengths may differ by block padding, and
     # every real edge index < count <= min length.
@@ -135,5 +146,6 @@ def expand_pull_pallas(
         arrays["pus_dst"],
         n_windows=meta["scatter_windows"],
         ww=meta["scatter_ww"],
+        interpret=interpret,
     )
     return out[:n_words]

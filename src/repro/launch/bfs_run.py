@@ -1,10 +1,11 @@
 """Distributed ButterFly BFS launcher (the paper's workload, end to end).
 
-``python -m repro.launch.bfs_run --scale 16 --devices 8 --fanout 4``
+``python -m repro.launch.bfs_run --scale 16 --fanout 4``
 
-Generates a Kronecker graph, 1D-partitions it over simulated devices,
-runs BFS from random roots with the paper's benchmarking protocol
-(100 roots, trim fastest/slowest 25%) and reports GTEP/s.
+Generates a Kronecker graph, 1D-partitions it over the devices JAX finds
+(``--devices`` takes fewer), runs BFS from random roots with the paper's
+benchmarking protocol (100 roots, trim fastest/slowest 25%) and reports
+GTEP/s.
 
 ``--num-sources B`` (B > 1) switches to the bit-parallel multi-source
 engine (DESIGN.md §13): the ``--roots`` queries are packed into B-lane
@@ -33,7 +34,6 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
-import os
 import sys
 
 STATS_SCHEMA = "bfs_run_stats/v1"
@@ -66,7 +66,9 @@ def main(argv=None) -> int:
     ap.add_argument("--edge-factor", type=int, default=8)
     ap.add_argument("--graph", default="kronecker",
                     choices=["kronecker", "urand", "torus"])
-    ap.add_argument("--devices", type=int, default=8)
+    ap.add_argument("--devices", type=int, default=None,
+                    help="devices to shard over (default: every device "
+                         "JAX finds)")
     ap.add_argument("--fanout", type=int, default=4)
     ap.add_argument("--sync", default="butterfly",
                     choices=["butterfly", "sparse", "adaptive", "rabenseifner",
@@ -97,7 +99,6 @@ def main(argv=None) -> int:
                     help="BFS lanes per wave: 1 = classic single-source; "
                          ">1 packs the root queries into bit-parallel "
                          "multi-source waves (analytics.msbfs)")
-    ap.add_argument("--pallas", action="store_true")
     ap.add_argument("--updates", default=None, metavar="FILE",
                     help="replay a recorded JSONL edge-update stream "
                          "(serve_graph --record-updates) through the §16 "
@@ -120,17 +121,10 @@ def main(argv=None) -> int:
                          "time×bytes table; FILE (optional) also receives "
                          "the profile as JSON")
     args = ap.parse_args(argv)
-    if args.trace and args.pallas:
-        ap.error("--trace instruments the XLA path; drop --pallas")
-    if args.profile and args.pallas:
-        ap.error("--profile times the XLA path; drop --pallas")
     if args.profile and args.algo != "bfs":
         ap.error("--profile profiles the single-source BFS program; "
                  "use --algo bfs")
 
-    os.environ.setdefault(
-        "XLA_FLAGS", f"--xla_force_host_platform_device_count={args.devices}"
-    )
     import time
 
     import jax
@@ -138,24 +132,34 @@ def main(argv=None) -> int:
 
     from repro.core import bfs
     from repro.graph import csr, generators, partition
+    from repro.launch import devices as devices_mod
 
+    try:
+        args.devices = devices_mod.resolve_device_count(args.devices)
+    except ValueError as e:
+        ap.error(str(e))
+    on = devices_mod.device_label(args.devices)
     max_weight = args.max_weight
     if args.algo == "sssp" and not max_weight:
         max_weight = 64
-    if args.graph == "kronecker":
-        g = generators.kronecker(args.scale, args.edge_factor, seed=args.seed,
-                                 max_weight=max_weight)
-    elif args.graph == "urand":
-        g = generators.uniform_random(
-            1 << args.scale, (1 << args.scale) * args.edge_factor,
-            seed=args.seed, max_weight=max_weight,
-        )
-    else:
-        g = generators.torus_2d(1 << (args.scale // 2), max_weight=max_weight,
-                                seed=args.seed)
+
+    def make_graph(timings):
+        if args.graph == "kronecker":
+            return generators.kronecker(
+                args.scale, args.edge_factor, seed=args.seed,
+                max_weight=max_weight, timings=timings)
+        if args.graph == "urand":
+            return generators.uniform_random(
+                1 << args.scale, (1 << args.scale) * args.edge_factor,
+                seed=args.seed, max_weight=max_weight,
+            )
+        return generators.torus_2d(1 << (args.scale // 2),
+                                   max_weight=max_weight, seed=args.seed)
+
+    g, pg, etl_line = devices_mod.partitioned_graph(make_graph, args.devices)
     print(f"graph: n={g.n:,} m={g.n_edges:,} (directed, symmetrized"
           f"{', weighted' if g.weighted else ''})")
-    pg = partition.partition_1d(g, args.devices)
+    print(etl_line)
     if args.updates:
         from repro.dynamic import delta as delta_mod
 
@@ -186,7 +190,7 @@ def main(argv=None) -> int:
                          axis_types=(jax.sharding.AxisType.Auto,))
     cfg = bfs.BFSConfig(
         axes=("data",), fanout=args.fanout, sync=args.sync, mode=args.mode,
-        use_pallas=args.pallas, sparse_capacity=args.sparse_capacity,
+        sparse_capacity=args.sparse_capacity,
         density_threshold=args.density_threshold,
     )
     rng = np.random.default_rng(args.seed)
@@ -202,8 +206,7 @@ def main(argv=None) -> int:
                  "weighted": bool(g.weighted)}
     config_doc = {"sync": args.sync, "mode": args.mode,
                   "fanout": args.fanout, "lanes": args.num_sources,
-                  "delta": args.delta, "max_weight": max_weight,
-                  "use_pallas": bool(args.pallas)}
+                  "delta": args.delta, "max_weight": max_weight}
 
     def emit_profile(report: dict) -> None:
         """Print the §20 profile table (+ cached-program reconciliation)
@@ -267,7 +270,7 @@ def main(argv=None) -> int:
         print(
             f"SSSP {scfg.sync} fanout={args.fanout} delta={args.delta} "
             f"devices={args.devices}: time {t.mean()*1e3:.1f}ms  "
-            f"GRelax/s {np.mean(rates):.4f} (host-simulated devices)"
+            f"GRelax/s {np.mean(rates):.4f} ({on})"
         )
         trace_doc = None
         if args.trace:
@@ -310,7 +313,7 @@ def main(argv=None) -> int:
         print(
             f"BC {args.sync} fanout={args.fanout} devices={args.devices} "
             f"lanes={lanes}: {n_roots} sources in {dt*1e3:.1f}ms "
-            f"({n_roots/dt:.1f} sources/s; host-simulated devices)"
+            f"({n_roots/dt:.1f} sources/s; {on})"
         )
         print("top-5 central vertices:",
               ", ".join(f"{v}={bc_scores[v]:.1f}" for v in top))
@@ -369,7 +372,7 @@ def main(argv=None) -> int:
         print(
             f"{args.algo} {args.sync} fanout={args.fanout} "
             f"devices={args.devices}: {iters} rounds in {t.mean()*1e3:.1f}ms"
-            f"  GEdge/s {work/t.mean()/1e9:.4f} (host-simulated devices)"
+            f"  GEdge/s {work/t.mean()/1e9:.4f} ({on})"
         )
         if args.algo == "pagerank":
             top = np.argsort(res)[::-1][:5]
@@ -421,7 +424,7 @@ def main(argv=None) -> int:
             f"devices={args.devices} lanes={args.num_sources}: "
             f"{n_roots} searches in {dt*1e3:.1f}ms over {eng.stats.waves} "
             f"waves  ({n_roots/dt:.1f} searches/s, aggregate GTEP/s "
-            f"{eng.stats.scanned_edges/dt/1e9:.4f}; host-simulated devices)"
+            f"{eng.stats.scanned_edges/dt/1e9:.4f}; {on})"
         )
         trace_doc = None
         if args.trace:
@@ -454,13 +457,8 @@ def main(argv=None) -> int:
             )
         return 0
 
-    layout = None
-    if cfg.use_pallas:
-        from repro.kernels import blocks
-
-        layout = blocks.build_bfs_layout(pg)
-    arrays = bfs.place_arrays(pg, mesh, cfg.axes, layout)
-    fn = bfs.build_bfs_fn(pg, mesh, cfg, layout)
+    arrays = bfs.place_arrays(pg, mesh, cfg.axes)
+    fn = bfs.build_bfs_fn(pg, mesh, cfg)
     # warmup / compile
     d, lvl, scanned = fn(arrays, np.int32(roots[0]))
     jax.block_until_ready(d)
@@ -484,8 +482,7 @@ def main(argv=None) -> int:
     print(
         f"BFS {args.sync} fanout={args.fanout} mode={args.mode} "
         f"devices={args.devices}: time {t.mean()*1e3:.1f}ms  "
-        f"GTEP/s {g_.mean():.4f} (host-simulated devices; "
-        f"see EXPERIMENTS.md for the measurement caveat)"
+        f"GTEP/s {g_.mean():.4f} ({on})"
     )
     trace_doc = None
     if args.trace:
@@ -521,4 +518,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch import devices
+
+    devices.enable_compile_cache()
     raise SystemExit(main())

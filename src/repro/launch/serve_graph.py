@@ -1,14 +1,16 @@
 """Graph-query serving CLI (DESIGN.md §15).
 
-``python -m repro.launch.serve_graph --scale 12 --devices 8 --duration 5``
+``python -m repro.launch.serve_graph --scale 12 --duration 5``
 
-Builds a graph, 1D-partitions it over simulated devices, starts a
-:class:`~repro.service.GraphQueryService`, and drives it with a built-in
-open-loop load (mixed ``bfs``/``closeness`` root queries at ``--qps``,
-per-request ``--deadline-ms``); on exit it prints — and with
-``--stats-json`` persists — the full telemetry snapshot (p50/p95/p99
-latency, QPS, wave occupancy, cache hit rate) alongside the engine stats,
-using the ``bfs_run`` stats schema extended with a ``telemetry`` block.
+Builds a graph, 1D-partitions it over the devices JAX finds (``--devices``
+takes fewer), starts a :class:`~repro.service.GraphQueryService`, and
+drives it with a built-in open-loop load (mixed ``bfs``/``closeness`` root
+queries at ``--qps``, per-request ``--deadline-ms``); on exit it prints —
+and with ``--stats-json`` persists — the full telemetry snapshot
+(p50/p95/p99 latency, QPS, wave occupancy, cache hit rate) alongside the
+engine stats, using the ``bfs_run`` stats schema extended with a
+``telemetry`` block.  It exits 1 when a request failed (an expired
+deadline is not a failure) and no ``--chaos`` spec was given.
 
 ``--swap-after N`` swaps in a fresh graph (new seed) after ``N`` requests
 to exercise the epoch-bump invalidation path under live traffic.
@@ -47,7 +49,6 @@ artifact.
 from __future__ import annotations
 
 import argparse
-import os
 import sys
 
 
@@ -55,7 +56,9 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=int, default=12)
     ap.add_argument("--edge-factor", type=int, default=8)
-    ap.add_argument("--devices", type=int, default=8)
+    ap.add_argument("--devices", type=int, default=None,
+                    help="devices to shard over (default: every device "
+                         "JAX finds)")
     ap.add_argument("--fanout", type=int, default=4)
     ap.add_argument("--sync", default="adaptive",
                     choices=["butterfly", "sparse", "adaptive", "rabenseifner",
@@ -149,9 +152,6 @@ def main(argv=None) -> int:
         ap.error("--swap-after is a single-service path; use mutations "
                  "(--mutate-rate) with --replicas")
 
-    os.environ.setdefault(
-        "XLA_FLAGS", f"--xla_force_host_platform_device_count={args.devices}"
-    )
     import json
     import time
 
@@ -159,9 +159,11 @@ def main(argv=None) -> int:
     import numpy as np
 
     from repro.core import bfs
-    from repro.graph import csr, generators, partition
+    from repro.graph import csr, generators
+    from repro.launch import devices as devices_mod
     from repro.service import (
         AdmissionError,
+        DeadlineExceeded,
         FaultInjector,
         GraphQueryService,
         Replica,
@@ -169,9 +171,19 @@ def main(argv=None) -> int:
         RouterTelemetry,
     )
 
+    try:
+        args.devices = devices_mod.resolve_device_count(args.devices)
+    except ValueError as e:
+        ap.error(str(e))
+    on = devices_mod.device_label(args.devices)
+
     def build(seed):
-        g = generators.kronecker(args.scale, args.edge_factor, seed=seed)
-        return g, partition.partition_1d(g, args.devices)
+        g, pg, etl_line = devices_mod.partitioned_graph(
+            lambda timings: generators.kronecker(
+                args.scale, args.edge_factor, seed=seed, timings=timings),
+            args.devices)
+        print(etl_line)
+        return g, pg
 
     from repro.core import events as events_mod
     from repro.core.tracing import NULL_TRACER, Tracer
@@ -380,13 +392,15 @@ def main(argv=None) -> int:
                 futs.append(svc.submit(algos[i % len(algos)], root))
         except AdmissionError:
             rejected += 1
-    ok = err = stale = 0
+    ok = err = expired = stale = 0
     for f in futs:
         try:
             res = f.result(timeout=600)
             ok += 1
             if replicated and res.stale:
                 stale += 1
+        except DeadlineExceeded:
+            expired += 1  # shed by --deadline-ms, as asked
         except Exception:
             err += 1
     elapsed = time.perf_counter() - t0
@@ -420,11 +434,11 @@ def main(argv=None) -> int:
         fb = snap["faults"]
         print(
             f"{ok}/{n} served in {elapsed:.2f}s ({ok/elapsed:.1f} QPS; "
-            f"{rejected} rejected, {err} failed, {stale} stale)  "
+            f"{rejected} rejected, {err} failed, {expired} expired, "
+            f"{stale} stale)  "
             f"p50 {lat['p50']:.1f}ms  p95 {lat['p95']:.1f}ms  "
             f"p99 {lat['p99']:.1f}ms  replicas "
-            f"{snap['n_serving']}/{args.replicas} serving "
-            f"(host-simulated devices)"
+            f"{snap['n_serving']}/{args.replicas} serving ({on})"
         )
         print(
             f"faults: injected {sum(fb['injected'].values())}  "
@@ -436,11 +450,10 @@ def main(argv=None) -> int:
     else:
         print(
             f"{ok}/{n} served in {elapsed:.2f}s ({ok/elapsed:.1f} QPS; "
-            f"{rejected} rejected, {err} failed/expired)  "
+            f"{rejected} rejected, {err} failed, {expired} expired)  "
             f"p50 {lat['p50']:.1f}ms  p95 {lat['p95']:.1f}ms  "
             f"p99 {lat['p99']:.1f}ms  occupancy {snap['wave_occupancy']:.2f}  "
-            f"cache hit-rate {snap['cache']['hit_rate']:.2f} "
-            f"(host-simulated devices)"
+            f"cache hit-rate {snap['cache']['hit_rate']:.2f} ({on})"
         )
     if n_mut and not replicated:
         mut = snap["mutations"]
@@ -471,7 +484,7 @@ def main(argv=None) -> int:
             devices=args.devices,
             config={"sync": args.sync, "mode": cfg.mode,
                     "fanout": args.fanout, "lanes": args.lanes,
-                    "delta": 0, "max_weight": 0, "use_pallas": False,
+                    "delta": 0, "max_weight": 0,
                     "replicas": args.replicas,
                     "chaos": args.chaos or ""},
             timing_ms={"mean": lat["mean"], "total": elapsed * 1e3},
@@ -517,8 +530,15 @@ def main(argv=None) -> int:
         tracer.write_jsonl(args.trace + "l")  # FILE.json -> FILE.jsonl
         print(f"trace ({n_ev} events) -> {args.trace} "
               f"(Perfetto/chrome://tracing) + {args.trace}l")
+    if err and not args.chaos:
+        # without injected faults a failed request is a fault of the system
+        print(f"FAILED: {err} request(s) failed", file=sys.stderr)
+        return 1
     return 0
 
 
 if __name__ == "__main__":
+    from repro.launch import devices
+
+    devices.enable_compile_cache()
     raise SystemExit(main())

@@ -9,7 +9,6 @@ import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
-import repro  # noqa: E402, F401  (installs the JAX version-compat shims)
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402

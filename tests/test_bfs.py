@@ -10,9 +10,10 @@ from repro.graph import csr, generators, partition
 INF32 = np.iinfo(np.int32).max
 
 
-def _dist(pg, mesh, root, **kw):
+def _dist(pg, mesh, root, interpret=False, **kw):
     cfg = bfs.BFSConfig(axes=("data",), **kw)
-    d, levels, scanned = bfs.distributed_bfs(pg, mesh, root, cfg)
+    d, levels, scanned = bfs.distributed_bfs(pg, mesh, root, cfg,
+                                             interpret=interpret)
     return d, levels, scanned
 
 
@@ -93,8 +94,31 @@ def test_pallas_path_matches(mesh8, mode):
     g = GRAPHS["kron10"]()
     pg = partition.partition_1d(g, 8)
     ref = bfs.bfs_reference(g, 3)
-    d, _, _ = _dist(pg, mesh8, 3, mode=mode, use_pallas=True)
+    d, _, _ = _dist(pg, mesh8, 3, mode=mode, use_pallas=True, interpret=True)
     np.testing.assert_array_equal(_norm(d), _norm(ref))
+
+
+def test_pallas_refused_on_tpu_backend(mesh8, monkeypatch):
+    """The TPU compiler refuses the Pallas frontier kernels; building the
+    Pallas path for a mesh of TPU devices fails at build time and names
+    why, rather than falling back to the interpreter.  The mesh decides,
+    not the process's default backend."""
+    import types
+
+    from repro.kernels import blocks
+
+    g = GRAPHS["kron10"]()
+    pg = partition.partition_1d(g, 8)
+    layout = blocks.build_bfs_layout(pg)
+    cfg = bfs.BFSConfig(axes=("data",), use_pallas=True)
+    tpu_mesh = types.SimpleNamespace(
+        devices=np.array([types.SimpleNamespace(platform="tpu")] * 8))
+    for interpret in (False, True):
+        with pytest.raises(NotImplementedError, match="Cannot do int indexing"):
+            bfs.build_bfs_fn(pg, tpu_mesh, cfg, layout, interpret=interpret)
+    # a CPU mesh builds, whatever the default backend says
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    bfs.build_bfs_fn(pg, mesh8, cfg, layout, interpret=True)
 
 
 def test_isolated_root(mesh8):

@@ -217,3 +217,67 @@ def test_largest_component_roots_distinct_and_clamped():
 
     everything = csr.largest_component_roots(g, comp_size + 999, rng)
     assert everything.shape == (comp_size,)  # clamped, never raises
+
+
+def _union_find_labels(g):
+    """The sequential union-find the vectorised components replaced: union
+    by smaller root, components numbered by their smallest vertex."""
+    parent = list(range(g.n))
+
+    def find(x):
+        while parent[x] != x:
+            parent[x] = parent[parent[x]]
+            x = parent[x]
+        return x
+
+    for u, v in zip(g.src.tolist(), g.dst.tolist()):
+        ru, rv = find(u), find(v)
+        if ru != rv:
+            parent[max(ru, rv)] = min(ru, rv)
+    return np.unique([find(i) for i in range(g.n)], return_inverse=True)[1]
+
+
+@pytest.mark.parametrize("make", [
+    lambda: generators.kronecker(9, 4, seed=1),
+    lambda: generators.uniform_random(700, 500, seed=2),
+    lambda: generators.path_graph(77),
+    lambda: csr.from_edges(np.array([5, 9, 40]), np.array([3, 2, 41]), 50,
+                           symmetrize=False),
+], ids=["kron9", "urand-sparse", "path-padded", "directed"])
+def test_connected_components_same_labels_as_union_find(make):
+    g = make()
+    got = csr.connected_components(g)
+    assert got.shape == (g.n,)
+    np.testing.assert_array_equal(got, _union_find_labels(g))
+
+
+def test_kronecker_etl_timings():
+    etl = {}
+    g = generators.kronecker(8, 8, seed=0, timings=etl)
+    assert set(etl) == {"generate", "from_edges", "validate"}
+    assert all(v >= 0.0 for v in etl.values())
+    assert g._validated
+    steps = {}
+    csr.from_edges(g.src, g.dst, g.n_real, timings=steps)
+    assert set(steps) == {"from_edges", "validate"}
+
+
+@pytest.mark.parametrize("scale,edge_factor,seed", [(6, 4, 0), (11, 16, 7)])
+def test_kronecker_edge_draw_matches_int64_loop(scale, edge_factor, seed):
+    """The buffered uint32 edge draw builds the same graph as the plain
+    per-bit int64 loop it replaced."""
+    a, b, c = 0.57, 0.19, 0.19
+    n, m = 1 << scale, (1 << scale) * edge_factor
+    rng = np.random.default_rng(seed)
+    src = np.zeros(m, dtype=np.int64)
+    dst = np.zeros(m, dtype=np.int64)
+    for bit in range(scale):
+        r = rng.random(m)
+        src |= (r >= a + b).astype(np.int64) << bit
+        dst |= (((r >= a) & (r < a + b)) | (r >= a + b + c)).astype(
+            np.int64) << bit
+    perm = rng.permutation(n)
+    want = csr.from_edges(perm[src], perm[dst], n)
+    got = generators.kronecker(scale, edge_factor, seed=seed)
+    np.testing.assert_array_equal(got.src, want.src)
+    np.testing.assert_array_equal(got.dst, want.dst)

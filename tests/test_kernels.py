@@ -1,4 +1,7 @@
-"""Pallas kernels (interpret mode) vs pure-jnp oracles, swept over shapes."""
+"""Pallas kernels (interpret mode) vs pure-jnp oracles, swept over shapes.
+
+Every call passes ``interpret=True``: the kernels run under the Pallas
+interpreter on any backend, and nothing picks the mode for the caller."""
 
 import jax
 import jax.numpy as jnp
@@ -16,7 +19,7 @@ from repro.kernels import blocks, ops, ref
 @pytest.mark.parametrize("w", [128, 1024, 4096])
 def test_bitmap_or_reduce(k, w, rng):
     stack = rng.integers(0, 2**32, size=(k, w), dtype=np.uint32)
-    got = ops.bitmap_or_reduce(jnp.asarray(stack))
+    got = ops.bitmap_or_reduce(jnp.asarray(stack), interpret=True)
     want = ref.bitmap_or_reduce(jnp.asarray(stack))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -27,7 +30,8 @@ def test_bitmap_or_reduce_property(k, w_blocks, seed):
     rng = np.random.default_rng(seed)
     w = 128 * w_blocks
     stack = rng.integers(0, 2**32, size=(k, w), dtype=np.uint32)
-    got = np.asarray(ops.bitmap_or_reduce(jnp.asarray(stack), block=128))
+    got = np.asarray(ops.bitmap_or_reduce(jnp.asarray(stack), block=128,
+                                         interpret=True))
     assert np.array_equal(got, np.bitwise_or.reduce(stack, axis=0))
 
 
@@ -41,7 +45,8 @@ def test_frontier_gather_windowed(nb, eb, ww, rng):
     block_ws = rng.integers(0, w // ww, size=(nb,)).astype(np.int32)
     src_local = rng.integers(0, ww * 32, size=(nb, eb)).astype(np.int32)
     got = ops.frontier_gather(
-        jnp.asarray(words), jnp.asarray(block_ws), jnp.asarray(src_local), ww=ww
+        jnp.asarray(words), jnp.asarray(block_ws), jnp.asarray(src_local), ww=ww,
+        interpret=True,
     )
     want = ref.frontier_gather(
         jnp.asarray(words), jnp.asarray(block_ws), jnp.asarray(src_local), ww
@@ -54,7 +59,8 @@ def test_frontier_gather_full(nb, eb, rng):
     w = 256
     words = rng.integers(0, 2**32, size=(w,), dtype=np.uint32)
     src = rng.integers(0, w * 32, size=(nb, eb)).astype(np.int32)
-    got = ops.frontier_gather_full(jnp.asarray(words), jnp.asarray(src))
+    got = ops.frontier_gather_full(jnp.asarray(words), jnp.asarray(src),
+                                   interpret=True)
     want = ref.frontier_gather_full(jnp.asarray(words), jnp.asarray(src))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -77,7 +83,7 @@ def test_frontier_scatter(n_windows, ww, nb, eb, rng):
     active = rng.integers(0, 2, size=(nb, eb)).astype(bool)
     got = ops.frontier_scatter(
         jnp.asarray(active), jnp.asarray(block_win), jnp.asarray(block_first),
-        jnp.asarray(dst_local), n_windows=n_windows, ww=ww,
+        jnp.asarray(dst_local), n_windows=n_windows, ww=ww, interpret=True,
     )
     want = ref.frontier_scatter(
         jnp.asarray(active), jnp.asarray(block_win), jnp.asarray(dst_local),
@@ -118,7 +124,8 @@ def test_expand_push_matches_jnp(mesh8, rng):
     fw = fr.pack(jnp.asarray(frontier_bits))
     arrays = {k: jnp.asarray(v[0]) for k, v in pg.arrays().items()}
     arrays.update({k: jnp.asarray(v[0]) for k, v in layout.arrays.items()})
-    got = kops.expand_push_pallas(fw, arrays, layout.meta, pg.n_words)
+    got = kops.expand_push_pallas(fw, arrays, layout.meta, pg.n_words,
+                                  interpret=True)
     # jnp reference path
     mask = jnp.arange(pg.emax) < arrays["edge_count"]
     active = fr.get_bits(fw, arrays["edge_src"]) & mask

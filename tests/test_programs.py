@@ -430,12 +430,11 @@ json.dump(got, sys.stdout)
 
 
 def test_hlo_fingerprints_stable():
-    """The bfs/sssp drivers were refactored onto ``repro.core.loop``; the
-    XLA programs they lower to must not have changed.  Golden sha256s
-    were captured from the pre-refactor builders on this jax version
+    """The XLA programs the bfs/sssp drivers lower to must not change
+    unnoticed.  Golden sha256s were captured on the installed jax version
     (fresh process, symbol names canonicalized — see
     ``_FINGERPRINT_SCRIPT``) — any drift is a real compilation change,
-    not suite-ordering noise."""
+    not suite-ordering noise; a deliberate one re-captures the golden."""
     with open(GOLDEN) as f:
         golden = json.load(f)
     if jax.__version__ != golden["jax"]:

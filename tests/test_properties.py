@@ -75,7 +75,8 @@ def test_bitmap_or_reduce_property(k, w_blocks, seed):
     rng = np.random.default_rng(seed)
     w = 128 * w_blocks
     stack = rng.integers(0, 2**32, size=(k, w), dtype=np.uint32)
-    got = np.asarray(ops.bitmap_or_reduce(jnp.asarray(stack), block=128))
+    got = np.asarray(ops.bitmap_or_reduce(jnp.asarray(stack), block=128,
+                                         interpret=True))
     assert np.array_equal(got, np.bitwise_or.reduce(stack, axis=0))
 
 
