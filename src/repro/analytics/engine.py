@@ -37,6 +37,7 @@ from repro.analytics import msbfs
 from repro.core import metrics as metrics_mod
 from repro.core.bfs import BFSConfig, place_arrays
 from repro.core.devlock import device_lock
+from repro.core.tracing import NULL_TRACER
 from repro.graph.partition import PartitionedGraph
 from repro.traversal import bc as bc_mod
 from repro.traversal import sssp as sssp_mod
@@ -155,6 +156,7 @@ class EngineStats:
     program_runs: int = 0  # §19 vertex-program executions
     program_iters: int = 0  # gather/sync/apply rounds across those runs
     program_edges: float = 0.0  # edges examined by vertex programs
+    device_wait_s: float = 0.0  # host time blocked on BFS waves' outputs
 
 
 class BFSQueryEngine:
@@ -163,6 +165,10 @@ class BFSQueryEngine:
     ``lanes`` is the wave width (bit-lanes per wave; 32 fills one uint32
     lane-word).  Queries are packed greedily: ``ceil(len(roots)/lanes)``
     waves per batch, each one compiled-program call.
+
+    Each BFS wave is a live ``engine/wave`` span of ``tracer``
+    (:mod:`repro.core.tracing`) with the children ``device-wait``,
+    ``copy-back`` and ``assemble``.
     """
 
     def __init__(
@@ -172,6 +178,7 @@ class BFSQueryEngine:
         cfg: BFSConfig = BFSConfig(),
         *,
         lanes: int = 32,
+        tracer=None,
     ):
         if lanes < 1:
             raise ValueError(f"lanes must be >= 1, got {lanes}")
@@ -179,6 +186,7 @@ class BFSQueryEngine:
         self.mesh = mesh
         self.cfg = cfg
         self.lanes = lanes
+        self.tracer = tracer if tracer is not None else NULL_TRACER
         self.stats = EngineStats()
         self._arrays = place_arrays(pg, mesh, cfg.axes)
         self._fn = compiled_wave_fn(pg, mesh, cfg, lanes)
@@ -193,21 +201,27 @@ class BFSQueryEngine:
     def _run_wave(self, roots: np.ndarray) -> np.ndarray:
         padded = np.full(self.lanes, -1, dtype=np.int32)
         padded[: roots.size] = roots
-        with device_lock(self.mesh):
-            d_owned, levels, scanned = self._fn(
-                self._arrays, jnp.asarray(padded)
-            )
-            # materialize INSIDE the lock: ops on the lazy outputs (even
-            # np.max) dispatch fresh device programs, which must not
-            # overlap another engine's collectives on shared devices
-            d_owned, levels, scanned = (
-                np.asarray(d_owned), np.asarray(levels), np.asarray(scanned)
-            )
-        self.stats.waves += 1
-        _WAVES.inc(algo="bfs")
-        self.stats.scanned_edges += float(np.asarray(scanned)[0])
-        self.stats.max_levels = max(self.stats.max_levels, int(np.max(levels)))
-        dist = msbfs.assemble_distances(self.pg, d_owned, self.lanes)
+        tracer = self.tracer
+        with tracer.span("wave", track="engine",
+                         args={"roots": int(roots.size)}):
+            with device_lock(self.mesh):
+                out = self._fn(self._arrays, jnp.asarray(padded))
+                # materialize INSIDE the lock: ops on the lazy outputs
+                # (even np.max) dispatch fresh device programs, which must
+                # not overlap another engine's collectives on shared devices
+                t0 = time.perf_counter()
+                with tracer.span("device-wait", track="engine"):
+                    jax.block_until_ready(out)
+                self.stats.device_wait_s += time.perf_counter() - t0
+                with tracer.span("copy-back", track="engine"):
+                    d_owned, levels, scanned = (np.asarray(o) for o in out)
+            self.stats.waves += 1
+            _WAVES.inc(algo="bfs")
+            self.stats.scanned_edges += float(scanned[0])
+            self.stats.max_levels = max(self.stats.max_levels,
+                                        int(np.max(levels)))
+            with tracer.span("assemble", track="engine"):
+                dist = msbfs.assemble_distances(self.pg, d_owned, self.lanes)
         return dist[: roots.size]
 
     def _checked_ids(self, ids: Sequence[int], what: str) -> np.ndarray:

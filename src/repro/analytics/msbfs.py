@@ -128,7 +128,8 @@ def build_msbfs_fn(
 
         def cond(state):
             frontier, seen, d_owned, level, scanned, pull = state[:6]
-            return (fr.popcount(frontier) > 0) & (level < max_levels)
+            with loop.phase("cond"):
+                return (fr.popcount(frontier) > 0) & (level < max_levels)
 
         def step(state):
             frontier, seen, d_owned, level, scanned, pull = state[:6]
@@ -142,59 +143,65 @@ def build_msbfs_fn(
                     arrays, frontier, seen, n_rows, False, lanes=True
                 )
 
-            if cfg.mode == "top_down":
-                gq = do_push(None)
-            elif cfg.mode == "bottom_up":
-                gq = do_pull(None)
-            else:
-                gq = lax.cond(pull, do_pull, do_push, None)
+            with loop.phase("expand"):
+                if cfg.mode == "top_down":
+                    gq = do_push(None)
+                elif cfg.mode == "bottom_up":
+                    gq = do_pull(None)
+                else:
+                    gq = lax.cond(pull, do_pull, do_push, None)
 
-            # edges examined this level, summed over ACTIVE lanes (inactive
-            # lanes would otherwise count every vertex as unvisited):
-            owned_front = owned_lanes(frontier)
-            m_f = (arrays["deg_out"][:, None] * owned_front).sum()
-            owned_unvis = (
-                ~fr.lane_unpack(
-                    lax.dynamic_slice(seen, (v_start, 0), (vmax, bw))
-                )[:, :n_lanes]
-                & owned_mask[:, None]
-                & lane_active[None, :]
-            )
-            m_u = (arrays["deg_out"][:, None] * owned_unvis).sum()
-            if cfg.mode == "bottom_up":
-                lvl_scanned = m_u
-            elif cfg.mode == "top_down":
-                lvl_scanned = m_f
-            else:
-                lvl_scanned = jnp.where(pull, m_u, m_f)
+                # edges examined this level, summed over ACTIVE lanes
+                # (inactive lanes would otherwise count every vertex as
+                # unvisited):
+                owned_front = owned_lanes(frontier)
+                m_f = (arrays["deg_out"][:, None] * owned_front).sum()
+                owned_unvis = (
+                    ~fr.lane_unpack(
+                        lax.dynamic_slice(seen, (v_start, 0), (vmax, bw))
+                    )[:, :n_lanes]
+                    & owned_mask[:, None]
+                    & lane_active[None, :]
+                )
+                m_u = (arrays["deg_out"][:, None] * owned_unvis).sum()
+                if cfg.mode == "bottom_up":
+                    lvl_scanned = m_u
+                elif cfg.mode == "top_down":
+                    lvl_scanned = m_f
+                else:
+                    lvl_scanned = jnp.where(pull, m_u, m_f)
 
             # -- Phase 2: butterfly sync, UNCHANGED on the flat buffer ---
-            if trace:
-                t_words, t_branch, t_shipped = flightrec.or_sync_stats(
-                    gq.reshape(-1), cfg
-                )
-            merged = _sync_frontier(gq.reshape(-1), cfg).reshape(n_rows, bw)
+            with loop.phase("exchange"):
+                if trace:
+                    t_words, t_branch, t_shipped = flightrec.or_sync_stats(
+                        gq.reshape(-1), cfg
+                    )
+                merged = _sync_frontier(gq.reshape(-1), cfg).reshape(
+                    n_rows, bw)
 
             # -- Per-lane enqueue-if-new + level capture -----------------
-            new = merged & ~seen
-            seen = seen | new
-            d_owned = jnp.where(owned_lanes(new), level + 1, d_owned)
+            with loop.phase("update"):
+                new = merged & ~seen
+                seen = seen | new
+                d_owned = jnp.where(owned_lanes(new), level + 1, d_owned)
 
             # -- Direction-optimizing switch, wave-aggregated ------------
             if cfg.mode == "direction_optimizing":
-                g_mf = lax.psum(m_f, cfg.axes)
-                g_mu = lax.psum(m_u, cfg.axes)
-                n_f = fr.popcount(new)
-                active_count = jnp.maximum(
-                    lane_active.sum(dtype=jnp.int32), 1
-                )
-                go_pull = g_mf.astype(jnp.float32) > (
-                    g_mu.astype(jnp.float32) / cfg.alpha
-                )
-                go_push = n_f.astype(jnp.float32) < (
-                    active_count * pg.n / cfg.beta
-                )
-                pull = jnp.where(pull, ~go_push, go_pull)
+                with loop.phase("direction"):
+                    g_mf = lax.psum(m_f, cfg.axes)
+                    g_mu = lax.psum(m_u, cfg.axes)
+                    n_f = fr.popcount(new)
+                    active_count = jnp.maximum(
+                        lane_active.sum(dtype=jnp.int32), 1
+                    )
+                    go_pull = g_mf.astype(jnp.float32) > (
+                        g_mu.astype(jnp.float32) / cfg.alpha
+                    )
+                    go_push = n_f.astype(jnp.float32) < (
+                        active_count * pg.n / cfg.beta
+                    )
+                    pull = jnp.where(pull, ~go_push, go_pull)
 
             out = (
                 new,

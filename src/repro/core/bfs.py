@@ -269,7 +269,8 @@ def build_bfs_fn(
 
         def cond(state):
             frontier_words, visited, d_owned, level, scanned, pull = state[:6]
-            return (fr.popcount(frontier_words) > 0) & (level < max_levels)
+            with loop.phase("cond"):
+                return (fr.popcount(frontier_words) > 0) & (level < max_levels)
 
         def step(state):
             frontier_words, visited, d_owned, level, scanned, pull = state[:6]
@@ -287,52 +288,58 @@ def build_bfs_fn(
                     meta, interpret=interpret,
                 )
 
-            if cfg.mode == "top_down":
-                gq = do_push(None)
-            elif cfg.mode == "bottom_up":
-                gq = do_pull(None)
-            else:
-                gq = lax.cond(pull, do_pull, do_push, None)
+            with loop.phase("expand"):
+                if cfg.mode == "top_down":
+                    gq = do_push(None)
+                elif cfg.mode == "bottom_up":
+                    gq = do_pull(None)
+                else:
+                    gq = lax.cond(pull, do_pull, do_push, None)
 
-            # edges examined this level (honest TEPS accounting):
-            owned_front = fr.unpack(
-                lax.dynamic_slice(frontier_words, (word_start,), (wmax,))
-            )[:vmax] & owned_mask
-            m_f = (arrays["deg_out"] * owned_front).sum()
-            owned_unvis = (
-                ~fr.unpack(lax.dynamic_slice(visited, (word_start,), (wmax,)))[:vmax]
-            ) & owned_mask
-            m_u = (arrays["deg_out"] * owned_unvis).sum()
-            if cfg.mode == "bottom_up":
-                lvl_scanned = m_u  # pull probes unvisited in-edges
-            elif cfg.mode == "top_down":
-                lvl_scanned = m_f
-            else:
-                lvl_scanned = jnp.where(pull, m_u, m_f)
+                # edges examined this level (honest TEPS accounting):
+                owned_front = fr.unpack(
+                    lax.dynamic_slice(frontier_words, (word_start,), (wmax,))
+                )[:vmax] & owned_mask
+                m_f = (arrays["deg_out"] * owned_front).sum()
+                owned_unvis = (
+                    ~fr.unpack(lax.dynamic_slice(visited, (word_start,),
+                                                 (wmax,)))[:vmax]
+                ) & owned_mask
+                m_u = (arrays["deg_out"] * owned_unvis).sum()
+                if cfg.mode == "bottom_up":
+                    lvl_scanned = m_u  # pull probes unvisited in-edges
+                elif cfg.mode == "top_down":
+                    lvl_scanned = m_f
+                else:
+                    lvl_scanned = jnp.where(pull, m_u, m_f)
 
             # -- Phase 2: butterfly frontier synchronization -------------
-            if trace:
-                t_words, t_branch, t_shipped = flightrec.or_sync_stats(gq, cfg)
-            merged = _sync_frontier(gq, cfg)
+            with loop.phase("exchange"):
+                if trace:
+                    t_words, t_branch, t_shipped = flightrec.or_sync_stats(
+                        gq, cfg)
+                merged = _sync_frontier(gq, cfg)
 
             # -- Update (enqueue-if-new as set ops) -----------------------
-            new = merged & ~visited
-            visited = visited | new
-            owned_new = fr.unpack(
-                lax.dynamic_slice(new, (word_start,), (wmax,))
-            )[:vmax] & owned_mask
-            d_owned = jnp.where(owned_new, level + 1, d_owned)
+            with loop.phase("update"):
+                new = merged & ~visited
+                visited = visited | new
+                owned_new = fr.unpack(
+                    lax.dynamic_slice(new, (word_start,), (wmax,))
+                )[:vmax] & owned_mask
+                d_owned = jnp.where(owned_new, level + 1, d_owned)
 
             # -- Direction-optimizing switch (Beamer alpha/beta) ----------
             if cfg.mode == "direction_optimizing":
-                g_mf = lax.psum(m_f, cfg.axes)
-                g_mu = lax.psum(m_u, cfg.axes)
-                n_f = fr.popcount(new)
-                go_pull = g_mf.astype(jnp.float32) > (
-                    g_mu.astype(jnp.float32) / cfg.alpha
-                )
-                go_push = n_f.astype(jnp.float32) < (pg.n / cfg.beta)
-                pull = jnp.where(pull, ~go_push, go_pull)
+                with loop.phase("direction"):
+                    g_mf = lax.psum(m_f, cfg.axes)
+                    g_mu = lax.psum(m_u, cfg.axes)
+                    n_f = fr.popcount(new)
+                    go_pull = g_mf.astype(jnp.float32) > (
+                        g_mu.astype(jnp.float32) / cfg.alpha
+                    )
+                    go_push = n_f.astype(jnp.float32) < (pg.n / cfg.beta)
+                    pull = jnp.where(pull, ~go_push, go_pull)
 
             out = (
                 new,

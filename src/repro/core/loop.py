@@ -25,6 +25,13 @@ Two pieces:
 The helpers are pure code motion from the pre-§19 builders: a delegating
 builder stages a byte-identical StableHLO program (asserted against
 recorded fingerprints), so the refactor is invisible to the compiler.
+
+:func:`phase` names the phases of a level step with ``jax.named_scope``
+(``traversal.expand``, ``.exchange``, ``.update``, ``.direction``,
+``.cond``).  A scope changes only the ``op_name`` metadata of the ops
+staged inside it: the compiled module names each operation's phase, so a
+device trace can be split by phase, and the StableHLO text the
+fingerprints hash is unchanged.
 """
 
 from __future__ import annotations
@@ -34,6 +41,20 @@ from typing import Callable, Optional, Sequence, Tuple
 import jax
 from jax import lax
 from jax.sharding import PartitionSpec as P
+
+#: the phases of a level step, as :func:`phase` names them
+PHASES = ("expand", "exchange", "update", "direction", "cond")
+
+
+def phase(name: str):
+    """``jax.named_scope("traversal.<name>")`` around the ops of one phase:
+    ``expand`` (phase 1, push or pull, and the level's edge count),
+    ``exchange`` (the phase-2 frontier sync), ``update`` (enqueue-if-new
+    and the distance write), ``direction`` (Beamer's switch), ``cond``
+    (the level loop's condition)."""
+    if name not in PHASES:
+        raise ValueError(f"unknown phase {name!r}; expected one of {PHASES}")
+    return jax.named_scope("traversal." + name)
 
 
 def traced_while(

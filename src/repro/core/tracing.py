@@ -35,11 +35,30 @@ Event model (deliberately smaller than OpenTelemetry):
 * every recorded event additionally carries a ``span_id`` — an 8-hex id
   unique within the tracer — so two same-named events on one trace (the
   original attempt and its hedged retry, say) stay distinguishable after
-  export (``args["span_id"]``).
+  export (``args["span_id"]``);
+* a live span (:meth:`Tracer.span`) also carries a ``parent_id``: the
+  ``span_id`` of the span open on the same thread when it started (``""``
+  at the top), so a stage's self time is its duration minus its
+  children's (exported as ``args["parent_id"]``).
+
+Profiler sink.  Every live span — :meth:`Tracer.span` and
+``NULL_TRACER.span`` alike — also opens a ``jax.profiler.TraceAnnotation``
+named ``repro:<track>/<name>``, so a ``jax.profiler`` trace of the process
+shows the program's stages on the same clock as the device's operations,
+whether or not a :class:`Tracer` is on.  jax is resolved on the first
+span, not at import, and where it is absent the sink is a no-op.  With no
+profile recording an annotation costs about a microsecond, which is why
+live spans mark stages (one per dispatch or wave), never requests.
+:meth:`Tracer.add_span` records an interval after the fact — the
+per-request ``queue-wait``/``coalesce`` spans, which lie in the past and
+cross threads — and so reaches only the in-memory tracer, never the
+profiler.
 """
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import json
 import threading
 import time
@@ -48,6 +67,25 @@ from typing import Any, Dict, List, Optional
 
 #: schema tag stamped on every exported trace document
 CHROME_SCHEMA = "request_trace/v1"
+
+#: name prefix of the live spans' ``jax.profiler`` annotations
+PROFILER_PREFIX = "repro:"
+
+
+@functools.lru_cache(maxsize=None)
+def _annotation_type():
+    """``jax.profiler.TraceAnnotation``, imported on first use; a null
+    context where jax is not installed."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return lambda name: contextlib.nullcontext()
+    return TraceAnnotation
+
+
+def profiler_annotation(track: str, name: str):
+    """The ``repro:<track>/<name>`` annotation a live span opens."""
+    return _annotation_type()(f"{PROFILER_PREFIX}{track}/{name}")
 
 
 class _SpanHandle:
@@ -61,10 +99,11 @@ class _SpanHandle:
 
 
 class _OpenSpan:
-    """Context manager measuring one span's wall interval."""
+    """Context manager measuring one span's wall interval, with its
+    profiler annotation open for the same block."""
 
     __slots__ = ("_tracer", "_name", "_track", "_cat", "_trace_id",
-                 "_handle", "_t0")
+                 "_handle", "_t0", "_id", "_parent", "_annotation")
 
     def __init__(self, tracer, name, track, cat, trace_id, args):
         self._tracer = tracer
@@ -75,15 +114,22 @@ class _OpenSpan:
         self._handle = _SpanHandle(dict(args or {}))
 
     def __enter__(self) -> _SpanHandle:
+        self._id, self._parent = self._tracer._open()
+        self._annotation = profiler_annotation(self._track, self._name)
+        self._annotation.__enter__()
         self._t0 = self._tracer.now()
         return self._handle
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        t1 = self._tracer.now()
+        self._annotation.__exit__(exc_type, exc, tb)
+        self._tracer._close(self._id)
         if exc_type is not None:
             self._handle.args.setdefault("error", exc_type.__name__)
-        self._tracer.add_span(
-            self._name, self._t0, self._tracer.now(), track=self._track,
-            cat=self._cat, trace_id=self._trace_id, args=self._handle.args,
+        self._tracer._record(
+            "span", self._name, self._t0, t1, self._track, self._cat,
+            self._trace_id, self._handle.args, span_id=self._id,
+            parent_id=self._parent,
         )
 
 
@@ -96,6 +142,7 @@ class Tracer:
         self._events: List[Dict[str, Any]] = []
         self._t0 = clock()
         self._next_span = 0  # span_id allocator (8-hex, unique per tracer)
+        self._local = threading.local()  # .open: this thread's live span ids
 
     enabled = True
 
@@ -120,6 +167,38 @@ class Tracer:
 
     # --- recording --------------------------------------------------------
 
+    def _record(self, kind, name, t0, t1, track, cat, trace_id, args, *,
+                span_id: str = "", parent_id: str = "") -> None:
+        ev = {
+            "kind": kind,
+            "name": name,
+            "cat": cat,
+            "track": track,
+            "ts_us": self._us(t0),
+            "dur_us": max(self._us(t1) - self._us(t0), 0),
+            "trace_id": trace_id,
+            "args": dict(args or {}),
+            "parent_id": parent_id,
+        }
+        with self._lock:
+            ev["span_id"] = span_id or self._new_span_id()
+            self._events.append(ev)
+
+    def _open(self):
+        """Allocate a live span's id and push it on this thread's stack;
+        returns ``(span_id, parent_id)``."""
+        with self._lock:
+            span_id = self._new_span_id()
+        stack = self._local.__dict__.setdefault("open", [])
+        parent = stack[-1] if stack else ""
+        stack.append(span_id)
+        return span_id, parent
+
+    def _close(self, span_id: str) -> None:
+        stack = self._local.__dict__.get("open", [])
+        if span_id in stack:  # the top, unless closed out of order
+            stack.remove(span_id)
+
     def add_span(
         self,
         name: str,
@@ -131,20 +210,9 @@ class Tracer:
         trace_id: str = "",
         args: Optional[Dict[str, Any]] = None,
     ) -> None:
-        """Record a completed ``[t0, t1]`` interval (tracer-clock seconds)."""
-        ev = {
-            "kind": "span",
-            "name": name,
-            "cat": cat,
-            "track": track,
-            "ts_us": self._us(t0),
-            "dur_us": max(self._us(t1) - self._us(t0), 0),
-            "trace_id": trace_id,
-            "args": dict(args or {}),
-        }
-        with self._lock:
-            ev["span_id"] = self._new_span_id()
-            self._events.append(ev)
+        """Record a completed ``[t0, t1]`` interval (tracer-clock seconds).
+        In memory only: the profiler cannot take an interval in the past."""
+        self._record("span", name, t0, t1, track, cat, trace_id, args)
 
     def span(
         self,
@@ -155,9 +223,11 @@ class Tracer:
         trace_id: str = "",
         args: Optional[Dict[str, Any]] = None,
     ) -> _OpenSpan:
-        """``with tracer.span("engine-wave", track="engine") as sp: ...`` —
-        measures the block's wall interval; ``sp.args`` is mutable and an
-        exception inside the block annotates ``args["error"]``."""
+        """``with tracer.span("wave", track="engine") as sp: ...`` —
+        measures the block's wall interval, under the span open on this
+        thread as its parent, and annotates the profiler's trace with
+        ``repro:<track>/<name>``; ``sp.args`` is mutable and an exception
+        inside the block annotates ``args["error"]``."""
         return _OpenSpan(self, name, track, cat, trace_id, args)
 
     def instant(
@@ -171,19 +241,8 @@ class Tracer:
         t: Optional[float] = None,
     ) -> None:
         """Record a point event (hedge fired, fault injected, ...)."""
-        ev = {
-            "kind": "instant",
-            "name": name,
-            "cat": cat,
-            "track": track,
-            "ts_us": self._us(self.now() if t is None else t),
-            "dur_us": 0,
-            "trace_id": trace_id,
-            "args": dict(args or {}),
-        }
-        with self._lock:
-            ev["span_id"] = self._new_span_id()
-            self._events.append(ev)
+        t = self.now() if t is None else t
+        self._record("instant", name, t, t, track, cat, trace_id, args)
 
     # --- access / export --------------------------------------------------
 
@@ -216,6 +275,8 @@ class Tracer:
                 args["trace_id"] = ev["trace_id"]
             if ev.get("span_id"):
                 args["span_id"] = ev["span_id"]
+            if ev.get("parent_id"):
+                args["parent_id"] = ev["parent_id"]
             rec = {
                 "name": ev["name"],
                 "cat": ev["cat"] or "serve",
@@ -275,8 +336,9 @@ class _NullTracer:
     def add_span(self, *a, **kw) -> None:
         pass
 
-    def span(self, *a, **kw) -> "_NullSpan":
-        return _NullSpan()
+    def span(self, name: str, *, track: str = "main", **kw) -> "_NullSpan":
+        """Records nothing, but still annotates the profiler's trace."""
+        return _NullSpan(track, name)
 
     def instant(self, *a, **kw) -> None:
         pass
@@ -292,14 +354,18 @@ class _NullTracer:
 
 
 class _NullSpan:
-    __slots__ = ("args",)
+    __slots__ = ("args", "_annotation")
+
+    def __init__(self, track: str, name: str):
+        self._annotation = profiler_annotation(track, name)
 
     def __enter__(self) -> _SpanHandle:
         self.args = {}
+        self._annotation.__enter__()
         return self  # duck-types _SpanHandle: has .args
 
     def __exit__(self, *exc) -> None:
-        pass
+        self._annotation.__exit__(*exc)
 
 
 #: process-wide disabled tracer; ``tracer or NULL_TRACER`` at wiring sites

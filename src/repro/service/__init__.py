@@ -115,7 +115,8 @@ class GraphQueryService:
         # (version, engine) swapped as ONE tuple so readers always see a
         # consistent pair without taking the swap lock
         self._state: Tuple[GraphVersion, BFSQueryEngine] = (
-            GraphVersion(), BFSQueryEngine(pg, mesh, cfg, lanes=lanes)
+            GraphVersion(),
+            BFSQueryEngine(pg, mesh, cfg, lanes=lanes, tracer=self.tracer),
         )
         self._sssp_cfg = sssp_cfg
         self._vp_cfg = None  # §19 knobs, derived from the engine cfg
@@ -351,7 +352,8 @@ class GraphQueryService:
         mesh = mesh if mesh is not None else self.mesh
         cfg = cfg if cfg is not None else self.cfg
         lanes = lanes if lanes is not None else self.lanes
-        engine = BFSQueryEngine(pg, mesh, cfg, lanes=lanes)
+        engine = BFSQueryEngine(pg, mesh, cfg, lanes=lanes,
+                                tracer=self.tracer)
         version = self._state[0].bump_epoch()
         self._state = (version, engine)
         self.mesh, self.cfg, self.lanes = mesh, cfg, lanes

@@ -177,7 +177,8 @@ class WaveScheduler:
     def _run_loop(self, svc, pending: Dict[str, List[QueryRequest]]) -> None:
         while True:
             timeout = self._next_timeout(pending, time.monotonic())
-            svc.queue.wait(timeout)
+            with svc.tracer.span("wait", track="scheduler"):
+                svc.queue.wait(timeout)
             if self._stop.is_set():
                 return
             now = time.monotonic()
@@ -224,110 +225,136 @@ class WaveScheduler:
         return "deadline"
 
     def _dispatch(self, cls: str, reqs: List[QueryRequest]) -> None:
+        """One dispatch of a wave class's pending group, as the live span
+        ``scheduler/dispatch`` (children: ``triage``, the engine's waves,
+        ``cache-put``, ``answer``).  Its duration and the part of it the
+        engine's waves kept this thread waiting on the device go to the
+        ``dispatch`` and ``device_wait`` stages of the telemetry current
+        when it began: a caller that resets the telemetry once its answer
+        is back (after a warm-up) never sees the tail of that dispatch."""
         svc = self.service
-        with svc.swap_lock:  # graph swaps wait for in-flight waves
-            epoch, engine = svc.state
-            now = time.monotonic()
+        telemetry = svc.telemetry
+        t0 = time.monotonic()
+        with svc.tracer.span("dispatch", track="scheduler",
+                             args={"cls": cls}) as span:
+            with svc.swap_lock:  # graph swaps wait for in-flight waves
+                epoch, engine = svc.state
+                waited = engine.stats.device_wait_s
+                try:
+                    self._dispatch_group(cls, reqs, epoch, engine, span)
+                finally:
+                    telemetry.record_stage("dispatch", time.monotonic() - t0)
+                    telemetry.record_stage(
+                        "device_wait", engine.stats.device_wait_s - waited)
 
-            live: List[QueryRequest] = []
-            for r in reqs:
-                if r.future.cancelled():
-                    continue
-                if r.expired(now):
-                    if resolve_future(r.future, exception=DeadlineExceeded(
-                        f"{r.algo} root={r.root}: deadline passed "
-                        "before dispatch"
-                    )):
-                        svc.telemetry.record_expired()
-                elif r.root >= engine.pg.n:
-                    # validated at submit against the THEN-current graph; a
-                    # swap can shrink n underneath a pending request.  Fail
-                    # just this one — never the innocents sharing its wave.
-                    if resolve_future(r.future, exception=ValueError(
-                        f"root {r.root} out of range after graph swap "
-                        f"(n={engine.pg.n})"
-                    )):
-                        svc.telemetry.record_failed()
-                else:
-                    live.append(r)
-            if not live:
-                return
+    def _triage(self, reqs: List[QueryRequest], epoch, engine):
+        """Drop cancelled requests, fail expired or out-of-range ones,
+        answer what the cache now holds, and fold duplicate roots: returns
+        ``(root -> its requests, riders folded onto another's lane)``."""
+        svc = self.service
+        now = time.monotonic()
+        live: List[QueryRequest] = []
+        for r in reqs:
+            if r.future.cancelled():
+                continue
+            if r.expired(now):
+                if resolve_future(r.future, exception=DeadlineExceeded(
+                    f"{r.algo} root={r.root}: deadline passed "
+                    "before dispatch"
+                )):
+                    svc.telemetry.record_expired()
+            elif r.root >= engine.pg.n:
+                # validated at submit against the THEN-current graph; a
+                # swap can shrink n underneath a pending request.  Fail
+                # just this one — never the innocents sharing its wave.
+                if resolve_future(r.future, exception=ValueError(
+                    f"root {r.root} out of range after graph swap "
+                    f"(n={engine.pg.n})"
+                )):
+                    svc.telemetry.record_failed()
+            else:
+                live.append(r)
 
-            # second cache probe (a wave since submission may have filled
-            # the entry) + duplicate-root fold: one lane per distinct root
-            by_root: Dict[int, List[QueryRequest]] = {}
-            n_riders = 0
-            for r in live:
-                hit, value = svc.cache_lookup(epoch, engine, r.algo, r.root)
-                if hit:
-                    self._resolve(r, value)
-                else:
-                    group = by_root.setdefault(r.root, [])
-                    if group:
-                        n_riders += 1
-                    group.append(r)
-            if not by_root:
-                return
+        # second cache probe (a wave since submission may have filled
+        # the entry) + duplicate-root fold: one lane per distinct root
+        by_root: Dict[int, List[QueryRequest]] = {}
+        n_riders = 0
+        for r in live:
+            hit, value = svc.cache_lookup(epoch, engine, r.algo, r.root)
+            if hit:
+                self._resolve(r, value)
+            else:
+                group = by_root.setdefault(r.root, [])
+                if group:
+                    n_riders += 1
+                group.append(r)
+        return by_root, n_riders
 
-            roots = sorted(by_root)
-            # §18 stage breakdown: queued-until-drained, then lingered in
-            # the coalescing window until this dispatch instant
-            t0 = time.monotonic()
-            tracer = svc.tracer
-            for group in by_root.values():
-                for r in group:
-                    drain_t = r.drain_t or t0
-                    svc.telemetry.record_stage(
-                        "queue_wait", drain_t - r.submit_t
-                    )
-                    svc.telemetry.record_stage("coalesce", t0 - drain_t)
-                    if tracer.enabled:
-                        tracer.add_span(
-                            f"queue-wait:{r.algo}", r.submit_t, drain_t,
-                            track="queue", trace_id=r.trace_id,
-                            args={"algo": r.algo, "root": r.root},
-                        )
-                        tracer.add_span(
-                            f"coalesce:{cls}", drain_t, t0,
-                            track="scheduler", trace_id=r.trace_id,
-                            args={"algo": r.algo, "root": r.root},
-                        )
-            results, engine_waves, offered = self._execute(
-                engine, epoch, cls, roots
-            )
-            dt_engine = time.monotonic() - t0
-            svc.telemetry.record_stage("engine", dt_engine)
-            if tracer.enabled:
-                tracer.add_span(
-                    f"wave:{cls}", t0, t0 + dt_engine, track="engine",
-                    args={
-                        "cls": cls, "roots": len(roots),
-                        "engine_waves": engine_waves, "riders": n_riders,
-                        "trace_ids": [r.trace_id for g in by_root.values()
-                                      for r in g][:8],
-                    },
+    def _dispatch_group(self, cls: str, reqs: List[QueryRequest], epoch,
+                        engine, span) -> None:
+        svc = self.service
+        tracer = svc.tracer
+        with tracer.span("triage", track="scheduler"):
+            by_root, n_riders = self._triage(reqs, epoch, engine)
+        if not by_root:
+            return
+
+        roots = sorted(by_root)
+        # §18 stage breakdown: queued-until-drained, then lingered in
+        # the coalescing window until this dispatch instant
+        t0 = time.monotonic()
+        for group in by_root.values():
+            for r in group:
+                drain_t = r.drain_t or t0
+                svc.telemetry.record_stage(
+                    "queue_wait", drain_t - r.submit_t
                 )
-            svc.events.emit(
-                "wave", cls, subsystem=svc.telemetry.name,
-                # one representative trace_id keeps the event slim; the
-                # wave span above carries the fuller list
-                trace_id=next((r.trace_id for g in by_root.values()
-                               for r in g if r.trace_id), ""),
-                args={"roots": len(roots), "engine_waves": engine_waves,
-                      "riders": n_riders,
-                      "duration_ms": round(dt_engine * 1e3, 3)})
-            n_calls = max(1, (engine_waves if cls != "bfs"
-                              else -(-len(roots) // self.wave_width(cls))))
-            self._est[cls] = (
-                0.7 * self._est[cls]
-                + 0.3 * dt_engine / n_calls
+                svc.telemetry.record_stage("coalesce", t0 - drain_t)
+                if tracer.enabled:
+                    tracer.add_span(
+                        f"queue-wait:{r.algo}", r.submit_t, drain_t,
+                        track="queue", trace_id=r.trace_id,
+                        args={"algo": r.algo, "root": r.root},
+                    )
+                    tracer.add_span(
+                        f"coalesce:{cls}", drain_t, t0,
+                        track="scheduler", trace_id=r.trace_id,
+                        args={"algo": r.algo, "root": r.root},
+                    )
+        results, engine_waves, offered = self._execute(
+            engine, epoch, cls, roots
+        )
+        dt_engine = time.monotonic() - t0
+        svc.telemetry.record_stage("engine", dt_engine)
+        if tracer.enabled:
+            span.args.update(
+                roots=len(roots), engine_waves=engine_waves,
+                riders=n_riders,
+                trace_ids=[r.trace_id for g in by_root.values()
+                           for r in g][:8],
             )
-            svc.telemetry.record_dispatch(
-                engine_waves=engine_waves,
-                lanes_used=len(roots),
-                lanes_offered=offered,
-                coalesced_roots=n_riders,
-            )
+        svc.events.emit(
+            "wave", cls, subsystem=svc.telemetry.name,
+            # one representative trace_id keeps the event slim; the
+            # dispatch span carries the fuller list
+            trace_id=next((r.trace_id for g in by_root.values()
+                           for r in g if r.trace_id), ""),
+            args={"roots": len(roots), "engine_waves": engine_waves,
+                  "riders": n_riders,
+                  "duration_ms": round(dt_engine * 1e3, 3)})
+        n_calls = max(1, (engine_waves if cls != "bfs"
+                          else -(-len(roots) // self.wave_width(cls))))
+        self._est[cls] = (
+            0.7 * self._est[cls]
+            + 0.3 * dt_engine / n_calls
+        )
+        svc.telemetry.record_dispatch(
+            engine_waves=engine_waves,
+            lanes_used=len(roots),
+            lanes_offered=offered,
+            coalesced_roots=n_riders,
+        )
+        with tracer.span("answer", track="scheduler"):
             for root in roots:
                 for r in by_root[root]:
                     self._resolve(
@@ -340,6 +367,10 @@ class WaveScheduler:
         ``(root -> raw result, engine_waves, lanes_offered)`` and caches
         raw results under the dispatch epoch."""
         svc = self.service
+
+        def cache_put():  # one live span per engine call's results
+            return svc.tracer.span("cache-put", track="scheduler")
+
         results = {}
         w0 = engine.stats.waves
         offered = 0
@@ -348,33 +379,36 @@ class WaveScheduler:
             for lo in range(0, len(roots), chunk):
                 part = roots[lo : lo + chunk]
                 dist = engine.query(part)
-                for root, row in zip(part, dist):
-                    row = row.copy()  # a view would pin the whole wave
-                    results[root] = row
-                    svc.cache.put(
-                        result_key(epoch, "bfs", engine.cfg, root), row
-                    )
+                with cache_put():
+                    for root, row in zip(part, dist):
+                        row = row.copy()  # a view would pin the whole wave
+                        results[root] = row
+                        svc.cache.put(
+                            result_key(epoch, "bfs", engine.cfg, root), row
+                        )
                 offered += engine.lanes * max(
                     1, -(-len(part) // engine.lanes)
                 )
             waves = engine.stats.waves - w0
         elif cls == "sssp":
             rows = engine.sssp(roots, svc.sssp_cfg)
-            for root, row in zip(roots, rows):
-                row = row.copy()  # a view would pin the whole batch
-                results[root] = row
-                svc.cache.put(
-                    result_key(epoch, "sssp", svc.sssp_cfg, root), row
-                )
+            with cache_put():
+                for root, row in zip(roots, rows):
+                    row = row.copy()  # a view would pin the whole batch
+                    results[root] = row
+                    svc.cache.put(
+                        result_key(epoch, "sssp", svc.sssp_cfg, root), row
+                    )
             waves = len(roots)  # one compiled min-reduce run per root
             offered = len(roots)
         elif cls == "bc":
             for root in roots:
                 vec = engine.betweenness([root])
                 results[root] = vec
-                svc.cache.put(
-                    result_key(epoch, "bc", engine.cfg, root), vec
-                )
+                with cache_put():
+                    svc.cache.put(
+                        result_key(epoch, "bc", engine.cfg, root), vec
+                    )
             waves = engine.stats.waves - w0
             offered = engine.lanes * len(roots)
         elif cls in PROGRAM_ALGOS:
@@ -382,9 +416,10 @@ class WaveScheduler:
             # 0 at submit) resolves from the same converged vector
             cfg = svc.program_cfg
             vec = engine.vertex_program(cls, cfg)
-            for root in roots:
-                results[root] = vec
-                svc.cache.put(result_key(epoch, cls, cfg, root), vec)
+            with cache_put():
+                for root in roots:
+                    results[root] = vec
+                    svc.cache.put(result_key(epoch, cls, cfg, root), vec)
             waves = 1
             offered = 1
         else:  # pragma: no cover

@@ -161,8 +161,12 @@ class PercentileReservoir:
 #: per-request lifecycle stages with their own latency reservoirs
 #: (DESIGN.md §18): time spent queued before the scheduler drained the
 #: request, linger inside the coalescing window, the engine-execution
-#: window of its wave, and the device-repair portion of a mutation batch.
-STAGES = ("queue_wait", "coalesce", "engine", "repair")
+#: window of its wave, and the device-repair portion of a mutation batch;
+#: and, once per scheduler dispatch, its whole duration (``dispatch``)
+#: and the part of it spent blocked on the device's waves
+#: (``device_wait``), whose difference is the host's share of a dispatch.
+STAGES = ("queue_wait", "coalesce", "engine", "repair", "dispatch",
+          "device_wait")
 
 #: every counter a Telemetry carries, as events of ONE registry family
 #: (``service_events_total{service=..., event=...}``)

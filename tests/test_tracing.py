@@ -67,6 +67,38 @@ def test_span_context_manager_annotates_exceptions():
     assert ev["args"]["error"] == "KeyError"
 
 
+def test_live_spans_carry_their_parent_on_the_same_thread():
+    tr = Tracer(clock=lambda: 0.0)
+    seen = {}
+
+    def other():  # a span on another thread has no parent here
+        with tr.span("elsewhere") as sp:
+            seen["thread"] = sp
+
+    with tr.span("outer"):
+        with tr.span("inner"):
+            t = threading.Thread(target=other)
+            t.start()
+            t.join(timeout=30)
+        with pytest.raises(KeyError):
+            with tr.span("failed"):
+                raise KeyError("x")
+    tr.add_span("after-the-fact", 0.0, 0.0)
+    assert not t.is_alive()
+    ev = {e["name"]: e for e in tr.events()}
+    outer = ev["outer"]["span_id"]
+    assert ev["outer"]["parent_id"] == ""
+    assert ev["inner"]["parent_id"] == outer
+    assert ev["failed"]["parent_id"] == outer
+    assert ev["failed"]["args"]["error"] == "KeyError"
+    assert ev["elsewhere"]["parent_id"] == ""
+    assert ev["after-the-fact"]["parent_id"] == ""
+    doc = tr.to_chrome()
+    assert validate_schema(doc, _schema()) == []
+    inner = [r for r in doc["traceEvents"] if r["name"] == "inner"][0]
+    assert inner["args"]["parent_id"] == outer
+
+
 def test_negative_duration_clamped_to_zero():
     tr = Tracer(clock=lambda: 0.0)
     tr.add_span("backwards", 2.0, 1.0)
