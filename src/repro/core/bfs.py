@@ -144,13 +144,18 @@ def _sync_frontier(words: jax.Array, cfg: BFSConfig) -> jax.Array:
 
 def _expand_push(arrays, frontier_words, n_words, use_pallas, meta=None, *,
                  lanes=False, interpret=False):
-    """Top-down: scatter frontier bits along owned out-edges (paper Alg. 2
-    phase 1).  Returns the node's 'global queue' bitmap.
+    """Top-down: propagate frontier bits along the graph's edges (paper
+    Alg. 2 phase 1).  Returns the node's 'global queue' bitmap.
 
-    ``lanes=False``: vertex-packed ``uint32[n_words]`` (single-source).
+    ``lanes=False``: vertex-packed ``uint32[n_words]`` (single-source).  An
+    owned vertex joins when any of its in-edges starts in the frontier: one
+    prefix count over the dst-sorted in-edges, read at ``in_offsets``
+    (:func:`fr.segment_or`), so no per-edge scatter.  Only the node's owned
+    window is set; phase 2 ORs the windows together.
     ``lanes=True``: lane-packed ``uint32[n_words, B/32]`` rows — the same
     traversal bit-parallel over B concurrent searches (``analytics.msbfs``),
-    where ``n_words`` counts vertex ROWS and merge is a per-row lane-mask OR.
+    where ``n_words`` counts vertex ROWS and merge is a per-row lane-mask OR
+    scattered along the owned out-edges.
     """
     if use_pallas:
         if lanes:
@@ -160,13 +165,14 @@ def _expand_push(arrays, frontier_words, n_words, use_pallas, meta=None, *,
 
         return kops.expand_push_pallas(frontier_words, arrays, meta, n_words,
                                        interpret=interpret)
+    if not lanes:
+        active = fr.get_bits(frontier_words, arrays["in_src"])
+        return fr.segment_or(n_words, arrays["in_offsets"], active,
+                             arrays["word_start"])
     src, dst = arrays["edge_src"], arrays["edge_dst"]
     mask = jnp.arange(src.shape[0], dtype=jnp.int32) < arrays["edge_count"]
-    if lanes:
-        active = jnp.where(mask[:, None], frontier_words[src], jnp.uint32(0))
-        return fr.scatter_or_lanes(n_words, dst, active)
-    active = fr.get_bits(frontier_words, src) & mask
-    return fr.scatter_or(n_words, dst, active)
+    active = jnp.where(mask[:, None], frontier_words[src], jnp.uint32(0))
+    return fr.scatter_or_lanes(n_words, dst, active)
 
 
 def _expand_pull(arrays, frontier_words, visited_words, n_words, use_pallas,
@@ -396,6 +402,7 @@ _ARRAY_KEYS = (
     "in_dst",
     "in_count",
     "deg_out",
+    "in_offsets",
 )
 
 

@@ -180,3 +180,59 @@ def scatter_or(n_words: int, idx: jax.Array, active: jax.Array) -> jax.Array:
     dense = jnp.zeros((n_words * WORD_BITS,), jnp.bool_)
     dense = dense.at[idx].max(active, mode="drop")
     return pack(dense)
+
+
+_SCAN_WIDTH = 128  # entries per row of the prefix count: one MXU tile
+
+
+def prefix_count(bits: jax.Array) -> jax.Array:
+    """``bool[n] -> int32[n]``: inclusive prefix count of the set entries.
+
+    Each row of 128 entries is scanned by a matmul with an upper-triangular
+    matrix of ones, and each row is offset by the prefix sum of the row
+    totals before it, recursively.  The matmuls take bfloat16 operands of
+    at most 8 bits (larger totals go byte by byte) and accumulate in
+    float32, so every partial sum is an integer below 2**24 and exact.
+    XLA's cumsum lowers on TPU to a reduce-window scan: with it the
+    scale-21 single-source program took 58 s to compile for a TPU v5e,
+    with this form 6 s.
+    """
+    return _prefix_sum(bits.astype(jnp.int32), 1)
+
+
+def _prefix_sum(values: jax.Array, bound: int) -> jax.Array:
+    """Inclusive prefix sum of ``int32[n]`` values in ``[0, bound]``, for
+    sums below 2**31."""
+    n = values.shape[0]
+    rows = -(-n // _SCAN_WIDTH)
+    x = jnp.pad(values, (0, rows * _SCAN_WIDTH - n)).reshape(rows, _SCAN_WIDTH)
+    ones = jnp.triu(jnp.ones((_SCAN_WIDTH, _SCAN_WIDTH), jnp.bfloat16))
+    within = jnp.zeros(x.shape, jnp.int32)
+    for shift in range(0, bound.bit_length(), 8):
+        digit = ((x >> shift) & 255).astype(jnp.bfloat16)
+        part = jnp.dot(digit, ones, preferred_element_type=jnp.float32)
+        within += part.astype(jnp.int32) << shift
+    if rows > 1:
+        totals = within[:, -1]
+        before = _prefix_sum(totals, bound * min(n, _SCAN_WIDTH)) - totals
+        within += before[:, None]
+    return within.reshape(-1)[:n]
+
+
+def segment_or(n_words: int, offsets: jax.Array, active: jax.Array,
+               word_start) -> jax.Array:
+    """Build a bitmap with bit ``32 * word_start + v`` set where any of
+    ``active[offsets[v]:offsets[v + 1]]`` is set.
+
+    The scatter-free form of :func:`scatter_or` for indices sorted into
+    runs, one per vertex of an aligned window (``offsets`` is
+    ``int32[k * 32 + 1]``, non-decreasing): one prefix count over
+    ``active``, one gather at the sorted run ends, and the window's packed
+    words written in place.  Entries past ``offsets[-1]`` are never read.
+    """
+    count = prefix_count(active)
+    count = jnp.concatenate([jnp.zeros((1,), jnp.int32), count])
+    at = count[offsets]
+    window = pack(at[1:] > at[:-1])
+    return lax.dynamic_update_slice(
+        jnp.zeros((n_words,), _U32), window, (word_start,))

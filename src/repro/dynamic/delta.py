@@ -13,12 +13,14 @@ Two mutable views are kept in lock-step:
 
 * **partitioned view** (:func:`apply_update_to_partition`) — the stacked
   ``[P, emax]`` device-shape arrays of a
-  :class:`~repro.graph.partition.PartitionedGraph`.  Inserts append into
-  each owner shard's static slack (``edge_count`` / ``in_count`` grow, the
+  :class:`~repro.graph.partition.PartitionedGraph`.  Inserts go into each
+  owner shard's static slack (``edge_count`` / ``in_count`` grow, the
   array SHAPES never change, so compiled programs are reused); deletions
-  compact the matching slots out of the active prefix.  The traversal
-  kernels never depend on edge ORDER (scatter-OR / scatter-MIN are
-  order-free), so appended edges traverse exactly like rebuilt ones.
+  compact the matching slots out of the active prefix.  Out-edges are
+  appended: the kernels that read them (scatter-OR / scatter-MIN) are
+  order-free.  In-edges are merged into their (dst, src) position and the
+  shard's ``in_offsets`` recomputed, because single-source top-down
+  expansion reduces each destination's contiguous run (DESIGN.md §3).
   When a shard's slack is exhausted the update is refused untouched and
   the caller falls back to compaction + repartition (a §15 full swap).
 """
@@ -356,6 +358,34 @@ def _owners(pg, vids: np.ndarray) -> np.ndarray:
     return np.searchsorted(pg.v_start, vids, side="right") - 1
 
 
+def _dst_src_key(dst: np.ndarray, src: np.ndarray) -> np.ndarray:
+    return (dst.astype(np.int64) << 32) | src.astype(np.int64)
+
+
+def _merge_in_edges(pg, i: int, src, dst, w) -> None:
+    """Insert in-edges into shard ``i``'s active prefix at their (dst, src)
+    position; duplicates land beside the slot they repeat."""
+    act = int(pg.in_count[i])
+    add_key = _dst_src_key(dst, src)
+    order = np.argsort(add_key, kind="stable")
+    at = np.searchsorted(_dst_src_key(pg.in_dst[i, :act], pg.in_src[i, :act]),
+                         add_key[order], side="right")
+    end = act + at.size
+    pg.in_src[i, :end] = np.insert(pg.in_src[i, :act], at, src[order])
+    pg.in_dst[i, :end] = np.insert(pg.in_dst[i, :act], at, dst[order])
+    if w is not None:
+        pg.in_weight[i, :end] = np.insert(pg.in_weight[i, :act], at, w[order])
+    pg.in_count[i] = end
+
+
+def _refresh_in_offsets(pg, i: int) -> None:
+    """Shard ``i``'s in-edge row offsets from its sorted active prefix;
+    slots past ``v_count`` get ``in_count`` (empty runs)."""
+    vids = int(pg.v_start[i]) + np.arange(pg.vmax + 1, dtype=np.int64)
+    pg.in_offsets[i] = np.searchsorted(pg.in_dst[i, : int(pg.in_count[i])],
+                                       vids, side="left")
+
+
 def apply_update_to_partition(pg, update: AppliedUpdate) -> bool:
     """Apply an :class:`AppliedUpdate` to the stacked ``[P, emax]`` arrays
     IN PLACE (host side; callers re-place on device afterwards).
@@ -363,11 +393,12 @@ def apply_update_to_partition(pg, update: AppliedUpdate) -> bool:
     Returns ``False`` — with every array untouched — when any shard's
     static slack cannot hold its inserts (the compaction trigger).
     Inserted directed edge ``(u, v)`` appends to ``owner(u)``'s out buffer
-    and ``owner(v)``'s in buffer; weight-lowerings append a duplicate slot
-    (scatter-MIN keeps the lower proposal, so duplicates are harmless and
-    cheaper than an in-place search); deletions compact every matching
-    slot out of the active prefix.  ``deg_out`` tracks the DEDUPLICATED
-    out-degree (weight-lowerings don't count)."""
+    and merges into ``owner(v)``'s (dst, src)-sorted in buffer, whose
+    ``in_offsets`` are then recomputed; weight-lowerings add a duplicate
+    slot (scatter-MIN keeps the lower proposal, so duplicates are harmless
+    and cheaper than an in-place search); deletions compact every matching
+    slot out of the active prefix, keeping its order.  ``deg_out`` tracks
+    the DEDUPLICATED out-degree (weight-lowerings don't count)."""
     ins_u, ins_v = update.ins_src, update.ins_dst
     out_own = _owners(pg, ins_u)
     in_own = _owners(pg, ins_v)
@@ -399,14 +430,12 @@ def apply_update_to_partition(pg, update: AppliedUpdate) -> bool:
                 1,
             )
         sel = in_own == i
-        k = int(sel.sum())
-        if k:
-            lo = int(pg.in_count[i])
-            pg.in_src[i, lo : lo + k] = ins_u[sel]
-            pg.in_dst[i, lo : lo + k] = ins_v[sel]
-            if weighted:
-                pg.in_weight[i, lo : lo + k] = update.ins_w[sel]
-            pg.in_count[i] += k
+        if sel.any():
+            _merge_in_edges(pg, i, ins_u[sel], ins_v[sel],
+                            update.ins_w[sel] if weighted else None)
+
+    in_touched = np.zeros(pg.p, bool)
+    in_touched[in_own] = True
 
     # -- deletes: compact matching slots out of the active prefix ---------
     if update.del_src.size:
@@ -444,6 +473,9 @@ def apply_update_to_partition(pg, update: AppliedUpdate) -> bool:
                     (del_u[sel] - pg.v_start[i]).astype(np.int64),
                     -1,
                 )
+        in_touched[d_in] = True
+    for i in np.flatnonzero(in_touched):
+        _refresh_in_offsets(pg, i)
     return True
 
 

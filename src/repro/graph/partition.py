@@ -11,10 +11,11 @@ that, with two TPU-specific refinements:
   static shapes) and stacked into ``[P, Emax]`` so a single ``shard_map``
   consumes them with the leading axis sharded over the device mesh.
 
-Out-edges are kept sorted by (src, dst) — gather locality for top-down —
-and in-edges sorted by (dst, src) — scatter locality for bottom-up (the
-degree-uniform layout that stands in for the paper's LRB load balancing,
-see DESIGN.md Sec. 3).
+Out-edges are kept sorted by (src, dst) and in-edges by (dst, src), with
+``in_offsets`` marking where each owned vertex's in-edges start:
+single-source top-down reduces each owned vertex's contiguous run of
+in-edges (the degree-uniform layout that stands in for the paper's LRB
+load balancing, see DESIGN.md Sec. 3).
 """
 
 from __future__ import annotations
@@ -53,6 +54,10 @@ class PartitionedGraph:
     in_dst: np.ndarray  # int32[P, emax]
     in_count: np.ndarray  # int32[P]
     deg_out: np.ndarray  # int32[P, vmax]  out-degree of owned vertices
+    # int32[P, vmax + 1]  shard-local in-edge row offsets of the owned slots:
+    # slot v's in-edges are in_*[i, in_offsets[i, v]:in_offsets[i, v + 1]];
+    # slots past v_count hold in_count (empty runs)
+    in_offsets: np.ndarray
     # uint32[P, emax] edge weights, partitioned alongside dst (out view) and
     # src (in view); None for unweighted graphs (DESIGN.md §14).
     edge_weight: Optional[np.ndarray] = None
@@ -79,6 +84,7 @@ class PartitionedGraph:
             in_dst=self.in_dst,
             in_count=self.in_count,
             deg_out=self.deg_out,
+            in_offsets=self.in_offsets,
         )
         if self.edge_weight is not None:
             out["edge_weight"] = self.edge_weight
@@ -122,6 +128,7 @@ class SyntheticShapes:
             in_dst=(p, emax),
             in_count=(p,),
             deg_out=(p, vmax),
+            in_offsets=(p, vmax + 1),
         )
 
 
@@ -178,6 +185,7 @@ def partition_1d(g: csr.Graph, p: int, *, lane_pad: int = 128) -> PartitionedGra
     in_src = np.zeros((p, emax), dtype=np.int32)
     in_dst = np.zeros((p, emax), dtype=np.int32)
     deg_out = np.zeros((p, vmax), dtype=np.int32)
+    local_in_offsets = np.zeros((p, vmax + 1), dtype=np.int32)
     edge_weight = np.zeros((p, emax), dtype=np.uint32) if g.weighted else None
     in_weight = np.zeros((p, emax), dtype=np.uint32) if g.weighted else None
     degrees = g.out_degree
@@ -193,6 +201,9 @@ def partition_1d(g: csr.Graph, p: int, *, lane_pad: int = 128) -> PartitionedGra
         if g.weighted:
             in_weight[i, : e - s] = in_w_all[s:e]
         deg_out[i, : v_count[i]] = degrees[v_start[i] : v_end[i]]
+        local_in_offsets[i, : v_count[i] + 1] = (
+            in_offsets[v_start[i] : v_end[i] + 1] - ie_lo[i])
+        local_in_offsets[i, v_count[i] + 1 :] = in_count[i]
 
     # Exchanged bitmap length: whole graph + one device window of slack so
     # every device can dynamic-slice its aligned [word_start, word_start+wmax)
@@ -218,6 +229,7 @@ def partition_1d(g: csr.Graph, p: int, *, lane_pad: int = 128) -> PartitionedGra
         in_dst=in_dst,
         in_count=in_count,
         deg_out=deg_out,
+        in_offsets=local_in_offsets,
         edge_weight=edge_weight,
         in_weight=in_weight,
     )

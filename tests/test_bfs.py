@@ -164,3 +164,91 @@ def test_rabenseifner_frontier_sync(mesh8):
     np.testing.assert_array_equal(_norm(d), _norm(ref))
     d, _, _ = _dist(pg, mesh8, 3, sync="rabenseifner", fanout=4)
     np.testing.assert_array_equal(_norm(d), _norm(ref))
+
+
+# --- single-source top-down: the dst-sorted segmented expansion -------------
+
+
+def _isolated_graph():
+    """Two small components and zero-degree vertices: 14-29 and 32-59 have
+    no edges, and 60-63 are the padding up to a multiple of 32."""
+    src = np.array([0, 1, 2, 3, 10, 11, 12, 30])
+    dst = np.array([1, 2, 3, 0, 11, 12, 13, 31])
+    return csr.from_edges(src, dst, 60)
+
+
+TOP_DOWN_GRAPHS = {  # name -> (graph, root); None: a largest-component root
+    "kron9": (lambda: generators.kronecker(9, 8, seed=4), None),
+    "torus12": (lambda: generators.torus_2d(12), 5),
+    "path150": (lambda: generators.path_graph(150), 0),
+    "isolated": (_isolated_graph, 10),
+    "isolated_root": (_isolated_graph, 45),
+}
+
+
+@pytest.mark.parametrize("mode", ["top_down", "direction_optimizing"])
+@pytest.mark.parametrize("sync", ["butterfly", "adaptive"])
+@pytest.mark.parametrize("p", [1, 2, 8])
+@pytest.mark.parametrize("name", list(TOP_DOWN_GRAPHS))
+def test_single_source_top_down_matches_reference(name, p, sync, mode):
+    """Top-down expansion reduces each owned vertex's run of dst-sorted
+    in-edges; distances equal the oracle on every layout, with padding
+    edges past every shard's ``in_count``."""
+    make, root = TOP_DOWN_GRAPHS[name]
+    g = make()
+    if root is None:
+        root = csr.largest_component_root(g, np.random.default_rng(0))
+    pg = partition.partition_1d(g, p, lane_pad=1024)
+    assert np.all(pg.in_count < pg.emax)
+    mesh = jax.make_mesh((p,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
+    d, _, _ = _dist(pg, mesh, root, sync=sync, mode=mode)
+    np.testing.assert_array_equal(_norm(d), _norm(bfs.bfs_reference(g, root)))
+
+
+@pytest.mark.parametrize("n_words,wmax,seed", [
+    (128, 1, 0), (128, 4, 1), (256, 32, 2), (1024, 128, 3)])
+def test_segment_or_matches_scatter_or(n_words, wmax, seed):
+    """The scatter-free segmented OR equals a scatter-OR of the same sorted
+    runs; entries past the last run (padding) are never read."""
+    import jax.numpy as jnp
+
+    from repro.core import frontier as fr
+
+    rng = np.random.default_rng(seed)
+    vmax = wmax * 32
+    word_start = int(rng.integers(0, n_words - wmax + 1))
+    deg = rng.integers(0, 4, vmax) * (rng.random(vmax) < 0.7)
+    count = int(deg.sum())
+    emax = count + int(rng.integers(1, 64))
+    dst = np.full(emax, n_words * 32, np.int32)  # padding: dropped below
+    dst[:count] = word_start * 32 + np.repeat(np.arange(vmax), deg)
+    offsets = np.concatenate([[0], np.cumsum(deg)]).astype(np.int32)
+    active = rng.random(emax) < 0.3
+    want = fr.scatter_or(n_words, jnp.asarray(dst), jnp.asarray(active))
+    got = fr.segment_or(n_words, jnp.asarray(offsets), jnp.asarray(active),
+                        jnp.int32(word_start))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("n,bound", [
+    (1, 1), (127, 1), (129, 1), (16385, 1), (2_200_000, 1),
+    (30_000, 60_000), (1_000, 2_000_000)])
+def test_prefix_count_exact(n, bound):
+    """The matmul prefix sum is exact: one level per 128 entries, and
+    totals past 8 bits byte by byte."""
+    import jax.numpy as jnp
+
+    from repro.core import frontier as fr
+
+    rng = np.random.default_rng(n)
+    if bound == 1:
+        bits = rng.random(n) < 0.5
+        got = fr.prefix_count(jnp.asarray(bits))
+        want = np.cumsum(bits)
+    else:
+        vals = rng.integers(0, bound + 1, n).astype(np.int32)
+        got = fr._prefix_sum(jnp.asarray(vals), bound)
+        want = np.cumsum(vals)
+    np.testing.assert_array_equal(np.asarray(got), want)
+

@@ -170,6 +170,55 @@ def test_partition_patch_matches_materialized(graph_w):
         np.testing.assert_array_equal(pg.deg_out[i, :c], deg[s : s + c])
 
 
+def _in_edge_batches(g, ov, kind, rng):
+    """Three batches of one kind against the overlay's current edge set;
+    on a weighted graph the inserts also lower an existing edge's weight
+    (a duplicate in-edge slot)."""
+    n_ins, n_del = {"insert": (12, 0), "delete": (0, 10),
+                    "mixed": (10, 8)}[kind]
+    for _ in range(3):
+        b = ov.sample_batch(rng, n_ins, n_del,
+                            max_weight=8 if g.weighted else 0)
+        if g.weighted and n_ins:
+            e = int(np.flatnonzero(g.weights > 1)[0])
+            b = delta.EdgeBatch(
+                insert_src=np.append(b.insert_src, g.src[e]),
+                insert_dst=np.append(b.insert_dst, g.dst[e]),
+                insert_weights=np.append(b.insert_weights, 1),
+                delete_src=b.delete_src, delete_dst=b.delete_dst)
+        yield ov.apply(b)
+
+
+@pytest.mark.parametrize("kind", ["insert", "delete", "mixed"])
+@pytest.mark.parametrize("weighted", [False, True])
+def test_partition_patch_keeps_in_edges_sorted(graph_u, graph_w, mesh8,
+                                               weighted, kind):
+    """After inserts and deletes every shard's active in-edges stay sorted
+    by (dst, src), its ``in_offsets`` equal a recount, and single-source
+    top-down BFS on the patched partition matches the oracle on the
+    mutated graph."""
+    g = graph_w if weighted else graph_u
+    pg = partition.partition_1d(g, 8)
+    ov = delta.DeltaOverlay(g)
+    for upd in _in_edge_batches(g, ov, kind, np.random.default_rng(4)):
+        assert delta.apply_update_to_partition(pg, upd)
+    for i in range(pg.p):
+        c = int(pg.in_count[i])
+        key = (pg.in_dst[i, :c].astype(np.int64) << 32) | pg.in_src[i, :c]
+        assert np.all(np.diff(key) >= 0), i
+        runs = np.bincount(pg.in_dst[i, :c] - pg.v_start[i],
+                           minlength=pg.vmax)
+        np.testing.assert_array_equal(
+            pg.in_offsets[i], np.concatenate([[0], np.cumsum(runs)]),
+            err_msg=f"shard {i}")
+    gm = ov.current_graph()
+    root = int(csr.largest_component_root(gm, np.random.default_rng(0)))
+    d, _, _ = bfs.distributed_bfs(pg, mesh8, root,
+                                  bfs.BFSConfig(axes=("data",)))
+    np.testing.assert_array_equal(_norm(d),
+                                  _norm(bfs.bfs_reference(gm, root)))
+
+
 def test_partition_patch_overflow_refused_atomically(graph_u):
     g = graph_u
     pg = partition.partition_1d(g, 8)

@@ -12,6 +12,7 @@ import time would make pytest-xdist workers collect different tests.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -77,8 +78,63 @@ def _fits(compiled):
     return m
 
 
+_CALLED = re.compile(r"(?:calls|to_apply|body|condition|true_computation|"
+                     r"false_computation)=(%[\w.\-]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+
+
+def _loop_ops(text, opcode):
+    """The ``opcode`` instructions that the level loop runs: every
+    instruction of each ``while`` body and of the computations it calls,
+    fusions included, as ``(name, op_name)`` pairs."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None and line.startswith("  "):
+            comps[name].append(line)
+    todo = [m.group(1) for lines in comps.values() for line in lines
+            if " while(" in line
+            for m in [re.search(r"body=(%[\w.\-]+)", line)]]
+    seen, found = set(), []
+    while todo:
+        comp = todo.pop()
+        if comp in seen:
+            continue
+        seen.add(comp)
+        for line in comps[comp]:
+            todo += _CALLED.findall(line)
+            for group in _BRANCHES.findall(line):
+                todo += [c.strip() for c in group.split(",")]
+            op = re.match(r"\s+(?:ROOT )?(%[\w.\-]+) = .*? " + opcode
+                          + r"\(", line)
+            if op:
+                where = re.search(r'op_name="([^"]*)"', line)
+                found.append((op.group(1), where.group(1) if where else ""))
+    assert seen, "no while loop in the compiled program"
+    return found
+
+
+def _assert_no_scatter_expansion(compiled, p):
+    """Single-source top-down reduces dst-sorted in-edges: no sort and no
+    scatter in the level loop, apart from the adaptive exchange's sparse
+    receive on P > 1 (the root's ``set_bit`` runs before the loop)."""
+    text = compiled.as_text()
+    assert _loop_ops(text, "sort") == []
+    scatters = _loop_ops(text, "scatter")
+    if p == 1:
+        assert scatters == []
+    assert all("traversal.exchange" in where for _, where in scatters), (
+        scatters)
+    assert _loop_ops(text, "gather"), "the frontier-bit gather is missing"
+
+
 def test_single_source_bfs_compiles_one_chip_scale21(topo):
-    _fits(_compile(topo, bfs.build_bfs_fn, 21, 1, ()))
+    compiled = _compile(topo, bfs.build_bfs_fn, 21, 1, ())
+    _fits(compiled)
+    _assert_no_scatter_expansion(compiled, 1)
 
 
 def test_single_source_bfs_compiles_four_chip_mesh_scale22(topo):
@@ -86,6 +142,7 @@ def test_single_source_bfs_compiles_four_chip_mesh_scale22(topo):
     _fits(compiled)
     # the butterfly exchange is compiled in: rounds of ppermute
     assert "collective-permute" in compiled.as_text()
+    _assert_no_scatter_expansion(compiled, 4)
 
 
 def test_served_32_lane_wave_compiles_one_chip_scale21(topo):
