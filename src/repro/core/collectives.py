@@ -300,6 +300,10 @@ def butterfly_or_adaptive(
     fallback), dense otherwise.  The two scalar ``pmax`` reductions ride the
     wire as a handful of bytes; both branches live in the compiled HLO and
     ``lax.cond`` picks one per level at run time.
+
+    Each branch stages under its own ``jax.named_scope`` (``sparse``,
+    ``dense``), so a compiled op's ``op_name`` and a device trace tell
+    which branch ran; a scope changes no staged op.
     """
     axes = _as_axes(axes)
     n_words = x.shape[0]
@@ -312,14 +316,17 @@ def butterfly_or_adaptive(
         nz = lax.pmax(nz, a)
     bits_limit = jnp.int32(density_threshold * n_words * fr.WORD_BITS)
     go_sparse = (pops <= bits_limit) & (nz <= cap)
-    return lax.cond(
-        go_sparse,
-        lambda w: butterfly_or_sparse(
-            w, axes, fanout=fanout, capacity=cap, fallback=False
-        ),
-        lambda w: butterfly_or(w, axes, fanout=fanout),
-        x,
-    )
+
+    def sparse(w):
+        with jax.named_scope("sparse"):
+            return butterfly_or_sparse(w, axes, fanout=fanout, capacity=cap,
+                                       fallback=False)
+
+    def dense(w):
+        with jax.named_scope("dense"):
+            return butterfly_or(w, axes, fanout=fanout)
+
+    return lax.cond(go_sparse, sparse, dense, x)
 
 
 # ---------------------------------------------------------------------------

@@ -7,6 +7,10 @@ that, with two TPU-specific refinements:
 
 * partition boundaries are rounded to multiples of 32 so each device's owned
   vertex range is a whole number of frontier-bitmap words;
+* the owned-vertex width ``vmax`` is rounded up to a multiple of
+  ``vertex_pad`` (default 32, one bitmap word).  An edge-balanced split
+  moves each device's vertex count with the graph; a coarser pad gives
+  every graph of one size the same shapes, and so one compiled program;
 * per-device edge arrays are padded to a common static shape (XLA needs
   static shapes) and stacked into ``[P, Emax]`` so a single ``shard_map``
   consumes them with the leading axis sharded over the device mesh.
@@ -92,8 +96,14 @@ class PartitionedGraph:
         return out
 
 
-def _round32(x: int) -> int:
-    return (x + WORD_BITS - 1) // WORD_BITS * WORD_BITS
+def _round_up(x: int, pad: int) -> int:
+    return (x + pad - 1) // pad * pad
+
+
+def _check_vertex_pad(vertex_pad: int) -> None:
+    if vertex_pad <= 0 or vertex_pad % WORD_BITS:
+        raise ValueError(f"vertex_pad must be a positive multiple of "
+                         f"{WORD_BITS}, got {vertex_pad}")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -133,22 +143,31 @@ class SyntheticShapes:
 
 
 def synthetic_shapes(n: int, m_directed: int, p: int, *, lane_pad: int = 128,
-                     slack: float = 1.15, vskew: float = 4.0) -> SyntheticShapes:
-    n_pad = _round32(n)
-    emax = int(m_directed / p * slack)
-    emax = (emax + lane_pad - 1) // lane_pad * lane_pad
-    vmax = _round32(int(n_pad / p * vskew))
+                     vertex_pad: int = WORD_BITS, slack: float = 1.15,
+                     vskew: float = 4.0) -> SyntheticShapes:
+    _check_vertex_pad(vertex_pad)
+    n_pad = _round_up(n, WORD_BITS)
+    emax = _round_up(int(m_directed / p * slack), lane_pad)
+    vmax = _round_up(int(n_pad / p * vskew), vertex_pad)
     wmax = vmax // WORD_BITS
-    n_words = n_pad // WORD_BITS + wmax
-    n_words = (n_words + lane_pad - 1) // lane_pad * lane_pad
+    n_words = _round_up(n_pad // WORD_BITS + wmax, lane_pad)
     return SyntheticShapes(
         p=p, n=n_pad, n_edges=m_directed, n_words=n_words,
         vmax=vmax, emax=emax, wmax=wmax,
     )
 
 
-def partition_1d(g: csr.Graph, p: int, *, lane_pad: int = 128) -> PartitionedGraph:
-    """Split vertices into ``p`` contiguous ranges with near-equal edges."""
+def partition_1d(g: csr.Graph, p: int, *, lane_pad: int = 128,
+                 vertex_pad: int = WORD_BITS) -> PartitionedGraph:
+    """Split vertices into ``p`` contiguous ranges with near-equal edges.
+
+    ``lane_pad`` rounds the edge width ``emax`` and the exchanged bitmap
+    length ``n_words``; ``vertex_pad`` (a positive multiple of 32) rounds
+    the owned-vertex width ``vmax``, and the window ``wmax`` follows.
+    Slots past a device's ``v_count`` stay empty: out-degree 0 and an
+    empty in-edge run.
+    """
+    _check_vertex_pad(vertex_pad)
     if not g._validated:  # corrupt inputs fail here, not as wrong traversals
         g.validate()
     cum = g.row_offsets  # int64[n+1], cumulative out-degree
@@ -156,7 +175,7 @@ def partition_1d(g: csr.Graph, p: int, *, lane_pad: int = 128) -> PartitionedGra
     for i in range(1, p):
         target = g.n_edges * i // p
         b = int(np.searchsorted(cum, target, side="left"))
-        b = min(max(_round32(b), bounds[-1]), g.n)
+        b = min(max(_round_up(b, WORD_BITS), bounds[-1]), g.n)
         bounds.append(b)
     bounds.append(g.n)
     v_start = np.array(bounds[:-1], dtype=np.int32)
@@ -174,10 +193,10 @@ def partition_1d(g: csr.Graph, p: int, *, lane_pad: int = 128) -> PartitionedGra
     ie_hi = in_offsets[v_end]
     in_count = (ie_hi - ie_lo).astype(np.int32)
 
-    emax = int(max(1, max(edge_count.max(initial=0), in_count.max(initial=0))))
-    emax = (emax + lane_pad - 1) // lane_pad * lane_pad
-    vmax = int(max(WORD_BITS, v_count.max(initial=0)))
-    vmax = _round32(vmax)
+    emax = _round_up(
+        int(max(1, edge_count.max(initial=0), in_count.max(initial=0))),
+        lane_pad)
+    vmax = _round_up(int(max(WORD_BITS, v_count.max(initial=0))), vertex_pad)
     wmax = vmax // WORD_BITS
 
     edge_src = np.zeros((p, emax), dtype=np.int32)
@@ -208,8 +227,7 @@ def partition_1d(g: csr.Graph, p: int, *, lane_pad: int = 128) -> PartitionedGra
     # Exchanged bitmap length: whole graph + one device window of slack so
     # every device can dynamic-slice its aligned [word_start, word_start+wmax)
     # window without clamping; padded to the 128-lane boundary.
-    n_words = g.n // WORD_BITS + wmax
-    n_words = (n_words + lane_pad - 1) // lane_pad * lane_pad
+    n_words = _round_up(g.n // WORD_BITS + wmax, lane_pad)
 
     return PartitionedGraph(
         p=p,

@@ -206,6 +206,56 @@ def test_single_source_top_down_matches_reference(name, p, sync, mode):
     np.testing.assert_array_equal(_norm(d), _norm(bfs.bfs_reference(g, root)))
 
 
+# --- four devices over a padded layout (partition_1d's vertex_pad) ----------
+
+
+PADDED_GRAPHS = {  # name -> (graph, root); None: a largest-component root
+    "kron10": (lambda: generators.kronecker(10, 8, seed=5), None),
+    "path300": (lambda: generators.path_graph(300), 7),
+    "isolated": (_isolated_graph, 10),
+}
+VERTEX_PAD = 512
+
+
+def _padded_p4(name):
+    make, root = PADDED_GRAPHS[name]
+    g = make()
+    if root is None:
+        root = csr.largest_component_root(g, np.random.default_rng(0))
+    pg = partition.partition_1d(g, 4, vertex_pad=VERTEX_PAD)
+    assert pg.vmax == VERTEX_PAD and pg.v_count.max() < pg.vmax
+    mesh = jax.make_mesh((4,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
+    return g, int(root), pg, mesh
+
+
+@pytest.mark.parametrize("sync", ["adaptive", "sparse", "butterfly"])
+@pytest.mark.parametrize("name", list(PADDED_GRAPHS))
+def test_single_source_p4_padded_layout_matches_reference(name, sync):
+    """Single-source BFS over four devices whose owned windows are padded
+    past ``v_count`` equals the oracle, whichever exchange merges them."""
+    g, root, pg, mesh = _padded_p4(name)
+    d, _, _ = _dist(pg, mesh, root, sync=sync, fanout=2)
+    np.testing.assert_array_equal(_norm(d), _norm(bfs.bfs_reference(g, root)))
+
+
+def test_p4_padded_adaptive_exchange_takes_both_branches():
+    """Across the padded four-device cases the adaptive exchange runs its
+    dense and its sparse branch (the flight recorder's branch column), and
+    the recorded traversals still equal the oracle."""
+    from repro.core import flightrec
+
+    cfg = bfs.BFSConfig(axes=("data",), sync="adaptive", fanout=2)
+    branches = set()
+    for name in PADDED_GRAPHS:
+        g, root, pg, mesh = _padded_p4(name)
+        d, levels, _, tr = flightrec.traced_bfs(pg, mesh, root, cfg)
+        np.testing.assert_array_equal(_norm(d),
+                                      _norm(bfs.bfs_reference(g, root)))
+        branches |= set(tr.data[:levels, flightrec.COL_BRANCH].tolist())
+    assert branches == {flightrec.BRANCH_DENSE, flightrec.BRANCH_SPARSE}
+
+
 @pytest.mark.parametrize("n_words,wmax,seed", [
     (128, 1, 0), (128, 4, 1), (256, 32, 2), (1024, 128, 3)])
 def test_segment_or_matches_scatter_or(n_words, wmax, seed):

@@ -219,6 +219,38 @@ def test_partition_patch_keeps_in_edges_sorted(graph_u, graph_w, mesh8,
                                   _norm(bfs.bfs_reference(gm, root)))
 
 
+@pytest.mark.parametrize("kind", ["insert", "mixed"])
+def test_partition_patch_keeps_vertex_pad(graph_u, kind):
+    """A partition whose owned-vertex width is padded (``vertex_pad``)
+    keeps that width and every array shape through updates; its padded
+    slots stay empty runs, and single-source BFS over four devices on the
+    patched partition matches the oracle on the mutated graph."""
+    import jax
+
+    g = graph_u
+    pg = partition.partition_1d(g, 4, lane_pad=4096, vertex_pad=512)
+    assert pg.vmax == 512 and pg.v_count.max() < pg.vmax
+    shapes = {k: v.shape for k, v in pg.arrays().items()}
+    ov = delta.DeltaOverlay(g)
+    for upd in _in_edge_batches(g, ov, kind, np.random.default_rng(6)):
+        assert delta.apply_update_to_partition(pg, upd)
+    assert pg.vmax == 512 and pg.wmax == 512 // 32
+    assert {k: v.shape for k, v in pg.arrays().items()} == shapes
+    for i in range(pg.p):
+        c = int(pg.v_count[i])
+        assert np.all(pg.in_offsets[i, c:] == pg.in_count[i]), i
+        assert np.all(pg.deg_out[i, c:] == 0), i
+    gm = ov.current_graph()
+    root = int(csr.largest_component_root(gm, np.random.default_rng(0)))
+    mesh4 = jax.make_mesh((4,), ("data",),
+                          axis_types=(jax.sharding.AxisType.Auto,))
+    d, _, _ = bfs.distributed_bfs(pg, mesh4, root,
+                                  bfs.BFSConfig(axes=("data",),
+                                                sync="adaptive"))
+    np.testing.assert_array_equal(_norm(d),
+                                  _norm(bfs.bfs_reference(gm, root)))
+
+
 def test_partition_patch_overflow_refused_atomically(graph_u):
     g = graph_u
     pg = partition.partition_1d(g, 8)

@@ -1,5 +1,7 @@
 """Graph substrate: ETL invariants, partitioning, generators."""
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -188,6 +190,57 @@ def test_synthetic_shapes_match_real_partition():
     ashapes = syn.array_shapes()
     real = pg.arrays()
     assert set(ashapes) == set(real)
+
+
+@pytest.mark.parametrize("vertex_pad", [512, 1024])
+def test_vertex_pad_gives_one_layout_across_seeds(vertex_pad):
+    """With ``vertex_pad`` the owned-vertex width no longer moves with the
+    graph: four seeds of one Kronecker size give one (vmax, emax, n_words)
+    at P = 4, and the slots past each device's ``v_count`` stay empty."""
+    layouts = set()
+    for seed in range(4):
+        g = generators.kronecker(10, 16, seed=seed)
+        pg = partition.partition_1d(g, 4, lane_pad=16384,
+                                    vertex_pad=vertex_pad)
+        layouts.add((pg.vmax, pg.emax, pg.n_words))
+        assert pg.vmax % vertex_pad == 0 and pg.vmax > pg.v_count.max()
+        assert pg.wmax == pg.vmax // 32
+        assert pg.n_words >= g.n // 32 + pg.wmax
+        assert pg.deg_out.shape == (4, pg.vmax)
+        assert pg.in_offsets.shape == (4, pg.vmax + 1)
+        for i in range(4):
+            c = int(pg.v_count[i])
+            assert np.all(pg.deg_out[i, c:] == 0)
+            assert np.all(pg.in_offsets[i, c:] == pg.in_count[i])
+    assert len(layouts) == 1, layouts
+
+
+@pytest.mark.parametrize("p", [1, 4, 8])
+@pytest.mark.parametrize("weighted", [False, True])
+def test_vertex_pad_32_is_the_default_layout(p, weighted):
+    """``vertex_pad=32`` (the default) rounds ``vmax`` to one bitmap word,
+    as the layout always has: array for array the same partition."""
+    g = generators.kronecker(10, 8, seed=5, max_weight=9 if weighted else 0)
+    base = partition.partition_1d(g, p)
+    pg = partition.partition_1d(g, p, vertex_pad=32)
+    assert base.vmax == -(-max(32, int(base.v_count.max())) // 32) * 32
+    for f in dataclasses.fields(partition.PartitionedGraph):
+        a, b = getattr(base, f.name), getattr(pg, f.name)
+        if isinstance(a, np.ndarray):
+            assert a.dtype == b.dtype and np.array_equal(a, b), f.name
+        else:
+            assert a == b, f.name
+    assert partition.synthetic_shapes(1 << 12, 1 << 16, p) == \
+        partition.synthetic_shapes(1 << 12, 1 << 16, p, vertex_pad=32)
+
+
+@pytest.mark.parametrize("vertex_pad", [0, -32, 16, 48, 100])
+def test_vertex_pad_not_a_multiple_of_32_raises(vertex_pad):
+    g = generators.kronecker(8, 8, seed=0)
+    with pytest.raises(ValueError, match="vertex_pad"):
+        partition.partition_1d(g, 4, vertex_pad=vertex_pad)
+    with pytest.raises(ValueError, match="vertex_pad"):
+        partition.synthetic_shapes(1 << 8, 1 << 12, 4, vertex_pad=vertex_pad)
 
 
 def test_largest_component_root():

@@ -69,6 +69,22 @@ def test_compiled_phases(pg8, mesh8, program, mode):
     assert moved and all("traversal.expand" in n for n in moved)
 
 
+def test_adaptive_exchange_branches_are_scoped(pg8, mesh8):
+    """The adaptive exchange's two branches carry their own scopes inside
+    ``traversal.exchange``: every permute of the compiled module is under
+    ``…/sparse/…`` or ``…/dense/…``, and both occur."""
+    cfg = bfs.BFSConfig(axes=("data",), sync="adaptive")
+    arrays = bfs.place_arrays(pg8, mesh8, cfg.axes)
+    text = bfs.build_bfs_fn(pg8, mesh8, cfg).lower(
+        arrays, jnp.int32(0)).compile().as_text()
+    permutes = [name for op, name in _instructions(text)
+                if op.startswith("collective-permute")]
+    branch = re.compile(r"traversal\.exchange/.*/(sparse|dense)/")
+    found = {m.group(1) for m in map(branch.search, permutes) if m}
+    assert found == {"sparse", "dense"}
+    assert all(branch.search(n) for n in permutes), permutes
+
+
 def test_phase_rejects_unknown_names():
     assert loop.PHASES == ("expand", "exchange", "update", "direction",
                            "cond")
