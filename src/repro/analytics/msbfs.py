@@ -136,7 +136,8 @@ def build_msbfs_fn(
 
             # -- Phase 1: lane-parallel traversal ------------------------
             def do_push(_):
-                return _expand_push(arrays, frontier, n_rows, False, lanes=True)
+                return _expand_push(arrays, frontier, n_rows, False,
+                                    lanes=True)[0]
 
             def do_pull(_):
                 return _expand_pull(

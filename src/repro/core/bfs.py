@@ -142,21 +142,92 @@ def _sync_frontier(words: jax.Array, cfg: BFSConfig) -> jax.Array:
     raise ValueError(f"unknown sync {cfg.sync!r}")
 
 
-def _expand_push(arrays, frontier_words, n_words, use_pallas, meta=None, *,
-                 lanes=False, interpret=False):
-    """Top-down: propagate frontier bits along the graph's edges (paper
-    Alg. 2 phase 1).  Returns the node's 'global queue' bitmap.
+def push_capacity(emax: int) -> int:
+    """``K``, the out-edge slots the sparse push expands in one level: a
+    thirty-second of a shard's edge slots.  A sparse level costs about
+    ``vmax + 3K`` random accesses against the in-edge scan's ``emax``."""
+    return emax // 32
 
-    ``lanes=False``: vertex-packed ``uint32[n_words]`` (single-source).  An
-    owned vertex joins when any of its in-edges starts in the frontier: one
-    prefix count over the dst-sorted in-edges, read at ``in_offsets``
-    (:func:`fr.segment_or`), so no per-edge scatter.  Only the node's owned
-    window is set; phase 2 ORs the windows together.
+
+def _push_sparse(arrays, run, n_words, n, capacity):
+    """Set the out-neighbours of the owned frontier vertices, whose runs of
+    (src, dst)-sorted out-edges have lengths ``run`` (``int32[vmax]``, 0
+    off the frontier) and fit ``capacity`` slots together; vertex ids are
+    below ``n``.
+
+    Slot ``s`` of the frontier's concatenated runs lies in the run of
+    vertex ``v`` and is edge ``s`` plus the out-edges of the vertices
+    before ``v`` that were not pushed.  Each vertex adds its unpushed
+    out-edges at its run's first slot, and a prefix sum over the slots
+    hands every slot its offset: no search, no sort.  Destinations may be
+    any vertex; phase 2 ORs the nodes' bitmaps."""
+    offsets = arrays["out_offsets"]
+    emax = arrays["edge_dst"].shape[0]
+    first = fr._prefix_sum(run, emax) - run
+    unpushed = offsets[1:] - offsets[:-1] - run
+    slot = jnp.arange(capacity, dtype=jnp.int32)
+    edge = slot + fr._prefix_sum(
+        fr.scatter_int32(capacity, first, unpushed, "add"), emax)
+    dst = jnp.take(arrays["edge_dst"], edge, mode="clip")
+    hit = fr.scatter_int32(n, jnp.where(slot < run.sum(), dst, n), 1, "max")
+    return jnp.pad(fr.pack(hit > 0), (0, n_words - n // fr.WORD_BITS))
+
+
+def _owned_runs(arrays, frontier_words):
+    """``int32[vmax]``: each owned frontier vertex's out-edge slots, 0 for
+    every other owned slot."""
+    offsets = arrays["out_offsets"]
+    vmax = offsets.shape[0] - 1
+    owned = fr.unpack(lax.dynamic_slice(
+        frontier_words, (arrays["word_start"],), (vmax // fr.WORD_BITS,)))
+    return (offsets[1:] - offsets[:-1]) * owned
+
+
+def frontier_demand(arrays, frontier_words, axes):
+    """``(demand, live)``: replicated int32 scalars of a frontier.
+    ``demand`` is the most out-edge slots any node's owned frontier
+    vertices have, which the sparse push compares with its capacity;
+    ``live`` the most frontier vertices any node holds.  One ``pmax`` over
+    ``axes`` gives both, so every node takes the same expansion branch, and
+    every node runs another level while any node's frontier is live: the
+    nodes' collectives pair up even if their frontiers differ."""
+    both = jnp.stack([_owned_runs(arrays, frontier_words).sum(),
+                      fr.popcount(frontier_words)])
+    if axes:
+        both = lax.pmax(both, axes)
+    return both[0], both[1]
+
+
+def _expand_push(arrays, frontier_words, n_words, use_pallas, meta=None, *,
+                 lanes=False, interpret=False, axes=(), n=None, sparse=None):
+    """Top-down: propagate frontier bits along the graph's edges (paper
+    Alg. 2 phase 1).  Returns the node's 'global queue' bitmap and whether
+    the level pushed the frontier's own out-edges (a bool scalar).
+
+    ``lanes=False``: vertex-packed ``uint32[n_words]`` (single-source)
+    over vertex ids below ``n``.  Two branches, the same on every node:
+    ``sparse`` (a Python bool) names one, or, when it is ``None``, the
+    frontier's :func:`frontier_demand` over ``axes`` against
+    :func:`push_capacity` picks one with a ``lax.cond``.
+
+    * ``sparse_push``, when no node's owned frontier has more out-edge
+      slots than the capacity: push those out-edges
+      (:func:`_push_sparse`);
+    * ``dense_scan`` otherwise: an owned vertex joins when any of its
+      in-edges starts in the frontier, one prefix count over the
+      dst-sorted in-edges read at ``in_offsets`` (:func:`fr.segment_or`),
+      so no per-edge scatter; only the node's owned window is set.
+
+    A node on one branch and a node on the other would lose the owned
+    destinations of the one whose frontier in-neighbours the other owns.
+    Phase 2 ORs the bitmaps together.
     ``lanes=True``: lane-packed ``uint32[n_words, B/32]`` rows — the same
     traversal bit-parallel over B concurrent searches (``analytics.msbfs``),
     where ``n_words`` counts vertex ROWS and merge is a per-row lane-mask OR
-    scattered along the owned out-edges.
+    scattered along the owned out-edges.  It and the Pallas path read every
+    edge: never sparse.
     """
+    full = jnp.array(False)
     if use_pallas:
         if lanes:
             raise NotImplementedError("Pallas frontier kernels are "
@@ -164,15 +235,32 @@ def _expand_push(arrays, frontier_words, n_words, use_pallas, meta=None, *,
         from repro.kernels import ops as kops
 
         return kops.expand_push_pallas(frontier_words, arrays, meta, n_words,
-                                       interpret=interpret)
-    if not lanes:
-        active = fr.get_bits(frontier_words, arrays["in_src"])
-        return fr.segment_or(n_words, arrays["in_offsets"], active,
-                             arrays["word_start"])
-    src, dst = arrays["edge_src"], arrays["edge_dst"]
-    mask = jnp.arange(src.shape[0], dtype=jnp.int32) < arrays["edge_count"]
-    active = jnp.where(mask[:, None], frontier_words[src], jnp.uint32(0))
-    return fr.scatter_or_lanes(n_words, dst, active)
+                                       interpret=interpret), full
+    if lanes:
+        src, dst = arrays["edge_src"], arrays["edge_dst"]
+        mask = jnp.arange(src.shape[0], dtype=jnp.int32) < arrays["edge_count"]
+        active = jnp.where(mask[:, None], frontier_words[src], jnp.uint32(0))
+        return fr.scatter_or_lanes(n_words, dst, active), full
+
+    def dense(_):
+        with jax.named_scope("dense_scan"):
+            active = fr.get_bits(frontier_words, arrays["in_src"])
+            return fr.segment_or(n_words, arrays["in_offsets"], active,
+                                 arrays["word_start"])
+
+    capacity = push_capacity(arrays["edge_dst"].shape[0])
+
+    def push(_):
+        with jax.named_scope("sparse_push"):
+            return _push_sparse(arrays, _owned_runs(arrays, frontier_words),
+                                n_words, n, capacity)
+
+    if capacity <= 0 or sparse is False:
+        return dense(None), full
+    if sparse:
+        return push(None), jnp.array(True)
+    go_sparse = frontier_demand(arrays, frontier_words, axes)[0] <= capacity
+    return lax.cond(go_sparse, push, dense, None), go_sparse
 
 
 def _expand_pull(arrays, frontier_words, visited_words, n_words, use_pallas,
@@ -274,33 +362,34 @@ def build_bfs_fn(
             init_dir = jnp.array(False)
 
         def cond(state):
-            frontier_words, visited, d_owned, level, scanned, pull = state[:6]
+            level, live = state[3], state[7]
             with loop.phase("cond"):
-                return (fr.popcount(frontier_words) > 0) & (level < max_levels)
+                return (live > 0) & (level < max_levels)
 
-        def step(state):
+        def step(state, sparse=None):
             frontier_words, visited, d_owned, level, scanned, pull = state[:6]
 
             # -- Phase 1: traversal -------------------------------------
             def do_push(_):
                 return _expand_push(
                     arrays, frontier_words, n_words, cfg.use_pallas, meta,
-                    interpret=interpret,
+                    interpret=interpret, axes=cfg.axes, n=pg.n,
+                    sparse=sparse,
                 )
 
             def do_pull(_):
                 return _expand_pull(
                     arrays, frontier_words, visited, n_words, cfg.use_pallas,
                     meta, interpret=interpret,
-                )
+                ), jnp.array(False)
 
             with loop.phase("expand"):
                 if cfg.mode == "top_down":
-                    gq = do_push(None)
+                    gq, pushed = do_push(None)
                 elif cfg.mode == "bottom_up":
-                    gq = do_pull(None)
+                    gq, pushed = do_pull(None)
                 else:
-                    gq = lax.cond(pull, do_pull, do_push, None)
+                    gq, pushed = lax.cond(pull, do_pull, do_push, None)
 
                 # edges examined this level (honest TEPS accounting):
                 owned_front = fr.unpack(
@@ -335,6 +424,10 @@ def build_bfs_fn(
                 )[:vmax] & owned_mask
                 d_owned = jnp.where(owned_new, level + 1, d_owned)
 
+            # the next level's expansion branch, and whether it runs
+            with loop.phase("expand"):
+                demand, live = frontier_demand(arrays, new, cfg.axes)
+
             # -- Direction-optimizing switch (Beamer alpha/beta) ----------
             if cfg.mode == "direction_optimizing":
                 with loop.phase("direction"):
@@ -354,6 +447,8 @@ def build_bfs_fn(
                 level + 1,
                 scanned + lvl_scanned.astype(jnp.float32),
                 pull,
+                demand,
+                live,
             )
             if not trace:
                 return out, None
@@ -365,10 +460,12 @@ def build_bfs_fn(
                 direction = state[5].astype(jnp.int32)  # level's own dir
             row = flightrec.trace_row(
                 level, t_words, fr.popcount(new), direction, t_branch,
-                t_shipped, jnp.count_nonzero(new).astype(jnp.int32),
+                t_shipped, jnp.count_nonzero(new).astype(jnp.int32), pushed,
             )
             return out, (level, row)
 
+        with loop.phase("expand"):
+            demand, live = frontier_demand(arrays, frontier_words, cfg.axes)
         init = (
             frontier_words,
             visited,
@@ -376,16 +473,30 @@ def build_bfs_fn(
             jnp.int32(0),
             jnp.float32(0),
             init_dir,
+            demand,
+            live,
         )
+        # top-down levels run as two inner loops, one per expansion
+        # branch, so that neither branch sits in a lax.cond: XLA's memory
+        # space assignment prefetches the in-edge scan's bitmap into VMEM
+        # in a loop body, and not in a conditional's branch
+        capacity = push_capacity(pg.emax)
+        steps, pick = step, None
+        if cfg.mode != "bottom_up" and not cfg.use_pallas and capacity > 0:
+            steps = (functools.partial(step, sparse=True),
+                     functools.partial(step, sparse=False))
+
+            def pick(state):  # 0: the sparse push, 1: the in-edge scan
+                return (state[6] > capacity).astype(jnp.int32)
         state = loop.traced_while(
-            cond, step, init, trace=trace,
-            trace_levels=t_levels if trace else None,
+            cond, steps, init, trace=trace,
+            trace_levels=t_levels if trace else None, pick=pick,
         )
         frontier_words, visited, d_owned, level, scanned, _ = state[:6]
         total_scanned = lax.psum(scanned, cfg.axes)
         out = (d_owned[None], level[None], total_scanned[None])
         if trace:
-            out = out + (state[6][None],)
+            out = out + (state[-1][None],)
         return out
 
     return loop.jit_shard(body, mesh, array_keys, spec, trace=trace)
@@ -403,6 +514,7 @@ _ARRAY_KEYS = (
     "in_count",
     "deg_out",
     "in_offsets",
+    "out_offsets",
 )
 
 

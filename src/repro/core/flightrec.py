@@ -25,6 +25,10 @@ col   name         meaning
                    compaction when the sparse wire format ran, else 0
 6     CHANGED      words the merge actually changed (OR: words gaining
                    bits; MIN: words lowered) — the monoid-changed count
+7     EXPAND       phase-1 expansion branch: 1 when single-source
+                   top-down pushed the frontier's own out-edges, 0 when
+                   it scanned every edge slot (and for every other
+                   expansion: pull, lanes, SSSP, BC, programs)
 ====  ===========  =====================================================
 
 §19 convergence programs (``repro.programs``) share the buffer and
@@ -68,7 +72,7 @@ from jax import lax
 from repro.core import butterfly
 from repro.core import frontier as fr
 
-TRACE_COLS = 7
+TRACE_COLS = 8
 COL_LEVEL = 0
 COL_WORDS = 1
 COL_POP = 2
@@ -76,8 +80,10 @@ COL_DIR = 3
 COL_BRANCH = 4
 COL_SHIPPED = 5
 COL_CHANGED = 6
+COL_EXPAND = 7
 
-COL_NAMES = ("level", "words", "pop", "dir", "branch", "shipped", "changed")
+COL_NAMES = ("level", "words", "pop", "dir", "branch", "shipped", "changed",
+             "expand")
 
 BRANCH_DENSE = 0
 BRANCH_SPARSE = 1
@@ -178,7 +184,8 @@ def dense_sync_stats(buf: jax.Array, axes: Sequence[str]):
     return nz, zero, zero
 
 
-def trace_row(level, words, pop, direction, branch, shipped, changed):
+def trace_row(level, words, pop, direction, branch, shipped, changed,
+              expand=0):
     """Assemble one ``int32[TRACE_COLS]`` row (LEVEL is stored 1-based so a
     zero LEVEL cell marks an unwritten row)."""
     return jnp.stack(
@@ -190,6 +197,7 @@ def trace_row(level, words, pop, direction, branch, shipped, changed):
             jnp.asarray(branch, jnp.int32),
             jnp.asarray(shipped, jnp.int32),
             jnp.asarray(changed, jnp.int32),
+            jnp.asarray(expand, jnp.int32),
         ]
     )
 
@@ -340,6 +348,7 @@ class TraversalTrace:
             "sparse_levels": int((branch == BRANCH_SPARSE).sum()),
             "fallback_levels": int((branch == BRANCH_FALLBACK).sum()),
             "pull_levels": int((self.data[:, COL_DIR] == 1).sum()),
+            "sparse_push_levels": int((self.data[:, COL_EXPAND] == 1).sum()),
             "bytes_per_node_total": float(self.level_bytes_per_node().sum()),
         }
         if self.algo == "bc":
@@ -374,7 +383,8 @@ def trace_chrome_doc(trace: TraversalTrace) -> Dict:
                     BRANCH_FALLBACK: "fallback"}
     for row in trace.level_table():
         name = (f"L{row['level']} {branch_names[row['branch']]}"
-                f"{' pull' if row['dir'] else ''}")
+                f"{' pull' if row['dir'] else ''}"
+                f"{' sparse-push' if row['expand'] else ''}")
         if "wall_ms" in row:
             dur = row["wall_ms"] / 1e3
             tracer.add_span(name, t, t + dur, track="traversal",
@@ -402,7 +412,9 @@ def reconcile_bytes(trace: TraversalTrace, hlo_text: str) -> Dict:
     branch 0 (the False path — dense) must carry exactly the model's
     dense bytes/node in ``collective-permute`` wire bytes and branch 1
     (sparse) exactly the §12 capacity schedule.  For an unconditional
-    dense program the whole while-body's permute bytes are compared.
+    dense program one level body's permute bytes are compared (a level
+    loop with one inner loop per expansion branch holds one copy of the
+    exchange in each).
     Returns ``{"model": {...}, "hlo": {...}, "matches": bool}``.
     """
     from repro.launch import hlo_stats
@@ -427,8 +439,10 @@ def reconcile_bytes(trace: TraversalTrace, hlo_text: str) -> Dict:
             hlo_dense == model["dense"] and hlo_sparse == model["sparse"]
         )
         return out
-    stats = hlo_stats.collective_stats(hlo_text)
-    hlo_dense = stats["collective-permute"]["wire_bytes"]
+    hlo_dense = max(
+        (st["collective-permute"]["wire_bytes"]
+         for _, st in hlo_stats.level_body_collective_stats(hlo_text)),
+        default=0.0)
     out["hlo"] = {"dense": hlo_dense}
     out["matches"] = hlo_dense == model["dense"]
     return out
@@ -496,19 +510,20 @@ def build_bfs_level_fn(pg, mesh, cfg):
         owned_mask = vown_ids < v_count
 
         def do_push(_):
-            return bfs_mod._expand_push(arrays, frontier_words, n_words, False)
+            return bfs_mod._expand_push(arrays, frontier_words, n_words, False,
+                                        axes=cfg.axes, n=pg.n)
 
         def do_pull(_):
             return bfs_mod._expand_pull(
                 arrays, frontier_words, visited, n_words, False
-            )
+            ), jnp.array(False)
 
         if cfg.mode == "top_down":
-            gq = do_push(None)
+            gq, pushed = do_push(None)
         elif cfg.mode == "bottom_up":
-            gq = do_pull(None)
+            gq, pushed = do_pull(None)
         else:
-            gq = lax.cond(pull, do_pull, do_push, None)
+            gq, pushed = lax.cond(pull, do_pull, do_push, None)
 
         words, branch, shipped = or_sync_stats(gq, cfg)
         merged = bfs_mod._sync_frontier(gq, cfg)
@@ -548,7 +563,7 @@ def build_bfs_level_fn(pg, mesh, cfg):
 
         row = trace_row(
             level, words, fr.popcount(new), direction, branch, shipped,
-            jnp.count_nonzero(new).astype(jnp.int32),
+            jnp.count_nonzero(new).astype(jnp.int32), pushed,
         )
         return new, visited, d_owned[None], next_pull, row[None]
 

@@ -171,6 +171,26 @@ def scatter_or_lanes(n_rows: int, idx: jax.Array, masks: jax.Array) -> jax.Array
     return lane_pack(dense)
 
 
+#: entries of one int32 scatter target: the TPU compiler sorts the indices
+#: of a scatter into a larger target (and into any sub-word type) first,
+#: which would put a sort in the level loop
+SCATTER_CHUNK = 1 << 21
+
+
+def scatter_int32(size: int, idx: jax.Array, vals, op: str) -> jax.Array:
+    """``int32[size]`` zeros with ``vals`` combined in at ``idx`` by ``op``
+    (``"add"`` or ``"max"``); indices outside ``[0, size)`` are dropped.
+    One scatter per :data:`SCATTER_CHUNK` entries of the target, so no
+    sort is compiled whatever ``size``."""
+    parts = []
+    for lo in range(0, size, SCATTER_CHUNK):
+        width = min(SCATTER_CHUNK, size - lo)
+        at = jnp.where((idx >= lo) & (idx < lo + width), idx - lo, width)
+        ref = jnp.zeros((width,), jnp.int32).at[at]
+        parts.append(getattr(ref, op)(vals, mode="drop"))
+    return jnp.concatenate(parts) if len(parts) > 1 else parts[0]
+
+
 def scatter_or(n_words: int, idx: jax.Array, active: jax.Array) -> jax.Array:
     """Build a bitmap with bits ``idx[i]`` set where ``active[i]``.
 
@@ -202,7 +222,7 @@ def prefix_count(bits: jax.Array) -> jax.Array:
 
 def _prefix_sum(values: jax.Array, bound: int) -> jax.Array:
     """Inclusive prefix sum of ``int32[n]`` values in ``[0, bound]``, for
-    sums below 2**31."""
+    sums below 2**31 (so the row totals' bound is capped there)."""
     n = values.shape[0]
     rows = -(-n // _SCAN_WIDTH)
     x = jnp.pad(values, (0, rows * _SCAN_WIDTH - n)).reshape(rows, _SCAN_WIDTH)
@@ -214,7 +234,8 @@ def _prefix_sum(values: jax.Array, bound: int) -> jax.Array:
         within += part.astype(jnp.int32) << shift
     if rows > 1:
         totals = within[:, -1]
-        before = _prefix_sum(totals, bound * min(n, _SCAN_WIDTH)) - totals
+        before = _prefix_sum(
+            totals, min(bound * min(n, _SCAN_WIDTH), 2**31 - 1)) - totals
         within += before[:, None]
     return within.reshape(-1)[:n]
 

@@ -59,11 +59,12 @@ def phase(name: str):
 
 def traced_while(
     cond: Callable,
-    step: Callable,
+    step,
     init: Tuple,
     *,
     trace: bool = False,
     trace_levels: Optional[int] = None,
+    pick: Optional[Callable] = None,
 ):
     """Run ``lax.while_loop(cond, step, init)`` with optional §18 tracing.
 
@@ -74,28 +75,54 @@ def traced_while(
     LAST carry entry, so ``cond``/``step`` address their own state by
     prefix (``state[:k]``) exactly as before the refactor.
 
+    With ``pick``, ``step`` is a sequence of steps and ``pick(state)`` the
+    index of the one the next iteration runs: an outer loop runs, for each
+    step in turn, an inner loop of that step while ``cond`` holds and
+    ``pick`` names it.  Every step is then straight-line code in a loop
+    body, which XLA compiles as it does a plain loop, where a ``lax.cond``
+    over the steps would put each in a conditional's branch.
+
     Returns the final full state tuple; traced runs carry the filled
     ``int32[trace_levels, TRACE_COLS]`` buffer in the last slot.
     """
+    init = tuple(init)
     if trace:
         from repro.core import flightrec
 
         if trace_levels is None:
             raise ValueError("trace=True requires trace_levels")
+        init = init + (flightrec.zeros(trace_levels),)
+    if pick is None:
+        return lax.while_loop(cond, _body(step, trace), init)
+    bodies = [_body(s, trace) for s in step]
+
+    def outer(state):
+        for i, body in enumerate(bodies):
+            state = lax.while_loop(
+                lambda s, i=i: cond(s) & (pick(s) == i), body, state)
+        return state
+
+    return lax.while_loop(cond, outer, init)
+
+
+def _body(step: Callable, trace: bool) -> Callable:
+    """A ``lax.while_loop`` body from a ``step``: with ``trace`` it records
+    the step's row into the trace buffer in the last carry slot."""
+    if trace:
+        from repro.core import flightrec
 
         def body(state):
             out, rec = step(state)
             index, row = rec
             return tuple(out) + (flightrec.record(state[-1], index, row),)
 
-        init = tuple(init) + (flightrec.zeros(trace_levels),)
-        return lax.while_loop(cond, body, init)
+        return body
 
     def body(state):
         out, _ = step(state)
         return tuple(out)
 
-    return lax.while_loop(cond, body, tuple(init))
+    return body
 
 
 def jit_shard(

@@ -17,10 +17,10 @@ Two mutable views are kept in lock-step:
   owner shard's static slack (``edge_count`` / ``in_count`` grow, the
   array SHAPES never change, so compiled programs are reused); deletions
   compact the matching slots out of the active prefix.  Out-edges are
-  appended: the kernels that read them (scatter-OR / scatter-MIN) are
-  order-free.  In-edges are merged into their (dst, src) position and the
-  shard's ``in_offsets`` recomputed, because single-source top-down
-  expansion reduces each destination's contiguous run (DESIGN.md §3).
+  merged into their (src, dst) position and in-edges into their (dst,
+  src) position, and the shard's ``out_offsets`` and ``in_offsets``
+  recomputed, because single-source top-down expansion reads each
+  vertex's contiguous run (DESIGN.md §3).
   When a shard's slack is exhausted the update is refused untouched and
   the caller falls back to compaction + repartition (a §15 full swap).
 """
@@ -358,32 +358,50 @@ def _owners(pg, vids: np.ndarray) -> np.ndarray:
     return np.searchsorted(pg.v_start, vids, side="right") - 1
 
 
-def _dst_src_key(dst: np.ndarray, src: np.ndarray) -> np.ndarray:
-    return (dst.astype(np.int64) << 32) | src.astype(np.int64)
+#: the two edge views of a shard: (major, minor, weight, count, offsets)
+#: array names; each view is kept sorted by (major, minor) in its active
+#: prefix, and its offsets mark where each owned vertex's run of majors
+#: starts
+_VIEWS = {
+    "out": ("edge_src", "edge_dst", "edge_weight", "edge_count",
+            "out_offsets"),
+    "in": ("in_dst", "in_src", "in_weight", "in_count", "in_offsets"),
+}
 
 
-def _merge_in_edges(pg, i: int, src, dst, w) -> None:
-    """Insert in-edges into shard ``i``'s active prefix at their (dst, src)
-    position; duplicates land beside the slot they repeat."""
-    act = int(pg.in_count[i])
-    add_key = _dst_src_key(dst, src)
+def _key(major: np.ndarray, minor: np.ndarray) -> np.ndarray:
+    return (major.astype(np.int64) << 32) | minor.astype(np.int64)
+
+
+def _merge_edges(pg, i: int, view: str, major, minor, w) -> None:
+    """Insert edges into shard ``i``'s active prefix of one view at their
+    (major, minor) position; duplicates land beside the slot they
+    repeat."""
+    a_name, b_name, w_name, c_name, _ = _VIEWS[view]
+    majors, minors = getattr(pg, a_name), getattr(pg, b_name)
+    count = getattr(pg, c_name)
+    act = int(count[i])
+    add_key = _key(major, minor)
     order = np.argsort(add_key, kind="stable")
-    at = np.searchsorted(_dst_src_key(pg.in_dst[i, :act], pg.in_src[i, :act]),
+    at = np.searchsorted(_key(majors[i, :act], minors[i, :act]),
                          add_key[order], side="right")
     end = act + at.size
-    pg.in_src[i, :end] = np.insert(pg.in_src[i, :act], at, src[order])
-    pg.in_dst[i, :end] = np.insert(pg.in_dst[i, :act], at, dst[order])
+    majors[i, :end] = np.insert(majors[i, :act], at, major[order])
+    minors[i, :end] = np.insert(minors[i, :act], at, minor[order])
     if w is not None:
-        pg.in_weight[i, :end] = np.insert(pg.in_weight[i, :act], at, w[order])
-    pg.in_count[i] = end
+        wts = getattr(pg, w_name)
+        wts[i, :end] = np.insert(wts[i, :act], at, w[order])
+    count[i] = end
 
 
-def _refresh_in_offsets(pg, i: int) -> None:
-    """Shard ``i``'s in-edge row offsets from its sorted active prefix;
-    slots past ``v_count`` get ``in_count`` (empty runs)."""
+def _refresh_offsets(pg, i: int, view: str) -> None:
+    """Shard ``i``'s row offsets of one view from its sorted active
+    prefix; slots past ``v_count`` get the view's count (empty runs)."""
+    a_name, _, _, c_name, o_name = _VIEWS[view]
     vids = int(pg.v_start[i]) + np.arange(pg.vmax + 1, dtype=np.int64)
-    pg.in_offsets[i] = np.searchsorted(pg.in_dst[i, : int(pg.in_count[i])],
-                                       vids, side="left")
+    getattr(pg, o_name)[i] = np.searchsorted(
+        getattr(pg, a_name)[i, : int(getattr(pg, c_name)[i])], vids,
+        side="left")
 
 
 def apply_update_to_partition(pg, update: AppliedUpdate) -> bool:
@@ -392,90 +410,61 @@ def apply_update_to_partition(pg, update: AppliedUpdate) -> bool:
 
     Returns ``False`` — with every array untouched — when any shard's
     static slack cannot hold its inserts (the compaction trigger).
-    Inserted directed edge ``(u, v)`` appends to ``owner(u)``'s out buffer
-    and merges into ``owner(v)``'s (dst, src)-sorted in buffer, whose
-    ``in_offsets`` are then recomputed; weight-lowerings add a duplicate
-    slot (scatter-MIN keeps the lower proposal, so duplicates are harmless
-    and cheaper than an in-place search); deletions compact every matching
-    slot out of the active prefix, keeping its order.  ``deg_out`` tracks
-    the DEDUPLICATED out-degree (weight-lowerings don't count)."""
+    Inserted directed edge ``(u, v)`` merges into ``owner(u)``'s
+    (src, dst)-sorted out buffer and ``owner(v)``'s (dst, src)-sorted in
+    buffer, whose ``out_offsets`` and ``in_offsets`` are then recomputed;
+    weight-lowerings add a duplicate slot (scatter-MIN keeps the lower
+    proposal, so duplicates are harmless and cheaper than an in-place
+    search); deletions compact every matching slot out of the active
+    prefix, keeping its order.  ``deg_out`` tracks the DEDUPLICATED
+    out-degree (weight-lowerings don't count)."""
     ins_u, ins_v = update.ins_src, update.ins_dst
-    out_own = _owners(pg, ins_u)
-    in_own = _owners(pg, ins_v)
+    own = {"out": _owners(pg, ins_u), "in": _owners(pg, ins_v)}
 
     # capacity pre-check: refuse atomically, never half-apply
-    out_add = np.bincount(out_own, minlength=pg.p) if ins_u.size else np.zeros(pg.p, np.int64)
-    in_add = np.bincount(in_own, minlength=pg.p) if ins_u.size else np.zeros(pg.p, np.int64)
-    if np.any(pg.edge_count + out_add > pg.emax) or np.any(
-        pg.in_count + in_add > pg.emax
-    ):
-        return False
+    for view, o in own.items():
+        add = np.bincount(o, minlength=pg.p) if ins_u.size else 0
+        if np.any(getattr(pg, _VIEWS[view][3]) + add > pg.emax):
+            return False
 
     weighted = pg.edge_weight is not None
-    for i in range(pg.p):
-        # -- inserts: append into the shard's slack -----------------------
-        sel = out_own == i
-        k = int(sel.sum())
-        if k:
-            lo = int(pg.edge_count[i])
-            pg.edge_src[i, lo : lo + k] = ins_u[sel]
-            pg.edge_dst[i, lo : lo + k] = ins_v[sel]
-            if weighted:
-                pg.edge_weight[i, lo : lo + k] = update.ins_w[sel]
-            pg.edge_count[i] += k
-            newsel = sel & update.ins_is_new
-            np.add.at(
-                pg.deg_out[i],
-                (ins_u[newsel] - pg.v_start[i]).astype(np.int64),
-                1,
-            )
-        sel = in_own == i
-        if sel.any():
-            _merge_in_edges(pg, i, ins_u[sel], ins_v[sel],
-                            update.ins_w[sel] if weighted else None)
-
-    in_touched = np.zeros(pg.p, bool)
-    in_touched[in_own] = True
+    ins = {"out": (ins_u, ins_v), "in": (ins_v, ins_u)}
+    touched = {view: np.zeros(pg.p, bool) for view in _VIEWS}
+    for view, o in own.items():
+        major, minor = ins[view]
+        for i in np.unique(o):
+            sel = o == i
+            _merge_edges(pg, i, view, major[sel], minor[sel],
+                         update.ins_w[sel] if weighted else None)
+        touched[view][o] = True
+    newsel = update.ins_is_new
+    np.add.at(pg.deg_out, (own["out"][newsel],
+                           ins_u[newsel] - pg.v_start[own["out"][newsel]]), 1)
 
     # -- deletes: compact matching slots out of the active prefix ---------
     if update.del_src.size:
         del_u, del_v = update.del_src, update.del_dst
-        del_key = (del_u << 32) | del_v
-        d_out = _owners(pg, del_u)
-        d_in = _owners(pg, del_v)
-        for i in range(pg.p):
-            for (srcs, dsts, wts, cnt_name, own) in (
-                (pg.edge_src, pg.edge_dst, pg.edge_weight, "edge_count", d_out),
-                (pg.in_src, pg.in_dst, pg.in_weight, "in_count", d_in),
-            ):
-                keys_i = del_key[own == i]
-                if not keys_i.size:
-                    continue
-                cnt_arr = getattr(pg, cnt_name)
-                act = int(cnt_arr[i])
-                slot_key = (
-                    srcs[i, :act].astype(np.int64) << 32
-                ) | dsts[i, :act].astype(np.int64)
-                keep = ~np.isin(slot_key, keys_i)
+        dels = {"out": (del_u, del_v), "in": (del_v, del_u)}
+        for view, (major, minor) in dels.items():
+            a_name, b_name, w_name, c_name, _ = _VIEWS[view]
+            majors, minors = getattr(pg, a_name), getattr(pg, b_name)
+            wts, count = getattr(pg, w_name), getattr(pg, c_name)
+            d_own = _owners(pg, major)
+            for i in np.unique(d_own):
+                act = int(count[i])
+                keep = ~np.isin(_key(majors[i, :act], minors[i, :act]),
+                                _key(major[d_own == i], minor[d_own == i]))
                 new_cnt = int(keep.sum())
-                srcs[i, :new_cnt] = srcs[i, :act][keep]
-                srcs[i, new_cnt:act] = 0
-                dsts[i, :new_cnt] = dsts[i, :act][keep]
-                dsts[i, new_cnt:act] = 0
-                if wts is not None:
-                    wts[i, :new_cnt] = wts[i, :act][keep]
-                    wts[i, new_cnt:act] = 0
-                cnt_arr[i] = new_cnt
-            sel = d_out == i
-            if sel.any():
-                np.add.at(
-                    pg.deg_out[i],
-                    (del_u[sel] - pg.v_start[i]).astype(np.int64),
-                    -1,
-                )
-        in_touched[d_in] = True
-    for i in np.flatnonzero(in_touched):
-        _refresh_in_offsets(pg, i)
+                for arr in (majors, minors) + ((wts,) if weighted else ()):
+                    arr[i, :new_cnt] = arr[i, :act][keep]
+                    arr[i, new_cnt:act] = 0
+                count[i] = new_cnt
+            touched[view][d_own] = True
+        d_out = _owners(pg, del_u)
+        np.add.at(pg.deg_out, (d_out, del_u - pg.v_start[d_out]), -1)
+    for view, t in touched.items():
+        for i in np.flatnonzero(t):
+            _refresh_offsets(pg, i, view)
     return True
 
 

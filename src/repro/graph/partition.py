@@ -16,10 +16,11 @@ that, with two TPU-specific refinements:
   consumes them with the leading axis sharded over the device mesh.
 
 Out-edges are kept sorted by (src, dst) and in-edges by (dst, src), with
-``in_offsets`` marking where each owned vertex's in-edges start:
-single-source top-down reduces each owned vertex's contiguous run of
-in-edges (the degree-uniform layout that stands in for the paper's LRB
-load balancing, see DESIGN.md Sec. 3).
+``out_offsets`` and ``in_offsets`` marking where each owned vertex's runs
+start: single-source top-down either pushes the frontier's own runs of
+out-edges, or reduces each owned vertex's contiguous run of in-edges (the
+degree-uniform layout that stands in for the paper's LRB load balancing,
+see DESIGN.md Sec. 3).
 """
 
 from __future__ import annotations
@@ -62,6 +63,10 @@ class PartitionedGraph:
     # slot v's in-edges are in_*[i, in_offsets[i, v]:in_offsets[i, v + 1]];
     # slots past v_count hold in_count (empty runs)
     in_offsets: np.ndarray
+    # int32[P, vmax + 1]  the same for the out-edges: slot v's out-edges are
+    # edge_*[i, out_offsets[i, v]:out_offsets[i, v + 1]]; slots past
+    # v_count hold edge_count
+    out_offsets: np.ndarray
     # uint32[P, emax] edge weights, partitioned alongside dst (out view) and
     # src (in view); None for unweighted graphs (DESIGN.md §14).
     edge_weight: Optional[np.ndarray] = None
@@ -89,6 +94,7 @@ class PartitionedGraph:
             in_count=self.in_count,
             deg_out=self.deg_out,
             in_offsets=self.in_offsets,
+            out_offsets=self.out_offsets,
         )
         if self.edge_weight is not None:
             out["edge_weight"] = self.edge_weight
@@ -139,6 +145,7 @@ class SyntheticShapes:
             in_count=(p,),
             deg_out=(p, vmax),
             in_offsets=(p, vmax + 1),
+            out_offsets=(p, vmax + 1),
         )
 
 
@@ -164,8 +171,8 @@ def partition_1d(g: csr.Graph, p: int, *, lane_pad: int = 128,
     ``lane_pad`` rounds the edge width ``emax`` and the exchanged bitmap
     length ``n_words``; ``vertex_pad`` (a positive multiple of 32) rounds
     the owned-vertex width ``vmax``, and the window ``wmax`` follows.
-    Slots past a device's ``v_count`` stay empty: out-degree 0 and an
-    empty in-edge run.
+    Slots past a device's ``v_count`` stay empty: out-degree 0 and empty
+    out- and in-edge runs.
     """
     _check_vertex_pad(vertex_pad)
     if not g._validated:  # corrupt inputs fail here, not as wrong traversals
@@ -205,6 +212,7 @@ def partition_1d(g: csr.Graph, p: int, *, lane_pad: int = 128,
     in_dst = np.zeros((p, emax), dtype=np.int32)
     deg_out = np.zeros((p, vmax), dtype=np.int32)
     local_in_offsets = np.zeros((p, vmax + 1), dtype=np.int32)
+    local_out_offsets = np.zeros((p, vmax + 1), dtype=np.int32)
     edge_weight = np.zeros((p, emax), dtype=np.uint32) if g.weighted else None
     in_weight = np.zeros((p, emax), dtype=np.uint32) if g.weighted else None
     degrees = g.out_degree
@@ -223,6 +231,9 @@ def partition_1d(g: csr.Graph, p: int, *, lane_pad: int = 128,
         local_in_offsets[i, : v_count[i] + 1] = (
             in_offsets[v_start[i] : v_end[i] + 1] - ie_lo[i])
         local_in_offsets[i, v_count[i] + 1 :] = in_count[i]
+        local_out_offsets[i, : v_count[i] + 1] = (
+            cum[v_start[i] : v_end[i] + 1] - e_lo[i])
+        local_out_offsets[i, v_count[i] + 1 :] = edge_count[i]
 
     # Exchanged bitmap length: whole graph + one device window of slack so
     # every device can dynamic-slice its aligned [word_start, word_start+wmax)
@@ -248,6 +259,7 @@ def partition_1d(g: csr.Graph, p: int, *, lane_pad: int = 128,
         in_count=in_count,
         deg_out=deg_out,
         in_offsets=local_in_offsets,
+        out_offsets=local_out_offsets,
         edge_weight=edge_weight,
         in_weight=in_weight,
     )

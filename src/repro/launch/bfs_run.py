@@ -238,7 +238,8 @@ def main(argv=None) -> int:
         s = trace.summary()
         print(f"trace: {s['levels']} levels ({s['dense_levels']} dense / "
               f"{s['sparse_levels']} sparse / {s['fallback_levels']} "
-              f"fallback), {s['bytes_per_node_total']:.0f} sync B/node "
+              f"fallback exchanges; {s['sparse_push_levels']} sparse "
+              f"pushes), {s['bytes_per_node_total']:.0f} sync B/node "
               f"-> {args.trace}")
         return trace.to_dict()
 

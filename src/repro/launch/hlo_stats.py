@@ -244,6 +244,28 @@ def computation_collective_stats(
     return {name: total(name, frozenset()) for name in direct}
 
 
+def level_body_collective_stats(hlo_text: str):
+    """Collective stats of each innermost ``while`` body of the compiled
+    program, with everything it calls: ``[(computation_name, stats)]``.
+
+    A level loop whose steps come in variants (``loop.traced_while`` with
+    ``pick``) compiles one inner loop per variant, each with its own copy
+    of the level's collectives; a level runs exactly one of them, so one
+    level's bytes are one innermost body's, not the module's total."""
+    comp_stats = computation_collective_stats(hlo_text)
+    _, callees = _segment_computations(hlo_text)
+    bodies = set(re.findall(r" while\(.*?body=%([\w.\-]+)", hlo_text))
+
+    def reaches(name, seen=frozenset()):
+        for callee in callees.get(name, []):
+            if callee in bodies or (callee not in seen
+                                    and reaches(callee, seen | {name})):
+                return True
+        return False
+
+    return [(b, comp_stats[b]) for b in sorted(bodies) if not reaches(b)]
+
+
 def conditional_branch_stats(hlo_text: str):
     """Collective stats per ``lax.cond`` branch of the compiled program.
 

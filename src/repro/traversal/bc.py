@@ -196,7 +196,7 @@ def build_bc_fn(
         def fstep(state):
             frontier, seen, lvl, sigma, level, scanned = state[:6]
 
-            gq = _expand_push(arrays, frontier, n_rows, False, lanes=True)
+            gq, _ = _expand_push(arrays, frontier, n_rows, False, lanes=True)
             if trace:
                 t_words, t_branch, t_shipped = flightrec.or_sync_stats(
                     gq.reshape(-1), cfg

@@ -219,6 +219,49 @@ def test_partition_patch_keeps_in_edges_sorted(graph_u, graph_w, mesh8,
                                   _norm(bfs.bfs_reference(gm, root)))
 
 
+@pytest.mark.parametrize("kind", ["insert", "delete", "mixed"])
+@pytest.mark.parametrize("weighted", [False, True])
+def test_partition_patch_keeps_out_edges_sorted(graph_u, graph_w, mesh8,
+                                                monkeypatch, weighted, kind):
+    """After inserts, deletes and weight-lowerings every shard's active
+    out-edges stay sorted by (src, dst), its ``out_offsets`` equal a
+    recount and hold the updated graph's edges, ``deg_out`` its degrees;
+    single-source BFS on the patched partition matches the oracle whether
+    every level pushes the frontier's out-edges or scans the in-edges."""
+    g = graph_w if weighted else graph_u
+    pg = partition.partition_1d(g, 8)
+    ov = delta.DeltaOverlay(g)
+    for upd in _in_edge_batches(g, ov, kind, np.random.default_rng(5)):
+        assert delta.apply_update_to_partition(pg, upd)
+    gm = ov.current_graph()
+    for i in range(pg.p):
+        s, c, e = int(pg.v_start[i]), int(pg.v_count[i]), int(pg.edge_count[i])
+        src, dst = pg.edge_src[i, :e], pg.edge_dst[i, :e]
+        key = (src.astype(np.int64) << 32) | dst
+        assert np.all(np.diff(key) >= 0), i
+        np.testing.assert_array_equal(
+            pg.out_offsets[i],
+            np.searchsorted(src, s + np.arange(pg.vmax + 1)),
+            err_msg=f"shard {i}")
+        lo, hi = gm.row_offsets[s], gm.row_offsets[s + c]
+        np.testing.assert_array_equal(
+            np.unique(key), (gm.src[lo:hi].astype(np.int64) << 32)
+            | gm.dst[lo:hi], err_msg=f"shard {i}")
+        np.testing.assert_array_equal(pg.deg_out[i, :c],
+                                      gm.out_degree[s: s + c])
+        if not weighted:  # no duplicate slots: a fresh partition's runs
+            np.testing.assert_array_equal(
+                pg.out_offsets[i, : c + 1],
+                gm.row_offsets[s: s + c + 1] - lo)
+    root = int(csr.largest_component_root(gm, np.random.default_rng(0)))
+    ref = _norm(bfs.bfs_reference(gm, root))
+    for k in (0, pg.emax):
+        monkeypatch.setattr(bfs, "push_capacity", lambda emax, k=k: k)
+        d, _, _ = bfs.distributed_bfs(pg, mesh8, root,
+                                      bfs.BFSConfig(axes=("data",)))
+        np.testing.assert_array_equal(_norm(d), ref, err_msg=f"K={k}")
+
+
 @pytest.mark.parametrize("kind", ["insert", "mixed"])
 def test_partition_patch_keeps_vertex_pad(graph_u, kind):
     """A partition whose owned-vertex width is padded (``vertex_pad``)

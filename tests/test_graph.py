@@ -192,6 +192,31 @@ def test_synthetic_shapes_match_real_partition():
     assert set(ashapes) == set(real)
 
 
+@pytest.mark.parametrize("p,vertex_pad", [(1, 32), (3, 32), (4, 512),
+                                          (8, 32)])
+@pytest.mark.parametrize("weighted", [False, True])
+def test_out_offsets_mark_each_owned_vertex_run(p, vertex_pad, weighted):
+    """``out_offsets`` is a searchsorted of each shard's (src, dst)-sorted
+    out-edges at its owned vertex ids: runs as long as the out-degrees,
+    padded slots empty at ``edge_count``; the dry-run shapes list it."""
+    g = generators.kronecker(10, 8, seed=7, max_weight=9 if weighted else 0)
+    pg = partition.partition_1d(g, p, vertex_pad=vertex_pad)
+    assert pg.out_offsets.shape == (p, pg.vmax + 1)
+    assert pg.arrays()["out_offsets"] is pg.out_offsets
+    for i in range(p):
+        s, c, e = int(pg.v_start[i]), int(pg.v_count[i]), int(pg.edge_count[i])
+        src = pg.edge_src[i, :e]
+        np.testing.assert_array_equal(
+            pg.out_offsets[i],
+            np.searchsorted(src, s + np.arange(pg.vmax + 1), side="left"))
+        np.testing.assert_array_equal(np.diff(pg.out_offsets[i, : c + 1]),
+                                      pg.deg_out[i, :c])
+        assert np.all(pg.out_offsets[i, c:] == e)
+    syn = partition.synthetic_shapes(g.n, g.n_edges, p,
+                                     vertex_pad=vertex_pad)
+    assert syn.array_shapes()["out_offsets"] == (p, syn.vmax + 1)
+
+
 @pytest.mark.parametrize("vertex_pad", [512, 1024])
 def test_vertex_pad_gives_one_layout_across_seeds(vertex_pad):
     """With ``vertex_pad`` the owned-vertex width no longer moves with the

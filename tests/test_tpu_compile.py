@@ -11,6 +11,7 @@ The topology is described inside a fixture: loading the TPU library at
 import time would make pytest-xdist workers collect different tests.
 """
 
+import math
 import os
 import re
 
@@ -81,12 +82,14 @@ def _fits(compiled):
 _CALLED = re.compile(r"(?:calls|to_apply|body|condition|true_computation|"
                      r"false_computation)=(%[\w.\-]+)")
 _BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+_DEFINED = re.compile(r"\s+(?:ROOT )?(%[\w.\-]+) = \w+\[([\d,]*)\]")
 
 
 def _loop_ops(text, opcode):
     """The ``opcode`` instructions that the level loop runs: every
     instruction of each ``while`` body and of the computations it calls,
-    fusions included, as ``(name, op_name)`` pairs."""
+    fusions included, as ``(name, op_name, operand sizes)``, the sizes in
+    elements, from each operand's definition in the same computation."""
     comps, name = {}, None
     for line in text.splitlines():
         head = re.match(r"(?:ENTRY )?(%[\w.\-]+) .*\{$", line)
@@ -104,37 +107,55 @@ def _loop_ops(text, opcode):
         if comp in seen:
             continue
         seen.add(comp)
+        sizes = {}
+        for line in comps[comp]:
+            d = _DEFINED.match(line)
+            if d:
+                sizes[d.group(1)] = math.prod(
+                    int(x) for x in d.group(2).split(",") if x)
         for line in comps[comp]:
             todo += _CALLED.findall(line)
             for group in _BRANCHES.findall(line):
                 todo += [c.strip() for c in group.split(",")]
             op = re.match(r"\s+(?:ROOT )?(%[\w.\-]+) = .*? " + opcode
-                          + r"\(", line)
+                          + r"\(([^)]*)\)", line)
             if op:
                 where = re.search(r'op_name="([^"]*)"', line)
-                found.append((op.group(1), where.group(1) if where else ""))
+                operands = [sizes.get(o.strip())
+                            for o in op.group(2).split(",")]
+                found.append((op.group(1), where.group(1) if where else "",
+                              operands))
     assert seen, "no while loop in the compiled program"
     return found
 
 
-def _assert_no_scatter_expansion(compiled, p):
-    """Single-source top-down reduces dst-sorted in-edges: no sort and no
-    scatter in the level loop, apart from the adaptive exchange's sparse
-    receive on P > 1 (the root's ``set_bit`` runs before the loop)."""
+def _assert_no_scatter_expansion(compiled, shapes):
+    """Single-source top-down: the level loop holds no sort at all; its
+    dense expansion (the dst-sorted in-edge scan) holds no scatter; the
+    only scatters are the adaptive exchange's sparse receive on P > 1 and
+    the sparse push of the frontier's own out-edges, each of at most
+    ``max(vmax, K)`` updates (the root's ``set_bit`` runs before the
+    loop)."""
     text = compiled.as_text()
     assert _loop_ops(text, "sort") == []
+    limit = max(shapes.vmax, bfs.push_capacity(shapes.emax))
     scatters = _loop_ops(text, "scatter")
-    if p == 1:
-        assert scatters == []
-    assert all("traversal.exchange" in where for _, where in scatters), (
-        scatters)
-    assert _loop_ops(text, "gather"), "the frontier-bit gather is missing"
+    pushed = [s for s in scatters if "traversal.expand/" in s[1]]
+    assert pushed, "the sparse push is missing"
+    for name, where, (_, _, updates) in scatters:
+        assert "traversal.exchange/" in where or (
+            "traversal.expand/" in where and "/sparse_push/" in where), (
+            name, where)
+        assert updates is not None and updates <= limit, (name, updates)
+    dense = [w for _, w, _ in _loop_ops(text, "gather")
+             if "traversal.expand/" in w and "/dense_scan/" in w]
+    assert dense, "the dense branch's frontier-bit gather is missing"
 
 
 def test_single_source_bfs_compiles_one_chip_scale21(topo):
     compiled = _compile(topo, bfs.build_bfs_fn, 21, 1, ())
     _fits(compiled)
-    _assert_no_scatter_expansion(compiled, 1)
+    _assert_no_scatter_expansion(compiled, graph500_shapes(21, 1))
 
 
 def test_single_source_bfs_compiles_four_chip_mesh_scale22(topo):
@@ -142,7 +163,7 @@ def test_single_source_bfs_compiles_four_chip_mesh_scale22(topo):
     _fits(compiled)
     # the butterfly exchange is compiled in: rounds of ppermute
     assert "collective-permute" in compiled.as_text()
-    _assert_no_scatter_expansion(compiled, 4)
+    _assert_no_scatter_expansion(compiled, graph500_shapes(22, 4))
 
 
 def test_served_32_lane_wave_compiles_one_chip_scale21(topo):
