@@ -395,8 +395,10 @@ import hashlib, json, os, re, sys
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import jax
 import jax.numpy as jnp
+from repro.analytics import msbfs
 from repro.core import bfs
 from repro.graph import generators, partition
+from repro.traversal import bc as bc_mod
 from repro.traversal import sssp as sssp_mod
 
 _SYM = re.compile(r"@[A-Za-z_][\w$.]*")
@@ -424,6 +426,20 @@ for sync in ("butterfly", "sparse", "adaptive"):
     txt = sssp_mod.build_sssp_fn(pg, mesh, scfg).lower(
         arrays, jnp.int32(0)).as_text()
     got["sssp/%s" % sync] = hashlib.sha256(
+        canonical(txt).encode()).hexdigest()
+roots = jnp.arange(32, dtype=jnp.int32)
+for sync in ("butterfly", "sparse", "adaptive"):
+    for mode in ("top_down", "direction_optimizing"):
+        cfg = bfs.BFSConfig(sync=sync, mode=mode)
+        txt = msbfs.build_msbfs_fn(pg, mesh, cfg, 32).lower(
+            arrays, roots).as_text()
+        got["msbfs/%s/%s" % (sync, mode)] = hashlib.sha256(
+            canonical(txt).encode()).hexdigest()
+for sync in ("butterfly", "adaptive"):
+    cfg = bfs.BFSConfig(sync=sync)
+    txt = bc_mod.build_bc_fn(pg, mesh, cfg, 32).lower(
+        arrays, roots).as_text()
+    got["bc/%s" % sync] = hashlib.sha256(
         canonical(txt).encode()).hexdigest()
 json.dump(got, sys.stdout)
 """
