@@ -13,11 +13,13 @@ round count is independent of how many searches share the words.  Packing
 near-zero extra sync cost (Buluç & Madduri; Pan, Pearce & Owens — see
 PAPERS.md).
 
-Phase 1 reuses :func:`repro.core.bfs._expand_push` / ``_expand_pull`` with
-``lanes=True`` (the push/pull machinery generalized over the lane axis);
-phase 2 reuses ``collectives.butterfly_or`` / ``_sparse`` / ``_adaptive``
-UNCHANGED on the flattened word buffer.  The whole B-search wave compiles to
-ONE XLA program: ``jit(shard_map(lax.while_loop))``.
+A wave runs the single-source search's level step,
+:func:`repro.core.bfs.level_step`, over the lane-packed frontier format
+:class:`repro.core.bfs.LaneFormat` (its push and pull generalized over the
+lane axis, Beamer's β scaled by the active lanes); phase 2 reuses
+``collectives.butterfly_or`` / ``_sparse`` / ``_adaptive`` UNCHANGED on the
+flattened word buffer.  The whole B-search wave compiles to ONE XLA
+program: ``jit(shard_map(lax.while_loop))``.
 """
 
 from __future__ import annotations
@@ -27,19 +29,18 @@ from typing import Sequence, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from repro.core import flightrec
 from repro.core import frontier as fr
 from repro.core import loop
 from repro.core.bfs import (
     INF,
     BFSConfig,
-    _expand_pull,
-    _expand_push,
-    _sync_frontier,
+    LaneFormat,
     graph_array_keys,
     place_arrays,
+    run_levels,
 )
 from repro.graph.partition import PartitionedGraph
 
@@ -89,20 +90,14 @@ def build_msbfs_fn(
         )
     bw = lane_words(n_lanes)
     n_rows = wave_rows(pg)
-    vmax = pg.vmax
     max_levels = cfg.max_levels if cfg.max_levels is not None else pg.n
     spec = P(cfg.axes if len(cfg.axes) > 1 else cfg.axes[0])
-    if trace:
-        from repro.core import flightrec
-
-        t_levels = flightrec.resolve_trace_levels(trace_levels, max_levels)
+    t_levels = (flightrec.resolve_trace_levels(trace_levels, max_levels)
+                if trace else None)
 
     def body(arrays, roots):
         arrays = jax.tree.map(lambda a: a[0], arrays)
-        v_start = arrays["v_start"]
-        v_count = arrays["v_count"]
-        vown_ids = jnp.arange(vmax, dtype=jnp.int32)
-        owned_mask = vown_ids < v_count
+        owned_mask = jnp.arange(pg.vmax, dtype=jnp.int32) < arrays["v_count"]
 
         lane_ids = jnp.arange(n_lanes, dtype=jnp.int32)
         lane_active = roots >= 0
@@ -113,137 +108,11 @@ def build_msbfs_fn(
             jnp.arange(bw * LANE_BITS, dtype=jnp.int32)[None, :] == lane_ids[:, None]
         ) & lane_active[:, None]
         seen = fr.scatter_or_lanes(n_rows, seed_rows, fr.lane_pack(onehot))
-        frontier = seen
 
-        def owned_lanes(buf):
-            win = lax.dynamic_slice(buf, (v_start, 0), (vmax, bw))
-            return fr.lane_unpack(win)[:, :n_lanes] & owned_mask[:, None]
-
-        d_owned = jnp.where(owned_lanes(seen), 0, INF)
-
-        if cfg.mode == "bottom_up":
-            init_dir = jnp.array(True)
-        else:
-            init_dir = jnp.array(False)  # False == push
-
-        def cond(state):
-            frontier, seen, d_owned, level, scanned, pull = state[:6]
-            with loop.phase("cond"):
-                return (fr.popcount(frontier) > 0) & (level < max_levels)
-
-        def step(state):
-            frontier, seen, d_owned, level, scanned, pull = state[:6]
-
-            # -- Phase 1: lane-parallel traversal ------------------------
-            def do_push(_):
-                return _expand_push(arrays, frontier, n_rows, False,
-                                    lanes=True)[0]
-
-            def do_pull(_):
-                return _expand_pull(
-                    arrays, frontier, seen, n_rows, False, lanes=True
-                )
-
-            with loop.phase("expand"):
-                if cfg.mode == "top_down":
-                    gq = do_push(None)
-                elif cfg.mode == "bottom_up":
-                    gq = do_pull(None)
-                else:
-                    gq = lax.cond(pull, do_pull, do_push, None)
-
-                # edges examined this level, summed over ACTIVE lanes
-                # (inactive lanes would otherwise count every vertex as
-                # unvisited):
-                owned_front = owned_lanes(frontier)
-                m_f = (arrays["deg_out"][:, None] * owned_front).sum()
-                owned_unvis = (
-                    ~fr.lane_unpack(
-                        lax.dynamic_slice(seen, (v_start, 0), (vmax, bw))
-                    )[:, :n_lanes]
-                    & owned_mask[:, None]
-                    & lane_active[None, :]
-                )
-                m_u = (arrays["deg_out"][:, None] * owned_unvis).sum()
-                if cfg.mode == "bottom_up":
-                    lvl_scanned = m_u
-                elif cfg.mode == "top_down":
-                    lvl_scanned = m_f
-                else:
-                    lvl_scanned = jnp.where(pull, m_u, m_f)
-
-            # -- Phase 2: butterfly sync, UNCHANGED on the flat buffer ---
-            with loop.phase("exchange"):
-                if trace:
-                    t_words, t_branch, t_shipped = flightrec.or_sync_stats(
-                        gq.reshape(-1), cfg
-                    )
-                merged = _sync_frontier(gq.reshape(-1), cfg).reshape(
-                    n_rows, bw)
-
-            # -- Per-lane enqueue-if-new + level capture -----------------
-            with loop.phase("update"):
-                new = merged & ~seen
-                seen = seen | new
-                d_owned = jnp.where(owned_lanes(new), level + 1, d_owned)
-
-            # -- Direction-optimizing switch, wave-aggregated ------------
-            if cfg.mode == "direction_optimizing":
-                with loop.phase("direction"):
-                    g_mf = lax.psum(m_f, cfg.axes)
-                    g_mu = lax.psum(m_u, cfg.axes)
-                    n_f = fr.popcount(new)
-                    active_count = jnp.maximum(
-                        lane_active.sum(dtype=jnp.int32), 1
-                    )
-                    go_pull = g_mf.astype(jnp.float32) > (
-                        g_mu.astype(jnp.float32) / cfg.alpha
-                    )
-                    go_push = n_f.astype(jnp.float32) < (
-                        active_count * pg.n / cfg.beta
-                    )
-                    pull = jnp.where(pull, ~go_push, go_pull)
-
-            out = (
-                new,
-                seen,
-                d_owned,
-                level + 1,
-                scanned + lvl_scanned.astype(jnp.float32),
-                pull,
-            )
-            if not trace:
-                return out, None
-            if cfg.mode == "top_down":
-                direction = jnp.int32(0)
-            elif cfg.mode == "bottom_up":
-                direction = jnp.int32(1)
-            else:
-                direction = state[5].astype(jnp.int32)
-            row = flightrec.trace_row(
-                level, t_words, fr.popcount(new), direction, t_branch,
-                t_shipped, jnp.count_nonzero(new).astype(jnp.int32),
-            )
-            return out, (level, row)
-
-        init = (
-            frontier,
-            seen,
-            d_owned,
-            jnp.int32(0),
-            jnp.float32(0),
-            init_dir,
-        )
-        state = loop.traced_while(
-            cond, step, init, trace=trace,
-            trace_levels=t_levels if trace else None,
-        )
-        frontier, seen, d_owned, level, scanned, _ = state[:6]
-        total_scanned = lax.psum(scanned, cfg.axes)
-        out = (d_owned[None], level[None], total_scanned[None])
-        if trace:
-            out = out + (state[6][None],)
-        return out
+        fmt = LaneFormat(arrays, owned_mask, lane_active, pg.n)
+        d_owned = jnp.where(fmt.owned(seen), 0, INF)
+        return run_levels(fmt, cfg, seen, seen, d_owned, max_levels,
+                          trace=trace, trace_levels=t_levels)
 
     return loop.jit_shard(body, mesh, graph_array_keys(pg), spec, trace=trace)
 

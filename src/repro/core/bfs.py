@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Tuple
 
 import numpy as np
 import jax
@@ -30,6 +30,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.core import collectives
+from repro.core import flightrec
 from repro.core import frontier as fr
 from repro.core import loop
 from repro.graph.csr import Graph
@@ -173,120 +174,328 @@ def _push_sparse(arrays, run, n_words, n, capacity):
     return jnp.pad(fr.pack(hit > 0), (0, n_words - n // fr.WORD_BITS))
 
 
-def _owned_runs(arrays, frontier_words):
-    """``int32[vmax]``: each owned frontier vertex's out-edge slots, 0 for
-    every other owned slot."""
-    offsets = arrays["out_offsets"]
-    vmax = offsets.shape[0] - 1
-    owned = fr.unpack(lax.dynamic_slice(
-        frontier_words, (arrays["word_start"],), (vmax // fr.WORD_BITS,)))
-    return (offsets[1:] - offsets[:-1]) * owned
+# ---------------------------------------------------------------------------
+# Frontier formats: what one level step needs to know of a frontier buffer
+# ---------------------------------------------------------------------------
 
 
-def frontier_demand(arrays, frontier_words, axes):
-    """``(demand, live)``: replicated int32 scalars of a frontier.
-    ``demand`` is the most out-edge slots any node's owned frontier
-    vertices have, which the sparse push compares with its capacity;
-    ``live`` the most frontier vertices any node holds.  One ``pmax`` over
-    ``axes`` gives both, so every node takes the same expansion branch, and
-    every node runs another level while any node's frontier is live: the
-    nodes' collectives pair up even if their frontiers differ."""
-    both = jnp.stack([_owned_runs(arrays, frontier_words).sum(),
-                      fr.popcount(frontier_words)])
-    if axes:
-        both = lax.pmax(both, axes)
-    return both[0], both[1]
+class VertexFormat:
+    """One search: a vertex-packed ``uint32[n_words]`` bitmap over vertex
+    ids below ``n``, replicated on every node, of which a node owns the
+    ``vmax`` bits at word ``word_start``.
 
-
-def _expand_push(arrays, frontier_words, n_words, use_pallas, meta=None, *,
-                 lanes=False, interpret=False, axes=(), n=None, sparse=None):
-    """Top-down: propagate frontier bits along the graph's edges (paper
-    Alg. 2 phase 1).  Returns the node's 'global queue' bitmap and whether
-    the level pushed the frontier's own out-edges (a bool scalar).
-
-    ``lanes=False``: vertex-packed ``uint32[n_words]`` (single-source)
-    over vertex ids below ``n``.  Two branches, the same on every node:
-    ``sparse`` (a Python bool) names one, or, when it is ``None``, the
-    frontier's :func:`frontier_demand` over ``axes`` against
-    :func:`push_capacity` picks one with a ``lax.cond``.
-
-    * ``sparse_push``, when no node's owned frontier has more out-edge
-      slots than the capacity: push those out-edges
-      (:func:`_push_sparse`);
-    * ``dense_scan`` otherwise: an owned vertex joins when any of its
-      in-edges starts in the frontier, one prefix count over the
-      dst-sorted in-edges read at ``in_offsets`` (:func:`fr.segment_or`),
-      so no per-edge scatter; only the node's owned window is set.
-
-    A node on one branch and a node on the other would lose the owned
-    destinations of the one whose frontier in-neighbours the other owns.
-    Phase 2 ORs the bitmaps together.
-    ``lanes=True``: lane-packed ``uint32[n_words, B/32]`` rows — the same
-    traversal bit-parallel over B concurrent searches (``analytics.msbfs``),
-    where ``n_words`` counts vertex ROWS and merge is a per-row lane-mask OR
-    scattered along the owned out-edges.  It and the Pallas path read every
-    edge: never sparse.
+    Besides the level loop's carry it carries the frontier's
+    ``(demand, live)`` pair (:meth:`carry`, slots 6 and 7): ``demand``
+    picks the next top-down expansion, ``live`` keeps the loop running.
+    ``pallas`` is ``(meta, interpret)`` for the Pallas frontier kernels (a
+    ``BFSPallasLayout``'s meta, interpreter only), ``None`` for the XLA
+    expansion.  ``sparse`` fixes top-down's expansion branch (:meth:`push`).
     """
-    full = jnp.array(False)
-    if use_pallas:
-        if lanes:
-            raise NotImplementedError("Pallas frontier kernels are "
-                                      "single-source (vertex-packed) only")
-        from repro.kernels import ops as kops
 
-        return kops.expand_push_pallas(frontier_words, arrays, meta, n_words,
-                                       interpret=interpret), full
-    if lanes:
-        src, dst = arrays["edge_src"], arrays["edge_dst"]
-        mask = jnp.arange(src.shape[0], dtype=jnp.int32) < arrays["edge_count"]
-        active = jnp.where(mask[:, None], frontier_words[src], jnp.uint32(0))
-        return fr.scatter_or_lanes(n_words, dst, active), full
+    def __init__(self, arrays, owned_mask, n, axes, pallas=None, sparse=None):
+        self.arrays = arrays
+        self.owned_mask = owned_mask  # bool[vmax]: the node's real vertices
+        self.n = n
+        self.axes = axes
+        self.pallas = pallas
+        self.sparse = sparse
 
-    def dense(_):
-        with jax.named_scope("dense_scan"):
-            active = fr.get_bits(frontier_words, arrays["in_src"])
-            return fr.segment_or(n_words, arrays["in_offsets"], active,
-                                 arrays["word_start"])
+    def branch(self, sparse):
+        """This format with top-down's expansion branch fixed."""
+        return VertexFormat(self.arrays, self.owned_mask, self.n, self.axes,
+                            self.pallas, sparse)
 
-    capacity = push_capacity(arrays["edge_dst"].shape[0])
+    def window(self, buf):
+        """``bool[vmax]``: the node's owned bits of ``buf``."""
+        words = self.owned_mask.shape[0] // fr.WORD_BITS
+        return fr.unpack(lax.dynamic_slice(
+            buf, (self.arrays["word_start"],), (words,)))
 
-    def push(_):
-        with jax.named_scope("sparse_push"):
-            return _push_sparse(arrays, _owned_runs(arrays, frontier_words),
-                                n_words, n, capacity)
+    def owned(self, buf):
+        return self.window(buf) & self.owned_mask
 
-    if capacity <= 0 or sparse is False:
-        return dense(None), full
-    if sparse:
-        return push(None), jnp.array(True)
-    go_sparse = frontier_demand(arrays, frontier_words, axes)[0] <= capacity
-    return lax.cond(go_sparse, push, dense, None), go_sparse
+    def unvisited(self, visited):
+        return ~self.window(visited) & self.owned_mask
+
+    def runs(self, frontier_words):
+        """``int32[vmax]``: each owned frontier vertex's out-edge slots, 0
+        for every other owned slot."""
+        owned = self.window(frontier_words)
+        offsets = self.arrays["out_offsets"]
+        return (offsets[1:] - offsets[:-1]) * owned
+
+    def edges(self, marked):
+        """Out-edges of the owned vertices ``marked``."""
+        return (self.arrays["deg_out"] * marked).sum()
+
+    def push(self, frontier_words):
+        """Top-down: propagate frontier bits along the graph's edges (paper
+        Alg. 2 phase 1).  Returns the node's 'global queue' bitmap and
+        whether the level pushed the frontier's own out-edges (a bool
+        scalar).  Two branches, the same on every node: ``self.sparse`` (a
+        Python bool) names one, or, when it is ``None``, the frontier's
+        demand (:meth:`carry`) against :func:`push_capacity` picks one
+        with a ``lax.cond``.
+
+        * ``sparse_push``, when no node's owned frontier has more out-edge
+          slots than the capacity: push those out-edges
+          (:func:`_push_sparse`);
+        * ``dense_scan`` otherwise: an owned vertex joins when any of its
+          in-edges starts in the frontier, one prefix count over the
+          dst-sorted in-edges read at ``in_offsets``
+          (:func:`fr.segment_or`), so no per-edge scatter; only the node's
+          owned window is set.
+
+        A node on one branch and a node on the other would lose the owned
+        destinations of the one whose frontier in-neighbours the other
+        owns.  Phase 2 ORs the bitmaps together.  The Pallas kernel reads
+        every edge: never sparse.
+        """
+        arrays, n_words = self.arrays, frontier_words.shape[0]
+        full = jnp.array(False)
+        if self.pallas is not None:
+            from repro.kernels import ops as kops
+
+            meta, interpret = self.pallas
+            return kops.expand_push_pallas(frontier_words, arrays, meta,
+                                           n_words, interpret=interpret), full
+
+        def dense(_):
+            with jax.named_scope("dense_scan"):
+                active = fr.get_bits(frontier_words, arrays["in_src"])
+                return fr.segment_or(n_words, arrays["in_offsets"], active,
+                                     arrays["word_start"])
+
+        capacity = push_capacity(arrays["edge_dst"].shape[0])
+
+        def push(_):
+            with jax.named_scope("sparse_push"):
+                return _push_sparse(arrays, self.runs(frontier_words),
+                                    n_words, self.n, capacity)
+
+        if capacity <= 0 or self.sparse is False:
+            return dense(None), full
+        if self.sparse:
+            return push(None), jnp.array(True)
+        go_sparse = self.carry(frontier_words)[0] <= capacity
+        return lax.cond(go_sparse, push, dense, None), go_sparse
+
+    def pull(self, frontier_words, visited_words):
+        """Bottom-up: every unvisited owned vertex probes its in-edges for
+        a parent in the frontier (Beamer; paper Sec. 3 'Parallelization
+        Schemes').  Never pushes."""
+        arrays, n_words = self.arrays, frontier_words.shape[0]
+        if self.pallas is not None:
+            from repro.kernels import ops as kops
+
+            meta, interpret = self.pallas
+            return kops.expand_pull_pallas(
+                frontier_words, visited_words, arrays, meta, n_words,
+                interpret=interpret), jnp.array(False)
+        src, dst = arrays["in_src"], arrays["in_dst"]
+        mask = jnp.arange(src.shape[0], dtype=jnp.int32) < arrays["in_count"]
+        parent_in_frontier = fr.get_bits(frontier_words, src) & mask
+        unvisited = ~fr.get_bits(visited_words, dst)
+        found = parent_in_frontier & unvisited
+        return fr.scatter_or(n_words, dst, found), jnp.array(False)
+
+    def carry(self, frontier_words):
+        """``(demand, live)``: replicated int32 scalars of a frontier.
+        ``demand`` is the most out-edge slots any node's owned frontier
+        vertices have, which the sparse push compares with its capacity;
+        ``live`` the most frontier vertices any node holds.  One ``pmax``
+        over the axes gives both, so every node takes the same expansion
+        branch, and every node runs another level while any node's
+        frontier is live: the nodes' collectives pair up even if their
+        frontiers differ."""
+        both = jnp.stack([self.runs(frontier_words).sum(),
+                          fr.popcount(frontier_words)])
+        if self.axes:
+            both = lax.pmax(both, self.axes)
+        return both[0], both[1]
+
+    def live(self, state):
+        return state[7]
+
+    def searches(self):
+        return 1
 
 
-def _expand_pull(arrays, frontier_words, visited_words, n_words, use_pallas,
-                 meta=None, *, lanes=False, interpret=False):
-    """Bottom-up: every unvisited owned vertex probes its in-edges for a
-    parent in the frontier (Beamer; paper Sec. 3 'Parallelization Schemes').
-    ``lanes=True`` runs the probe per search lane: a vertex can be settled
-    in one search and still pulling in another, all in one bitwise op."""
-    if use_pallas:
-        if lanes:
-            raise NotImplementedError("Pallas frontier kernels are "
-                                      "single-source (vertex-packed) only")
-        from repro.kernels import ops as kops
+class LaneFormat:
+    """A wave of B searches (``analytics.msbfs``): lane-packed
+    ``uint32[n_rows, B/32]`` rows, row ``v`` holding vertex ``v``'s bit in
+    each search; a node owns the ``vmax`` rows at ``v_start``.  Only the
+    ``lane_active`` lanes count towards the edges a level examines and
+    towards Beamer's β; the loop runs while any lane's frontier is live.
+    Both expansions read every edge: never sparse."""
 
-        return kops.expand_pull_pallas(frontier_words, visited_words, arrays,
-                                       meta, n_words, interpret=interpret)
-    src, dst = arrays["in_src"], arrays["in_dst"]
-    mask = jnp.arange(src.shape[0], dtype=jnp.int32) < arrays["in_count"]
-    if lanes:
-        parent = jnp.where(mask[:, None], frontier_words[src], jnp.uint32(0))
-        found = parent & ~visited_words[dst]
-        return fr.scatter_or_lanes(n_words, dst, found)
-    parent_in_frontier = fr.get_bits(frontier_words, src) & mask
-    unvisited = ~fr.get_bits(visited_words, dst)
-    found = parent_in_frontier & unvisited
-    return fr.scatter_or(n_words, dst, found)
+    def __init__(self, arrays, owned_mask, lane_active, n):
+        self.arrays = arrays
+        self.owned_mask = owned_mask  # bool[vmax]: the node's real vertices
+        self.lane_active = lane_active  # bool[B]
+        self.n = n
+
+    def window(self, buf):
+        """``bool[vmax, B]``: the node's owned rows of ``buf``."""
+        win = lax.dynamic_slice(buf, (self.arrays["v_start"], 0),
+                                (self.owned_mask.shape[0], buf.shape[1]))
+        return fr.lane_unpack(win)[:, :self.lane_active.shape[0]]
+
+    def owned(self, buf):
+        return self.window(buf) & self.owned_mask[:, None]
+
+    def unvisited(self, visited):
+        return (~self.window(visited) & self.owned_mask[:, None]
+                & self.lane_active[None, :])
+
+    def edges(self, marked):
+        """Out-edges of the owned vertices ``marked``, summed over lanes."""
+        return (self.arrays["deg_out"][:, None] * marked).sum()
+
+    def push(self, frontier):
+        """Top-down: each owned out-edge ORs its source row's lane mask
+        into its destination row, one scatter for all B searches."""
+        src, dst = self.arrays["edge_src"], self.arrays["edge_dst"]
+        mask = (jnp.arange(src.shape[0], dtype=jnp.int32)
+                < self.arrays["edge_count"])
+        active = jnp.where(mask[:, None], frontier[src], jnp.uint32(0))
+        return fr.scatter_or_lanes(frontier.shape[0], dst, active), None
+
+    def pull(self, frontier, visited):
+        """Bottom-up, the probe per search lane: a vertex can be settled in
+        one search and still pulling in another, all in one bitwise op."""
+        src, dst = self.arrays["in_src"], self.arrays["in_dst"]
+        mask = (jnp.arange(src.shape[0], dtype=jnp.int32)
+                < self.arrays["in_count"])
+        parent = jnp.where(mask[:, None], frontier[src], jnp.uint32(0))
+        found = parent & ~visited[dst]
+        return fr.scatter_or_lanes(frontier.shape[0], dst, found), None
+
+    def carry(self, frontier):
+        return ()
+
+    def live(self, state):
+        return fr.popcount(state[0])
+
+    def searches(self):
+        return jnp.maximum(self.lane_active.sum(dtype=jnp.int32), 1)
+
+
+# ---------------------------------------------------------------------------
+# The level step and the level loop
+# ---------------------------------------------------------------------------
+
+
+def level_step(fmt, cfg: BFSConfig, state, *, trace=False):
+    """One BFS level over a frontier in format ``fmt`` (:class:`VertexFormat`
+    or :class:`LaneFormat`).
+
+    ``state`` is the level loop's carry ``(frontier, visited, d_owned,
+    level, scanned, pull)`` followed by the format's own carry.  Returns
+    ``(next_state, rec)``: ``rec`` is ``None``, or with ``trace`` the
+    flight-recorder ``(level, row)``.
+    """
+    frontier, visited, d_owned, level, scanned, pull = state[:6]
+
+    # -- Phase 1: traversal ---------------------------------------------
+    with loop.phase("expand"):
+        if cfg.mode == "top_down":
+            gq, pushed = fmt.push(frontier)
+        elif cfg.mode == "bottom_up":
+            gq, pushed = fmt.pull(frontier, visited)
+        else:
+            gq, pushed = lax.cond(
+                pull, lambda _: fmt.pull(frontier, visited),
+                lambda _: fmt.push(frontier), None)
+
+        # edges examined this level (honest TEPS accounting): a push reads
+        # the frontier's out-edges, a pull probes the unvisited vertices'
+        m_f = fmt.edges(fmt.owned(frontier))
+        m_u = fmt.edges(fmt.unvisited(visited))
+        if cfg.mode == "bottom_up":
+            lvl_scanned = m_u
+        elif cfg.mode == "top_down":
+            lvl_scanned = m_f
+        else:
+            lvl_scanned = jnp.where(pull, m_u, m_f)
+
+    # -- Phase 2: butterfly frontier synchronization, on the flat words ----
+    with loop.phase("exchange"):
+        if trace:
+            t_words, t_branch, t_shipped = flightrec.or_sync_stats(gq, cfg)
+        merged = _sync_frontier(gq.reshape(-1), cfg).reshape(gq.shape)
+
+    # -- Update (enqueue-if-new as set ops) -------------------------------
+    with loop.phase("update"):
+        new = merged & ~visited
+        visited = visited | new
+        d_owned = jnp.where(fmt.owned(new), level + 1, d_owned)
+
+    # what the next level's expansion and the loop's condition read
+    with loop.phase("expand"):
+        carried = fmt.carry(new)
+
+    # -- Direction-optimizing switch (Beamer alpha/beta) ------------------
+    if cfg.mode == "direction_optimizing":
+        with loop.phase("direction"):
+            g_mf = lax.psum(m_f, cfg.axes)
+            g_mu = lax.psum(m_u, cfg.axes)
+            n_f = fr.popcount(new)
+            searches = fmt.searches()
+            go_pull = (g_mf.astype(jnp.float32)
+                       > g_mu.astype(jnp.float32) / cfg.alpha)
+            go_push = n_f.astype(jnp.float32) < searches * fmt.n / cfg.beta
+            pull = jnp.where(pull, ~go_push, go_pull)
+
+    out = (new, visited, d_owned, level + 1,
+           scanned + lvl_scanned.astype(jnp.float32), pull) + carried
+    if not trace:
+        return out, None
+    if cfg.mode == "direction_optimizing":
+        direction = state[5].astype(jnp.int32)  # the level's own direction
+    else:
+        direction = jnp.int32(cfg.mode == "bottom_up")
+    row = flightrec.trace_row(
+        level, t_words, fr.popcount(new), direction, t_branch, t_shipped,
+        jnp.count_nonzero(new).astype(jnp.int32),
+        0 if pushed is None else pushed,  # a lane wave never pushes sparse
+    )
+    return out, (level, row)
+
+
+def run_levels(fmt, cfg: BFSConfig, frontier, visited, d_owned, max_levels,
+               *, trace=False, trace_levels=None, pick=None):
+    """The level loop from a seeded ``frontier``: :func:`level_step` while
+    ``fmt`` says a frontier is live and fewer than ``max_levels`` levels
+    ran.  The first level pushes unless ``cfg.mode`` is bottom-up.
+
+    With ``pick``, top-down levels run as two inner loops, one per
+    :meth:`VertexFormat.branch`, ``pick(state)`` naming the one the next
+    level runs: 0 the sparse push, 1 the in-edge scan
+    (:func:`loop.traced_while`).
+
+    Returns the per-device outputs ``(d_owned, levels, edges examined)``,
+    each with a leading axis of 1, and with ``trace`` the flight-recorder
+    buffer of ``trace_levels`` rows.
+    """
+
+    def cond(state):
+        with loop.phase("cond"):
+            return (fmt.live(state) > 0) & (state[3] < max_levels)
+
+    steps = functools.partial(level_step, fmt, cfg, trace=trace)
+    if pick is not None:
+        steps = tuple(functools.partial(level_step, branch, cfg, trace=trace)
+                      for branch in (fmt.branch(True), fmt.branch(False)))
+    with loop.phase("expand"):
+        carried = fmt.carry(frontier)
+    init = (frontier, visited, d_owned, jnp.int32(0), jnp.float32(0),
+            jnp.array(cfg.mode == "bottom_up")) + carried  # False == push
+    state = loop.traced_while(cond, steps, init, trace=trace,
+                              trace_levels=trace_levels, pick=pick)
+    out = (state[2], state[3], lax.psum(state[4], cfg.axes))
+    out += (state[-1],) if trace else ()
+    return tuple(x[None] for x in out)
 
 
 def build_bfs_fn(
@@ -313,9 +522,7 @@ def build_bfs_fn(
     Pallas interpreter executes: pass ``interpret=True``.  On a mesh of TPU
     devices it raises, naming the lowerings the chip's compiler refuses.
     """
-    n_words = pg.n_words
     vmax = pg.vmax
-    wmax = pg.wmax
     max_levels = cfg.max_levels if cfg.max_levels is not None else pg.n
     spec = P(cfg.axes if len(cfg.axes) > 1 else cfg.axes[0])
     if cfg.use_pallas and mesh.devices.flat[0].platform == "tpu":
@@ -327,177 +534,40 @@ def build_bfs_fn(
         )
     if cfg.use_pallas and layout is None:
         raise ValueError("use_pallas=True requires a BFSPallasLayout")
-    meta = layout.meta if layout is not None else None
+    pallas = (layout.meta, interpret) if cfg.use_pallas else None
     array_keys = graph_array_keys(pg) + (
         tuple(sorted(layout.arrays)) if layout is not None else ()
     )
-    if trace:
-        from repro.core import flightrec
-
-        t_levels = flightrec.resolve_trace_levels(trace_levels, max_levels)
+    t_levels = (flightrec.resolve_trace_levels(trace_levels, max_levels)
+                if trace else None)
 
     def body(arrays, root):
         # [P, ...] -> local [...]  (shard_map gives a leading axis of 1)
         arrays = jax.tree.map(lambda a: a[0], arrays)
         v_start = arrays["v_start"]
         v_count = arrays["v_count"]
-        word_start = arrays["word_start"]
         vown_ids = jnp.arange(vmax, dtype=jnp.int32)
         owned_mask = vown_ids < v_count
 
-        visited = jnp.zeros((n_words,), jnp.uint32)
-        visited = fr.set_bit(visited, root)
-        frontier_words = visited
+        visited = fr.set_bit(jnp.zeros((pg.n_words,), jnp.uint32), root)
         d_owned = jnp.full((vmax,), INF, jnp.int32)
         is_owner = (root >= v_start) & (root < v_start + v_count)
-        d_owned = jnp.where(
-            is_owner & (vown_ids == root - v_start), 0, d_owned
-        )
+        d_owned = jnp.where(is_owner & (vown_ids == root - v_start), 0, d_owned)
 
-        if cfg.mode == "top_down":
-            init_dir = jnp.array(False)  # False == push
-        elif cfg.mode == "bottom_up":
-            init_dir = jnp.array(True)
-        else:
-            init_dir = jnp.array(False)
-
-        def cond(state):
-            level, live = state[3], state[7]
-            with loop.phase("cond"):
-                return (live > 0) & (level < max_levels)
-
-        def step(state, sparse=None):
-            frontier_words, visited, d_owned, level, scanned, pull = state[:6]
-
-            # -- Phase 1: traversal -------------------------------------
-            def do_push(_):
-                return _expand_push(
-                    arrays, frontier_words, n_words, cfg.use_pallas, meta,
-                    interpret=interpret, axes=cfg.axes, n=pg.n,
-                    sparse=sparse,
-                )
-
-            def do_pull(_):
-                return _expand_pull(
-                    arrays, frontier_words, visited, n_words, cfg.use_pallas,
-                    meta, interpret=interpret,
-                ), jnp.array(False)
-
-            with loop.phase("expand"):
-                if cfg.mode == "top_down":
-                    gq, pushed = do_push(None)
-                elif cfg.mode == "bottom_up":
-                    gq, pushed = do_pull(None)
-                else:
-                    gq, pushed = lax.cond(pull, do_pull, do_push, None)
-
-                # edges examined this level (honest TEPS accounting):
-                owned_front = fr.unpack(
-                    lax.dynamic_slice(frontier_words, (word_start,), (wmax,))
-                )[:vmax] & owned_mask
-                m_f = (arrays["deg_out"] * owned_front).sum()
-                owned_unvis = (
-                    ~fr.unpack(lax.dynamic_slice(visited, (word_start,),
-                                                 (wmax,)))[:vmax]
-                ) & owned_mask
-                m_u = (arrays["deg_out"] * owned_unvis).sum()
-                if cfg.mode == "bottom_up":
-                    lvl_scanned = m_u  # pull probes unvisited in-edges
-                elif cfg.mode == "top_down":
-                    lvl_scanned = m_f
-                else:
-                    lvl_scanned = jnp.where(pull, m_u, m_f)
-
-            # -- Phase 2: butterfly frontier synchronization -------------
-            with loop.phase("exchange"):
-                if trace:
-                    t_words, t_branch, t_shipped = flightrec.or_sync_stats(
-                        gq, cfg)
-                merged = _sync_frontier(gq, cfg)
-
-            # -- Update (enqueue-if-new as set ops) -----------------------
-            with loop.phase("update"):
-                new = merged & ~visited
-                visited = visited | new
-                owned_new = fr.unpack(
-                    lax.dynamic_slice(new, (word_start,), (wmax,))
-                )[:vmax] & owned_mask
-                d_owned = jnp.where(owned_new, level + 1, d_owned)
-
-            # the next level's expansion branch, and whether it runs
-            with loop.phase("expand"):
-                demand, live = frontier_demand(arrays, new, cfg.axes)
-
-            # -- Direction-optimizing switch (Beamer alpha/beta) ----------
-            if cfg.mode == "direction_optimizing":
-                with loop.phase("direction"):
-                    g_mf = lax.psum(m_f, cfg.axes)
-                    g_mu = lax.psum(m_u, cfg.axes)
-                    n_f = fr.popcount(new)
-                    go_pull = g_mf.astype(jnp.float32) > (
-                        g_mu.astype(jnp.float32) / cfg.alpha
-                    )
-                    go_push = n_f.astype(jnp.float32) < (pg.n / cfg.beta)
-                    pull = jnp.where(pull, ~go_push, go_pull)
-
-            out = (
-                new,
-                visited,
-                d_owned,
-                level + 1,
-                scanned + lvl_scanned.astype(jnp.float32),
-                pull,
-                demand,
-                live,
-            )
-            if not trace:
-                return out, None
-            if cfg.mode == "top_down":
-                direction = jnp.int32(0)
-            elif cfg.mode == "bottom_up":
-                direction = jnp.int32(1)
-            else:
-                direction = state[5].astype(jnp.int32)  # level's own dir
-            row = flightrec.trace_row(
-                level, t_words, fr.popcount(new), direction, t_branch,
-                t_shipped, jnp.count_nonzero(new).astype(jnp.int32), pushed,
-            )
-            return out, (level, row)
-
-        with loop.phase("expand"):
-            demand, live = frontier_demand(arrays, frontier_words, cfg.axes)
-        init = (
-            frontier_words,
-            visited,
-            d_owned,
-            jnp.int32(0),
-            jnp.float32(0),
-            init_dir,
-            demand,
-            live,
-        )
         # top-down levels run as two inner loops, one per expansion
         # branch, so that neither branch sits in a lax.cond: XLA's memory
         # space assignment prefetches the in-edge scan's bitmap into VMEM
         # in a loop body, and not in a conditional's branch
         capacity = push_capacity(pg.emax)
-        steps, pick = step, None
-        if cfg.mode != "bottom_up" and not cfg.use_pallas and capacity > 0:
-            steps = (functools.partial(step, sparse=True),
-                     functools.partial(step, sparse=False))
+        pick = None
+        if cfg.mode != "bottom_up" and pallas is None and capacity > 0:
 
-            def pick(state):  # 0: the sparse push, 1: the in-edge scan
+            def pick(state):  # the frontier's demand against the capacity
                 return (state[6] > capacity).astype(jnp.int32)
-        state = loop.traced_while(
-            cond, steps, init, trace=trace,
-            trace_levels=t_levels if trace else None, pick=pick,
-        )
-        frontier_words, visited, d_owned, level, scanned, _ = state[:6]
-        total_scanned = lax.psum(scanned, cfg.axes)
-        out = (d_owned[None], level[None], total_scanned[None])
-        if trace:
-            out = out + (state[-1][None],)
-        return out
+
+        fmt = VertexFormat(arrays, owned_mask, pg.n, cfg.axes, pallas)
+        return run_levels(fmt, cfg, visited, visited, d_owned, max_levels,
+                          trace=trace, trace_levels=t_levels, pick=pick)
 
     return loop.jit_shard(body, mesh, array_keys, spec, trace=trace)
 
@@ -539,6 +609,17 @@ def place_arrays(
     return {k: jax.device_put(v, sharding) for k, v in arrays.items()}
 
 
+def assemble_distances(pg: PartitionedGraph, d_owned) -> np.ndarray:
+    """``d_owned [P, vmax]`` -> global ``int64[n]`` distances (INT32_MAX
+    for unreached)."""
+    d_owned = np.asarray(d_owned)
+    dist = np.full(pg.n, np.iinfo(np.int32).max, dtype=np.int64)
+    for i in range(pg.p):
+        s, c = int(pg.v_start[i]), int(pg.v_count[i])
+        dist[s : s + c] = d_owned[i, :c]
+    return dist
+
+
 def distributed_bfs(
     pg: PartitionedGraph,
     mesh: jax.sharding.Mesh,
@@ -557,10 +638,5 @@ def distributed_bfs(
     arrays = place_arrays(pg, mesh, cfg.axes, layout)
     fn = build_bfs_fn(pg, mesh, cfg, layout, interpret=interpret)
     d_owned, levels, scanned = fn(arrays, jnp.int32(root))
-    d_owned = np.asarray(d_owned)
-    levels = int(np.max(levels))
-    dist = np.full(pg.n, np.iinfo(np.int32).max, dtype=np.int64)
-    for i in range(pg.p):
-        s, c = int(pg.v_start[i]), int(pg.v_count[i])
-        dist[s : s + c] = d_owned[i, :c]
-    return dist, levels, float(np.asarray(scanned)[0])
+    return (assemble_distances(pg, d_owned), int(np.max(levels)),
+            float(np.asarray(scanned)[0]))

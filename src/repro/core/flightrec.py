@@ -46,16 +46,18 @@ the EXACT predicates the collectives dispatch on), so the host reads row
 
 Cost contract: all recording is gated behind Python-level ``if trace:``
 in the builders — ``trace=False`` traces the byte-identical jaxpr of the
-pre-instrumentation program (asserted by test against a vendored seed
-copy), and ``trace=True`` adds only scalar ops + a handful of scalar
-``pmax`` collectives per level (≤ 10 % wall-clock on kron13/P=8, the
-acceptance budget).
+pre-instrumentation program (pinned by the StableHLO fingerprints of
+``tests/test_programs.py``), and ``trace=True`` adds only scalar ops + a
+handful of scalar ``pmax`` collectives per level (≤ 10 % wall-clock on
+kron13/P=8, the acceptance budget).
 
 Host side, :class:`TraversalTrace` turns the raw buffer into per-level
 tables, attributes analytic wire bytes per level via the §3/§12 byte
 model (reconciled against the compiled HLO through
 ``launch/hlo_stats.py``), and :func:`timed_bfs_levels` re-runs a BFS one
-compiled level-step per host call to attach wall-clock per level.
+compiled level-step per host call to attach wall-clock per level (the
+fused program's own :func:`repro.core.bfs.level_step`, so its rows are
+the fused trace's).
 """
 
 from __future__ import annotations
@@ -176,30 +178,13 @@ def monoid_sync_stats(new: jax.Array, prev: jax.Array, cfg, capacity: int):
     raise ValueError(f"unknown sync {cfg.sync!r}")
 
 
-def dense_sync_stats(buf: jax.Array, axes: Sequence[str]):
-    """Stats for an always-dense sync (BC's non-idempotent ADD merge):
-    nonzero words on the densest rank, branch 0, nothing shipped sparse."""
-    nz = _pmax_all(jnp.count_nonzero(buf.reshape(-1)).astype(jnp.int32), axes)
-    zero = jnp.int32(0)
-    return nz, zero, zero
-
-
 def trace_row(level, words, pop, direction, branch, shipped, changed,
               expand=0):
     """Assemble one ``int32[TRACE_COLS]`` row (LEVEL is stored 1-based so a
     zero LEVEL cell marks an unwritten row)."""
-    return jnp.stack(
-        [
-            jnp.asarray(level, jnp.int32) + 1,
-            jnp.asarray(words, jnp.int32),
-            jnp.asarray(pop, jnp.int32),
-            jnp.asarray(direction, jnp.int32),
-            jnp.asarray(branch, jnp.int32),
-            jnp.asarray(shipped, jnp.int32),
-            jnp.asarray(changed, jnp.int32),
-            jnp.asarray(expand, jnp.int32),
-        ]
-    )
+    cols = (words, pop, direction, branch, shipped, changed, expand)
+    return jnp.stack([jnp.asarray(level, jnp.int32) + 1]
+                     + [jnp.asarray(c, jnp.int32) for c in cols])
 
 
 def record(tbuf: jax.Array, index, row: jax.Array) -> jax.Array:
@@ -465,17 +450,17 @@ def traced_bfs(pg, mesh, root: int, cfg, *, trace_levels: Optional[int] = None):
     fn = bfs_mod.build_bfs_fn(pg, mesh, cfg, trace=True,
                               trace_levels=trace_levels)
     d_owned, levels, scanned, tbuf = fn(arrays, jnp.int32(root))
-    d_owned = np.asarray(d_owned)
-    dist = np.full(pg.n, np.iinfo(np.int32).max, dtype=np.int64)
-    for i in range(pg.p):
-        s, c = int(pg.v_start[i]), int(pg.v_count[i])
-        dist[s : s + c] = d_owned[i, :c]
-    trace = TraversalTrace.from_buffer(
-        tbuf, algo="bfs", sync=cfg.sync, p=pg.p, fanout=cfg.fanout,
+    return (bfs_mod.assemble_distances(pg, d_owned), int(np.max(levels)),
+            float(np.asarray(scanned)[0]), _bfs_trace(pg, cfg, tbuf))
+
+
+def _bfs_trace(pg, cfg, buf, wall_ms=None) -> TraversalTrace:
+    """The :class:`TraversalTrace` of a single-source BFS's rows."""
+    return TraversalTrace.from_buffer(
+        buf, algo="bfs", sync=cfg.sync, p=pg.p, fanout=cfg.fanout,
         n_words=pg.n_words, capacity=cfg.resolved_capacity(pg.n_words),
-        density_threshold=cfg.density_threshold,
+        density_threshold=cfg.density_threshold, wall_ms=wall_ms,
     )
-    return dist, int(np.max(levels)), float(np.asarray(scanned)[0]), trace
 
 
 def build_bfs_level_fn(pg, mesh, cfg):
@@ -486,15 +471,13 @@ def build_bfs_level_fn(pg, mesh, cfg):
     ``(new_frontier, visited, d_owned, pull, row)`` where ``row`` is the
     flight-recorder ``int32[P, TRACE_COLS]`` row for that level.  Frontier
     and visited bitmaps are replicated; ``d_owned`` is per-device.  The
-    per-level results are bit-exact vs the fused while-loop program —
+    step is the fused program's own :func:`repro.core.bfs.level_step`, so
+    the per-level results, trace rows included, are the fused program's;
     only the host sync between levels (what buys the wall-clock) differs.
     """
     from jax.sharding import PartitionSpec as P
     from repro.core import bfs as bfs_mod
 
-    n_words = pg.n_words
-    vmax = pg.vmax
-    wmax = pg.wmax
     spec = P(cfg.axes if len(cfg.axes) > 1 else cfg.axes[0])
     if cfg.use_pallas:
         raise NotImplementedError(
@@ -503,81 +486,18 @@ def build_bfs_level_fn(pg, mesh, cfg):
 
     def body(arrays, frontier_words, visited, d_owned, level, pull):
         arrays = jax.tree.map(lambda a: a[0], arrays)
-        d_owned = d_owned[0]
-        v_count = arrays["v_count"]
-        word_start = arrays["word_start"]
-        vown_ids = jnp.arange(vmax, dtype=jnp.int32)
-        owned_mask = vown_ids < v_count
-
-        def do_push(_):
-            return bfs_mod._expand_push(arrays, frontier_words, n_words, False,
-                                        axes=cfg.axes, n=pg.n)
-
-        def do_pull(_):
-            return bfs_mod._expand_pull(
-                arrays, frontier_words, visited, n_words, False
-            ), jnp.array(False)
-
-        if cfg.mode == "top_down":
-            gq, pushed = do_push(None)
-        elif cfg.mode == "bottom_up":
-            gq, pushed = do_pull(None)
-        else:
-            gq, pushed = lax.cond(pull, do_pull, do_push, None)
-
-        words, branch, shipped = or_sync_stats(gq, cfg)
-        merged = bfs_mod._sync_frontier(gq, cfg)
-        new = merged & ~visited
-        visited = visited | new
-        owned_new = fr.unpack(
-            lax.dynamic_slice(new, (word_start,), (wmax,))
-        )[:vmax] & owned_mask
-        d_owned = jnp.where(owned_new, level + 1, d_owned)
-
-        if cfg.mode == "direction_optimizing":
-            owned_front = fr.unpack(
-                lax.dynamic_slice(frontier_words, (word_start,), (wmax,))
-            )[:vmax] & owned_mask
-            m_f = (arrays["deg_out"] * owned_front).sum()
-            owned_unvis = (
-                ~fr.unpack(
-                    lax.dynamic_slice(visited, (word_start,), (wmax,))
-                )[:vmax]
-            ) & owned_mask
-            m_u = (arrays["deg_out"] * owned_unvis).sum()
-            g_mf = lax.psum(m_f, cfg.axes)
-            g_mu = lax.psum(m_u, cfg.axes)
-            n_f = fr.popcount(new)
-            go_pull = g_mf.astype(jnp.float32) > (
-                g_mu.astype(jnp.float32) / cfg.alpha
-            )
-            go_push = n_f.astype(jnp.float32) < (pg.n / cfg.beta)
-            next_pull = jnp.where(pull, ~go_push, go_pull)
-            direction = pull.astype(jnp.int32)
-        elif cfg.mode == "bottom_up":
-            next_pull = pull
-            direction = jnp.int32(1)
-        else:
-            next_pull = pull
-            direction = jnp.int32(0)
-
-        row = trace_row(
-            level, words, fr.popcount(new), direction, branch, shipped,
-            jnp.count_nonzero(new).astype(jnp.int32), pushed,
-        )
+        owned_mask = jnp.arange(pg.vmax, dtype=jnp.int32) < arrays["v_count"]
+        fmt = bfs_mod.VertexFormat(arrays, owned_mask, pg.n, cfg.axes)
+        state = (frontier_words, visited, d_owned[0], level, jnp.float32(0),
+                 pull)
+        out, (_, row) = bfs_mod.level_step(fmt, cfg, state, trace=True)
+        new, visited, d_owned, _, _, next_pull = out[:6]
         return new, visited, d_owned[None], next_pull, row[None]
 
-    shard_fn = jax.shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(
-            {k: spec for k in bfs_mod.graph_array_keys(pg)},
-            P(), P(), spec, P(), P(),
-        ),
-        out_specs=(P(), P(), spec, P(), spec),
-        check_vma=False,
-    )
-    return jax.jit(shard_fn)
+    graph = {k: spec for k in bfs_mod.graph_array_keys(pg)}
+    return jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=(graph, P(), P(), spec, P(), P()),
+        out_specs=(P(), P(), spec, P(), spec), check_vma=False))
 
 
 def timed_bfs_levels(
@@ -600,32 +520,26 @@ def timed_bfs_levels(
     max_levels = cfg.max_levels if cfg.max_levels is not None else pg.n
     t_levels = resolve_trace_levels(trace_levels, max_levels)
 
-    def init_state():
+    def init_state():  # fn's (frontier, visited, d_owned, level, pull)
         frontier = np.zeros(pg.n_words, dtype=np.uint32)
         frontier[root >> 5] |= np.uint32(1) << np.uint32(root & 31)
-        visited = frontier.copy()
         d_owned = np.full((pg.p, pg.vmax), np.iinfo(np.int32).max, np.int32)
-        for i in range(pg.p):
-            s, c = int(pg.v_start[i]), int(pg.v_count[i])
-            if s <= root < s + c:
-                d_owned[i, root - s] = 0
-        pull = np.bool_(cfg.mode == "bottom_up")
-        return (jnp.asarray(frontier), jnp.asarray(visited),
-                jnp.asarray(d_owned), jnp.asarray(pull))
+        owner = pg.owner_of(root)
+        d_owned[owner, root - int(pg.v_start[owner])] = 0
+        return (jnp.asarray(frontier), jnp.asarray(frontier),
+                jnp.asarray(d_owned), jnp.int32(0),
+                jnp.asarray(cfg.mode == "bottom_up"))
 
     if warmup:  # compile + first-touch outside the timed loop.  TWO steps:
         # the first call takes uncommitted host arrays, later calls feed
         # back device-committed outputs — distinct specializations, and the
         # steady-state one is the one the timed loop must not compile in.
-        frontier, visited, d_owned, pull = init_state()
-        f, v, d, p, _ = fn(arrays, frontier, visited, d_owned,
-                           jnp.int32(0), pull)
+        f, v, d, p, _ = fn(arrays, *init_state())
         jax.block_until_ready(fn(arrays, f, v, d, jnp.int32(1), p))
 
-    frontier, visited, d_owned, pull = init_state()
+    frontier, visited, d_owned, _, pull = init_state()
     rows, walls = [], []
-    level = 0
-    while level < max_levels:
+    for level in range(max_levels):
         t0 = time.perf_counter()
         frontier, visited, d_owned, pull, row = fn(
             arrays, frontier, visited, d_owned, jnp.int32(level), pull
@@ -633,20 +547,9 @@ def timed_bfs_levels(
         row = np.asarray(jax.block_until_ready(row))[0]
         walls.append((time.perf_counter() - t0) * 1e3)
         rows.append(row)
-        level += 1
         if row[COL_POP] == 0:  # frontier exhausted
             break
 
-    d_owned = np.asarray(d_owned)
-    dist = np.full(pg.n, np.iinfo(np.int32).max, dtype=np.int64)
-    for i in range(pg.p):
-        s, c = int(pg.v_start[i]), int(pg.v_count[i])
-        dist[s : s + c] = d_owned[i, :c]
     buf = np.asarray(rows[:t_levels], dtype=np.int32).reshape(-1, TRACE_COLS)
-    trace = TraversalTrace.from_buffer(
-        buf, algo="bfs", sync=cfg.sync, p=pg.p, fanout=cfg.fanout,
-        n_words=pg.n_words, capacity=cfg.resolved_capacity(pg.n_words),
-        density_threshold=cfg.density_threshold,
-        wall_ms=np.asarray(walls[: len(buf)]),
-    )
-    return dist, trace
+    return (bfs_mod.assemble_distances(pg, d_owned),
+            _bfs_trace(pg, cfg, buf, np.asarray(walls[: len(buf)])))

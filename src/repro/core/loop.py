@@ -26,6 +26,14 @@ The helpers are pure code motion from the pre-§19 builders: a delegating
 builder stages a byte-identical StableHLO program (asserted against
 recorded fingerprints), so the refactor is invisible to the compiler.
 
+BFS and MS-BFS share more than the loop: one level step,
+:func:`repro.core.bfs.level_step` (expand, exchange, update, Beamer's
+switch), written once over two frontier formats —
+:class:`~repro.core.bfs.VertexFormat` (one search, vertex-packed) and
+:class:`~repro.core.bfs.LaneFormat` (a 32-lane wave, lane-packed) — which
+:func:`repro.core.bfs.run_levels` runs on :func:`traced_while`.  The
+host-segmented step of :mod:`repro.core.flightrec` is one call of it.
+
 :func:`phase` names the phases of a level step with ``jax.named_scope``
 (``traversal.expand``, ``.exchange``, ``.update``, ``.direction``,
 ``.cond``).  A scope changes only the ``op_name`` metadata of the ops

@@ -40,8 +40,8 @@ from repro.core import loop
 from repro.core.bfs import (
     INF,
     BFSConfig,
-    _expand_push,
     _sync_frontier,
+    LaneFormat,
     graph_array_keys,
     place_arrays,
 )
@@ -172,6 +172,7 @@ def build_bc_fn(
             == lane_ids[:, None]
         ) & lane_active[:, None]
         seen0 = fr.scatter_or_lanes(n_rows, seed_rows, fr.lane_pack(onehot))
+        fmt = LaneFormat(arrays, owned_mask, lane_active, pg.n)
 
         sigma0 = jnp.zeros((n_rows, n_lanes), jnp.float32).at[
             seed_rows, lane_ids
@@ -196,7 +197,7 @@ def build_bc_fn(
         def fstep(state):
             frontier, seen, lvl, sigma, level, scanned = state[:6]
 
-            gq, _ = _expand_push(arrays, frontier, n_rows, False, lanes=True)
+            gq, _ = fmt.push(frontier)
             if trace:
                 t_words, t_branch, t_shipped = flightrec.or_sync_stats(
                     gq.reshape(-1), cfg
@@ -222,10 +223,7 @@ def build_bc_fn(
             lvl = jnp.where(lanes_of(new), level + 1, lvl)
 
             # edges examined: out-degree of owned frontier rows, per lane
-            owned_front = lanes_of(
-                lax.dynamic_slice(frontier, (v_start, 0), (vmax, bw))
-            ) & owned_mask[:, None]
-            m_f = (arrays["deg_out"][:, None] * owned_front).sum()
+            m_f = fmt.edges(fmt.owned(frontier))
 
             out = (
                 new,

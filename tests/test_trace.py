@@ -193,6 +193,32 @@ def test_timed_bfs_levels_exact_and_timed():
     assert doc["otherData"]["schema"] == flightrec.TRACE_SCHEMA
 
 
+@pytest.mark.parametrize("mode,sync,alpha,beta,seed", [
+    ("direction_optimizing", "butterfly", 2.0, 18.0, 3),
+    ("direction_optimizing", "butterfly", 1.0, 4.0, 2),
+    ("top_down", "adaptive", 15.0, 18.0, 3),
+])
+def test_timed_bfs_levels_replays_the_fused_trace(mode, sync, alpha, beta,
+                                                  seed):
+    """The host-segmented level step runs the fused program's traversal:
+    every column of every flight-recorder row (Beamer's direction and the
+    expansion branch included) and the distances agree."""
+    import jax
+
+    mesh = jax.make_mesh((4,), ("data",), devices=jax.devices()[:4],
+                         axis_types=(jax.sharding.AxisType.Auto,))
+    pg = partition.partition_1d(generators.kronecker(12, 16, seed=seed), 4)
+    cfg = bfs.BFSConfig(axes=("data",), sync=sync, mode=mode, alpha=alpha,
+                        beta=beta)
+    d_fused, _, _, fused = flightrec.traced_bfs(pg, mesh, 0, cfg)
+    d_seg, seg = flightrec.timed_bfs_levels(pg, mesh, cfg, 0)
+    np.testing.assert_array_equal(d_seg, d_fused)
+    assert seg.data.shape == fused.data.shape
+    for col, name in enumerate(flightrec.COL_NAMES):
+        np.testing.assert_array_equal(seg.data[:, col], fused.data[:, col],
+                                      err_msg=name)
+
+
 def test_untimed_trace_renders_as_instants():
     mesh = _mesh8()
     g = GRAPHS["torus"]()
