@@ -144,34 +144,74 @@ def _sync_frontier(words: jax.Array, cfg: BFSConfig) -> jax.Array:
 
 
 def push_capacity(emax: int) -> int:
-    """``K``, the out-edge slots the sparse push expands in one level: a
-    thirty-second of a shard's edge slots.  A sparse level costs about
-    ``vmax + 3K`` random accesses against the in-edge scan's ``emax``."""
+    """``K``, the edge slots a sparse expansion (the push of the frontier's
+    out-edges, the pull of the unvisited vertices' in-edges) reads in one
+    level: a thirty-second of a shard's edge slots.  A sparse level costs
+    about ``vmax + 3K`` random accesses against the in-edge scan's
+    ``emax``."""
     return emax // 32
+
+
+def pick_expansion(push_demand, pull_demand, capacity):
+    """Top-down's expansion branch, as the flight recorder's EXPAND code:
+    push when the frontier's out-edge slots fit ``capacity``, else pull
+    when the unvisited vertices' in-edge slots fit it, else scan."""
+    return jnp.where(
+        push_demand <= capacity, flightrec.EXPAND_PUSH,
+        jnp.where(pull_demand <= capacity, flightrec.EXPAND_PULL,
+                  flightrec.EXPAND_SCAN)).astype(jnp.int32)
+
+
+def _run_slots(offsets, run, capacity, emax):
+    """Lay the vertices' runs of sorted edges end to end in ``capacity``
+    slots.  ``offsets`` (``int32[vmax + 1]``) marks each vertex's run in
+    an edge array of ``emax`` slots; ``run`` (``int32[vmax]``) is the run's
+    length for the vertices laid out, 0 for the others, and the lengths
+    fit ``capacity`` together.  Returns ``(ends, edge)``: the inclusive
+    prefix sum of ``run`` and, for each slot below ``ends[-1]``, the edge
+    it holds (``int32[capacity]``; slots past it hold no run's edge).
+
+    Slot ``s`` in the run of vertex ``v`` holds edge ``s`` plus the edges
+    of the vertices before ``v`` that were left out.  Each vertex adds its
+    left-out edges at its run's first slot, and a prefix sum over the
+    slots hands every slot its offset: no search, no sort."""
+    ends = fr._prefix_sum(run, emax)
+    left_out = offsets[1:] - offsets[:-1] - run
+    slot = jnp.arange(capacity, dtype=jnp.int32)
+    edge = slot + fr._prefix_sum(
+        fr.scatter_int32(capacity, ends - run, left_out, "add"), emax)
+    return ends, edge
 
 
 def _push_sparse(arrays, run, n_words, n, capacity):
     """Set the out-neighbours of the owned frontier vertices, whose runs of
     (src, dst)-sorted out-edges have lengths ``run`` (``int32[vmax]``, 0
     off the frontier) and fit ``capacity`` slots together; vertex ids are
-    below ``n``.
-
-    Slot ``s`` of the frontier's concatenated runs lies in the run of
-    vertex ``v`` and is edge ``s`` plus the out-edges of the vertices
-    before ``v`` that were not pushed.  Each vertex adds its unpushed
-    out-edges at its run's first slot, and a prefix sum over the slots
-    hands every slot its offset: no search, no sort.  Destinations may be
-    any vertex; phase 2 ORs the nodes' bitmaps."""
-    offsets = arrays["out_offsets"]
-    emax = arrays["edge_dst"].shape[0]
-    first = fr._prefix_sum(run, emax) - run
-    unpushed = offsets[1:] - offsets[:-1] - run
-    slot = jnp.arange(capacity, dtype=jnp.int32)
-    edge = slot + fr._prefix_sum(
-        fr.scatter_int32(capacity, first, unpushed, "add"), emax)
+    below ``n``.  Destinations may be any vertex; phase 2 ORs the nodes'
+    bitmaps."""
+    ends, edge = _run_slots(arrays["out_offsets"], run, capacity,
+                            arrays["edge_dst"].shape[0])
     dst = jnp.take(arrays["edge_dst"], edge, mode="clip")
-    hit = fr.scatter_int32(n, jnp.where(slot < run.sum(), dst, n), 1, "max")
+    slot = jnp.arange(capacity, dtype=jnp.int32)
+    hit = fr.scatter_int32(n, jnp.where(slot < ends[-1], dst, n), 1, "max")
     return jnp.pad(fr.pack(hit > 0), (0, n_words - n // fr.WORD_BITS))
+
+
+def _pull_sparse(arrays, run, frontier_words, capacity):
+    """Set the owned unvisited vertices with an in-neighbour in the
+    frontier.  Their runs of (dst, src)-sorted in-edges have lengths
+    ``run`` (``int32[vmax]``, 0 for every other owned slot) and fit
+    ``capacity`` slots together.  The runs' sources are read at the slots
+    (:func:`_run_slots`) and each run's frontier bits ORed by one prefix
+    count (:func:`fr.segment_or`); the slots past the last run are never
+    read.  Only the node's owned window is set, as by the in-edge scan."""
+    ends, edge = _run_slots(arrays["in_offsets"], run, capacity,
+                            arrays["in_src"].shape[0])
+    active = fr.get_bits(frontier_words,
+                         jnp.take(arrays["in_src"], edge, mode="clip"))
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
+    return fr.segment_or(frontier_words.shape[0], offsets, active,
+                         arrays["word_start"])
 
 
 # ---------------------------------------------------------------------------
@@ -184,26 +224,27 @@ class VertexFormat:
     ids below ``n``, replicated on every node, of which a node owns the
     ``vmax`` bits at word ``word_start``.
 
-    Besides the level loop's carry it carries the frontier's
-    ``(demand, live)`` pair (:meth:`carry`, slots 6 and 7): ``demand``
-    picks the next top-down expansion, ``live`` keeps the loop running.
-    ``pallas`` is ``(meta, interpret)`` for the Pallas frontier kernels (a
-    ``BFSPallasLayout``'s meta, interpreter only), ``None`` for the XLA
-    expansion.  ``sparse`` fixes top-down's expansion branch (:meth:`push`).
+    Besides the level loop's carry it carries the ``(push demand, live,
+    pull demand)`` of a frontier (:meth:`carry`, slots 6 to 8): the two
+    demands pick the next top-down expansion, ``live`` keeps the loop
+    running.  ``pallas`` is ``(meta, interpret)`` for the Pallas frontier
+    kernels (a ``BFSPallasLayout``'s meta, interpreter only), ``None`` for
+    the XLA expansion.  ``expand`` fixes top-down's expansion branch, an
+    EXPAND code of :mod:`repro.core.flightrec` (:meth:`push`).
     """
 
-    def __init__(self, arrays, owned_mask, n, axes, pallas=None, sparse=None):
+    def __init__(self, arrays, owned_mask, n, axes, pallas=None, expand=None):
         self.arrays = arrays
         self.owned_mask = owned_mask  # bool[vmax]: the node's real vertices
         self.n = n
         self.axes = axes
         self.pallas = pallas
-        self.sparse = sparse
+        self.expand = expand
 
-    def branch(self, sparse):
+    def branch(self, expand):
         """This format with top-down's expansion branch fixed."""
         return VertexFormat(self.arrays, self.owned_mask, self.n, self.axes,
-                            self.pallas, sparse)
+                            self.pallas, expand)
 
     def window(self, buf):
         """``bool[vmax]``: the node's owned bits of ``buf``."""
@@ -224,43 +265,53 @@ class VertexFormat:
         offsets = self.arrays["out_offsets"]
         return (offsets[1:] - offsets[:-1]) * owned
 
+    def in_runs(self, visited_words):
+        """``int32[vmax]``: each owned unvisited vertex's in-edge slots, 0
+        for every other owned slot."""
+        offsets = self.arrays["in_offsets"]
+        return (offsets[1:] - offsets[:-1]) * self.unvisited(visited_words)
+
     def edges(self, marked):
         """Out-edges of the owned vertices ``marked``."""
         return (self.arrays["deg_out"] * marked).sum()
 
-    def push(self, frontier_words):
+    def push(self, frontier_words, visited_words):
         """Top-down: propagate frontier bits along the graph's edges (paper
-        Alg. 2 phase 1).  Returns the node's 'global queue' bitmap and
-        whether the level pushed the frontier's own out-edges (a bool
-        scalar).  Two branches, the same on every node: ``self.sparse`` (a
-        Python bool) names one, or, when it is ``None``, the frontier's
-        demand (:meth:`carry`) against :func:`push_capacity` picks one
-        with a ``lax.cond``.
+        Alg. 2 phase 1).  Returns the node's 'global queue' bitmap and the
+        level's EXPAND code (an int32 scalar).  Three branches, the same on
+        every node: ``self.expand`` names one, or, when it is ``None``, the
+        two demands (:meth:`carry`) against :func:`push_capacity` pick one
+        (:func:`pick_expansion`) with a ``lax.switch``.
 
         * ``sparse_push``, when no node's owned frontier has more out-edge
           slots than the capacity: push those out-edges
           (:func:`_push_sparse`);
+        * ``sparse_pull``, else when no node's owned unvisited vertices
+          have more in-edge slots than the capacity: read only those
+          in-edges (:func:`_pull_sparse`), Beamer's bottom-up work set
+          without the early exit; only the node's owned window is set;
         * ``dense_scan`` otherwise: an owned vertex joins when any of its
           in-edges starts in the frontier, one prefix count over the
           dst-sorted in-edges read at ``in_offsets``
           (:func:`fr.segment_or`), so no per-edge scatter; only the node's
           owned window is set.
 
-        A node on one branch and a node on the other would lose the owned
-        destinations of the one whose frontier in-neighbours the other
-        owns.  Phase 2 ORs the bitmaps together.  The Pallas kernel reads
-        every edge: never sparse.
+        The scan sets visited vertices too; the update masks them.  A
+        node on the push and a node on another branch would lose the
+        owned destinations of the one whose frontier in-neighbours the
+        other owns.  Phase 2 ORs the bitmaps together.  The Pallas kernel
+        reads every edge: never sparse.
         """
         arrays, n_words = self.arrays, frontier_words.shape[0]
-        full = jnp.array(False)
         if self.pallas is not None:
             from repro.kernels import ops as kops
 
             meta, interpret = self.pallas
-            return kops.expand_push_pallas(frontier_words, arrays, meta,
-                                           n_words, interpret=interpret), full
+            return kops.expand_push_pallas(
+                frontier_words, arrays, meta, n_words, interpret=interpret
+            ), jnp.int32(flightrec.EXPAND_SCAN)
 
-        def dense(_):
+        def scan():
             with jax.named_scope("dense_scan"):
                 active = fr.get_bits(frontier_words, arrays["in_src"])
                 return fr.segment_or(n_words, arrays["in_offsets"], active,
@@ -268,17 +319,23 @@ class VertexFormat:
 
         capacity = push_capacity(arrays["edge_dst"].shape[0])
 
-        def push(_):
+        def push():
             with jax.named_scope("sparse_push"):
                 return _push_sparse(arrays, self.runs(frontier_words),
                                     n_words, self.n, capacity)
 
-        if capacity <= 0 or self.sparse is False:
-            return dense(None), full
-        if self.sparse:
-            return push(None), jnp.array(True)
-        go_sparse = self.carry(frontier_words)[0] <= capacity
-        return lax.cond(go_sparse, push, dense, None), go_sparse
+        def pull():
+            with jax.named_scope("sparse_pull"):
+                return _pull_sparse(arrays, self.in_runs(visited_words),
+                                    frontier_words, capacity)
+
+        branches = (scan, push, pull)  # indexed by EXPAND code
+        expand = flightrec.EXPAND_SCAN if capacity <= 0 else self.expand
+        if expand is not None:
+            return branches[expand](), jnp.int32(expand)
+        push_demand, _, pull_demand = self.carry(frontier_words, visited_words)
+        expand = pick_expansion(push_demand, pull_demand, capacity)
+        return lax.switch(expand, branches), expand
 
     def pull(self, frontier_words, visited_words):
         """Bottom-up: every unvisited owned vertex probes its in-edges for
@@ -291,28 +348,32 @@ class VertexFormat:
             meta, interpret = self.pallas
             return kops.expand_pull_pallas(
                 frontier_words, visited_words, arrays, meta, n_words,
-                interpret=interpret), jnp.array(False)
+                interpret=interpret), jnp.int32(flightrec.EXPAND_SCAN)
         src, dst = arrays["in_src"], arrays["in_dst"]
         mask = jnp.arange(src.shape[0], dtype=jnp.int32) < arrays["in_count"]
         parent_in_frontier = fr.get_bits(frontier_words, src) & mask
         unvisited = ~fr.get_bits(visited_words, dst)
         found = parent_in_frontier & unvisited
-        return fr.scatter_or(n_words, dst, found), jnp.array(False)
+        return (fr.scatter_or(n_words, dst, found),
+                jnp.int32(flightrec.EXPAND_SCAN))
 
-    def carry(self, frontier_words):
-        """``(demand, live)``: replicated int32 scalars of a frontier.
-        ``demand`` is the most out-edge slots any node's owned frontier
-        vertices have, which the sparse push compares with its capacity;
-        ``live`` the most frontier vertices any node holds.  One ``pmax``
-        over the axes gives both, so every node takes the same expansion
-        branch, and every node runs another level while any node's
-        frontier is live: the nodes' collectives pair up even if their
-        frontiers differ."""
-        both = jnp.stack([self.runs(frontier_words).sum(),
-                          fr.popcount(frontier_words)])
+    def carry(self, frontier_words, visited_words):
+        """``(push demand, live, pull demand)``: replicated int32 scalars of
+        a frontier and the visited set.  The push demand is the most
+        out-edge slots any node's owned frontier vertices have, the pull
+        demand the most in-edge slots any node's owned unvisited vertices
+        have, which top-down compares with its capacity
+        (:func:`pick_expansion`); ``live`` the most frontier vertices any
+        node holds.  One ``pmax`` over the axes gives all three, so every
+        node takes the same expansion branch, and every node runs another
+        level while any node's frontier is live: the nodes' collectives
+        pair up even if their frontiers differ."""
+        three = jnp.stack([self.runs(frontier_words).sum(),
+                           fr.popcount(frontier_words),
+                           self.in_runs(visited_words).sum()])
         if self.axes:
-            both = lax.pmax(both, self.axes)
-        return both[0], both[1]
+            three = lax.pmax(three, self.axes)
+        return three[0], three[1], three[2]
 
     def live(self, state):
         return state[7]
@@ -352,9 +413,10 @@ class LaneFormat:
         """Out-edges of the owned vertices ``marked``, summed over lanes."""
         return (self.arrays["deg_out"][:, None] * marked).sum()
 
-    def push(self, frontier):
+    def push(self, frontier, visited=None):
         """Top-down: each owned out-edge ORs its source row's lane mask
-        into its destination row, one scatter for all B searches."""
+        into its destination row, one scatter for all B searches
+        (``visited`` is not read)."""
         src, dst = self.arrays["edge_src"], self.arrays["edge_dst"]
         mask = (jnp.arange(src.shape[0], dtype=jnp.int32)
                 < self.arrays["edge_count"])
@@ -371,7 +433,7 @@ class LaneFormat:
         found = parent & ~visited[dst]
         return fr.scatter_or_lanes(frontier.shape[0], dst, found), None
 
-    def carry(self, frontier):
+    def carry(self, frontier, visited):
         return ()
 
     def live(self, state):
@@ -400,13 +462,13 @@ def level_step(fmt, cfg: BFSConfig, state, *, trace=False):
     # -- Phase 1: traversal ---------------------------------------------
     with loop.phase("expand"):
         if cfg.mode == "top_down":
-            gq, pushed = fmt.push(frontier)
+            gq, expand = fmt.push(frontier, visited)
         elif cfg.mode == "bottom_up":
-            gq, pushed = fmt.pull(frontier, visited)
+            gq, expand = fmt.pull(frontier, visited)
         else:
-            gq, pushed = lax.cond(
+            gq, expand = lax.cond(
                 pull, lambda _: fmt.pull(frontier, visited),
-                lambda _: fmt.push(frontier), None)
+                lambda _: fmt.push(frontier, visited), None)
 
         # edges examined this level (honest TEPS accounting): a push reads
         # the frontier's out-edges, a pull probes the unvisited vertices'
@@ -433,7 +495,7 @@ def level_step(fmt, cfg: BFSConfig, state, *, trace=False):
 
     # what the next level's expansion and the loop's condition read
     with loop.phase("expand"):
-        carried = fmt.carry(new)
+        carried = fmt.carry(new, visited)
 
     # -- Direction-optimizing switch (Beamer alpha/beta) ------------------
     if cfg.mode == "direction_optimizing":
@@ -458,7 +520,7 @@ def level_step(fmt, cfg: BFSConfig, state, *, trace=False):
     row = flightrec.trace_row(
         level, t_words, fr.popcount(new), direction, t_branch, t_shipped,
         jnp.count_nonzero(new).astype(jnp.int32),
-        0 if pushed is None else pushed,  # a lane wave never pushes sparse
+        0 if expand is None else expand,  # a lane wave never runs sparse
     )
     return out, (level, row)
 
@@ -469,10 +531,10 @@ def run_levels(fmt, cfg: BFSConfig, frontier, visited, d_owned, max_levels,
     ``fmt`` says a frontier is live and fewer than ``max_levels`` levels
     ran.  The first level pushes unless ``cfg.mode`` is bottom-up.
 
-    With ``pick``, top-down levels run as two inner loops, one per
+    With ``pick``, top-down levels run as three inner loops, one per
     :meth:`VertexFormat.branch`, ``pick(state)`` naming the one the next
-    level runs: 0 the sparse push, 1 the in-edge scan
-    (:func:`loop.traced_while`).
+    level runs by its EXPAND code: 0 the in-edge scan, 1 the sparse push,
+    2 the sparse pull (:func:`loop.traced_while`).
 
     Returns the per-device outputs ``(d_owned, levels, edges examined)``,
     each with a leading axis of 1, and with ``trace`` the flight-recorder
@@ -485,10 +547,12 @@ def run_levels(fmt, cfg: BFSConfig, frontier, visited, d_owned, max_levels,
 
     steps = functools.partial(level_step, fmt, cfg, trace=trace)
     if pick is not None:
-        steps = tuple(functools.partial(level_step, branch, cfg, trace=trace)
-                      for branch in (fmt.branch(True), fmt.branch(False)))
+        steps = tuple(
+            functools.partial(level_step, fmt.branch(expand), cfg, trace=trace)
+            for expand in (flightrec.EXPAND_SCAN, flightrec.EXPAND_PUSH,
+                           flightrec.EXPAND_PULL))
     with loop.phase("expand"):
-        carried = fmt.carry(frontier)
+        carried = fmt.carry(frontier, visited)
     init = (frontier, visited, d_owned, jnp.int32(0), jnp.float32(0),
             jnp.array(cfg.mode == "bottom_up")) + carried  # False == push
     state = loop.traced_while(cond, steps, init, trace=trace,
@@ -554,16 +618,16 @@ def build_bfs_fn(
         is_owner = (root >= v_start) & (root < v_start + v_count)
         d_owned = jnp.where(is_owner & (vown_ids == root - v_start), 0, d_owned)
 
-        # top-down levels run as two inner loops, one per expansion
-        # branch, so that neither branch sits in a lax.cond: XLA's memory
-        # space assignment prefetches the in-edge scan's bitmap into VMEM
-        # in a loop body, and not in a conditional's branch
+        # top-down levels run as three inner loops, one per expansion
+        # branch, so that no branch sits in a lax.cond: XLA's memory space
+        # assignment prefetches the in-edge scan's bitmap into VMEM in a
+        # loop body, and not in a conditional's branch
         capacity = push_capacity(pg.emax)
         pick = None
         if cfg.mode != "bottom_up" and pallas is None and capacity > 0:
 
-            def pick(state):  # the frontier's demand against the capacity
-                return (state[6] > capacity).astype(jnp.int32)
+            def pick(state):  # the two demands against the capacity
+                return pick_expansion(state[6], state[8], capacity)
 
         fmt = VertexFormat(arrays, owned_mask, pg.n, cfg.axes, pallas)
         return run_levels(fmt, cfg, visited, visited, d_owned, max_levels,

@@ -25,10 +25,11 @@ col   name         meaning
                    compaction when the sparse wire format ran, else 0
 6     CHANGED      words the merge actually changed (OR: words gaining
                    bits; MIN: words lowered) — the monoid-changed count
-7     EXPAND       phase-1 expansion branch: 1 when single-source
-                   top-down pushed the frontier's own out-edges, 0 when
-                   it scanned every edge slot (and for every other
-                   expansion: pull, lanes, SSSP, BC, programs)
+7     EXPAND       phase-1 expansion branch of single-source top-down:
+                   1 when it pushed the frontier's own out-edges, 2 when
+                   it pulled the unvisited vertices' own in-edges, 0
+                   when it scanned every edge slot (and for every other
+                   expansion: bottom-up, lanes, SSSP, BC, programs)
 ====  ===========  =====================================================
 
 §19 convergence programs (``repro.programs``) share the buffer and
@@ -86,6 +87,10 @@ COL_EXPAND = 7
 
 COL_NAMES = ("level", "words", "pop", "dir", "branch", "shipped", "changed",
              "expand")
+
+EXPAND_SCAN = 0
+EXPAND_PUSH = 1
+EXPAND_PULL = 2
 
 BRANCH_DENSE = 0
 BRANCH_SPARSE = 1
@@ -333,7 +338,10 @@ class TraversalTrace:
             "sparse_levels": int((branch == BRANCH_SPARSE).sum()),
             "fallback_levels": int((branch == BRANCH_FALLBACK).sum()),
             "pull_levels": int((self.data[:, COL_DIR] == 1).sum()),
-            "sparse_push_levels": int((self.data[:, COL_EXPAND] == 1).sum()),
+            "sparse_push_levels": int(
+                (self.data[:, COL_EXPAND] == EXPAND_PUSH).sum()),
+            "sparse_pull_levels": int(
+                (self.data[:, COL_EXPAND] == EXPAND_PULL).sum()),
             "bytes_per_node_total": float(self.level_bytes_per_node().sum()),
         }
         if self.algo == "bc":
@@ -366,10 +374,12 @@ def trace_chrome_doc(trace: TraversalTrace) -> Dict:
     t = 0.0
     branch_names = {BRANCH_DENSE: "dense", BRANCH_SPARSE: "sparse",
                     BRANCH_FALLBACK: "fallback"}
+    expand_names = {EXPAND_SCAN: "", EXPAND_PUSH: " sparse-push",
+                    EXPAND_PULL: " sparse-pull"}
     for row in trace.level_table():
         name = (f"L{row['level']} {branch_names[row['branch']]}"
                 f"{' pull' if row['dir'] else ''}"
-                f"{' sparse-push' if row['expand'] else ''}")
+                f"{expand_names[row['expand']]}")
         if "wall_ms" in row:
             dur = row["wall_ms"] / 1e3
             tracer.add_span(name, t, t + dur, track="traversal",

@@ -239,8 +239,8 @@ def main(argv=None) -> int:
         print(f"trace: {s['levels']} levels ({s['dense_levels']} dense / "
               f"{s['sparse_levels']} sparse / {s['fallback_levels']} "
               f"fallback exchanges; {s['sparse_push_levels']} sparse "
-              f"pushes), {s['bytes_per_node_total']:.0f} sync B/node "
-              f"-> {args.trace}")
+              f"pushes, {s['sparse_pull_levels']} sparse pulls), "
+              f"{s['bytes_per_node_total']:.0f} sync B/node -> {args.trace}")
         return trace.to_dict()
 
     if args.algo == "sssp":

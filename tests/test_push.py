@@ -1,8 +1,10 @@
-"""Single-source top-down's two expansion branches against the oracle.
+"""Single-source top-down's three expansion branches against the oracle.
 
 A level pushes the frontier's own out-edges when no chip's owned frontier
 has more out-edge slots than the capacity ``K`` (``bfs.push_capacity``),
-and scans every in-edge otherwise.  The tests force ``K`` by patching
+else pulls the unvisited vertices' own in-edges when no chip's owned
+unvisited vertices have more in-edge slots than ``K``, and scans every
+in-edge otherwise.  The tests force ``K`` by patching
 ``bfs.push_capacity``: 0 (every level dense), ``emax`` (every level
 sparse), and a level's exact demand and one less (the boundary), besides
 the default.
@@ -54,6 +56,32 @@ def demands(pg, dist):
         ok = d < INF32
         np.add.at(m[:, i], d[ok], runs[i, :c][ok])
     return m
+
+
+def pull_demands(pg, dist):
+    """``int64[levels, P]``: at each level the in-edge slots of every
+    chip's owned vertices not yet visited (farther than the level, or
+    never reached)."""
+    runs = np.diff(pg.in_offsets, axis=1)
+    levels = int(dist[dist < INF32].max()) + 1
+    m = np.zeros((levels, pg.p), np.int64)
+    for i in range(pg.p):
+        s, c = int(pg.v_start[i]), int(pg.v_count[i])
+        d, r = dist[s: s + c], runs[i, :c]
+        for level in range(levels):
+            m[level, i] = r[d > level].sum()
+    return m
+
+
+def expansions(pg, dist, k):
+    """The host model of each level's EXPAND code at capacity ``k``: push
+    (1) when every chip's frontier out-edge slots fit, else pull (2) when
+    every chip's unvisited in-edge slots fit, else scan (0)."""
+    push = demands(pg, dist).max(axis=1) <= k
+    pull = pull_demands(pg, dist).max(axis=1) <= k
+    return np.where(push, flightrec.EXPAND_PUSH,
+                    np.where(pull, flightrec.EXPAND_PULL,
+                             flightrec.EXPAND_SCAN)).astype(np.int32)
 
 
 def capacity_for(kind, pg, dist):
@@ -126,17 +154,18 @@ def test_one_chip_over_capacity_sends_every_chip_dense(monkeypatch):
     dist, rows = _traced(pg, _mesh(4), root, cfg)
     np.testing.assert_array_equal(_norm(dist), _norm(ref))
     expand = rows[:, :, flightrec.COL_EXPAND]
-    assert (expand[:, level] == 0).all()
-    want = (m.max(axis=1) <= k).astype(np.int32)
-    assert (expand == want).all()
+    assert (expand[:, level] != flightrec.EXPAND_PUSH).all()
+    assert (expand == expansions(pg, ref, k)).all()
 
 
 @pytest.mark.parametrize("p", [1, 4])
 def test_flight_recorder_logs_the_branch_each_level_took(p):
     """The EXPAND column is 1 exactly where no chip's owned frontier has
-    more out-edge slots than ``K``: on a small Kronecker graph the first
-    and last levels push and the peak level scans.  ``trace=False`` still
-    stages a program with no trace buffer."""
+    more out-edge slots than ``K``, and 2 where, besides, no chip's owned
+    unvisited vertices have more in-edge slots than ``K``: on a small
+    Kronecker graph the first and last levels push and the peak level
+    scans.  ``trace=False`` still stages a program with no trace
+    buffer."""
     g, root = _graph("kron9")
     ref = bfs.bfs_reference(g, root)
     pg = partition.partition_1d(g, p)
@@ -147,10 +176,11 @@ def test_flight_recorder_logs_the_branch_each_level_took(p):
     m = demands(pg, ref).max(axis=1)
     expand = trace.data[:, flightrec.COL_EXPAND]
     np.testing.assert_array_equal(
-        expand, (m <= bfs.push_capacity(pg.emax)).astype(np.int32))
+        expand, expansions(pg, ref, bfs.push_capacity(pg.emax)))
     assert expand[0] == 1 and expand[-1] == 1
     assert expand[int(np.argmax(m))] == 0
-    assert trace.summary()["sparse_push_levels"] == int(expand.sum())
+    assert trace.summary()["sparse_push_levels"] == int((expand == 1).sum())
+    assert trace.summary()["sparse_pull_levels"] == int((expand == 2).sum())
     assert all(row["expand"] == e
                for row, e in zip(trace.level_table(), expand))
 
@@ -178,6 +208,69 @@ def test_path_graph_pushes_every_level():
     np.testing.assert_array_equal(dist2, dist)
     np.testing.assert_array_equal(timed.data[:, flightrec.COL_EXPAND],
                                   trace.data[:, flightrec.COL_EXPAND])
+
+
+def three_way_capacity(pg, dist):
+    """The smallest ``K`` at which some level pushes, some pulls and some
+    scans, by the host model."""
+    for k in np.unique(np.concatenate([demands(pg, dist).ravel(),
+                                       pull_demands(pg, dist).ravel()])):
+        if set(expansions(pg, dist, int(k))) == {0, 1, 2}:
+            return int(k)
+    raise AssertionError("no capacity gives all three branches")
+
+
+@pytest.mark.parametrize("mode", ["top_down", "direction_optimizing"])
+@pytest.mark.parametrize("p", [1, 4])
+def test_all_three_branches_in_one_traversal(monkeypatch, p, mode):
+    """At a forced ``K`` some levels push, some pull and some scan: the
+    distances equal the oracle, the fused program's EXPAND column is the
+    host model's level for level, and the segmented level step logs the
+    same rows."""
+    g, root = _graph("kron9")
+    ref = bfs.bfs_reference(g, root)
+    pg = partition.partition_1d(g, p)
+    k = three_way_capacity(pg, ref)
+    force_capacity(monkeypatch, k)
+    mesh = _mesh(p)
+    cfg = bfs.BFSConfig(axes=("data",), sync="adaptive", mode=mode)
+    dist, _, _, trace = flightrec.traced_bfs(pg, mesh, root, cfg)
+    np.testing.assert_array_equal(_norm(dist), _norm(ref))
+    expand = trace.data[:, flightrec.COL_EXPAND]
+    want = expansions(pg, ref, k)
+    if mode == "direction_optimizing":  # a bottom-up level logs 0
+        want = np.where(trace.data[:, flightrec.COL_DIR] == 1, 0, want)
+    np.testing.assert_array_equal(expand, want)
+    assert trace.summary()["sparse_pull_levels"] == int((want == 2).sum())
+    dist2, timed = flightrec.timed_bfs_levels(pg, mesh, cfg, root,
+                                              warmup=False)
+    np.testing.assert_array_equal(dist2, dist)
+    np.testing.assert_array_equal(timed.data, trace.data)
+
+
+def test_one_chip_over_pull_capacity_sends_every_chip_dense(monkeypatch):
+    """At P = 4, a level too large to push where one chip's unvisited
+    vertices have more in-edge slots than ``K`` and the others' fit: the
+    one ``pmax`` of the demands sends every chip to the scan, so the
+    chips run the same inner loop and pair up their exchanges, and the
+    distances stay exact."""
+    g, root = _graph("kron9")
+    ref = bfs.bfs_reference(g, root)
+    pg = partition.partition_1d(g, 4)
+    push, pull = demands(pg, ref), np.sort(pull_demands(pg, ref), axis=1)
+    cases = [(int(pull[lv, -2]), lv) for lv in range(pull.shape[0])
+             if pull[lv, -1] > pull[lv, -2]
+             and push[lv].max() > pull[lv, -2]]
+    assert cases, "no level splits the chips' pull demands"
+    k, level = max(cases)
+    force_capacity(monkeypatch, k)
+    assert (pull_demands(pg, ref)[level] > k).sum() == 1
+    cfg = bfs.BFSConfig(axes=("data",), sync="adaptive")
+    dist, rows = _traced(pg, _mesh(4), root, cfg)
+    np.testing.assert_array_equal(_norm(dist), _norm(ref))
+    expand = rows[:, :, flightrec.COL_EXPAND]
+    assert (expand[:, level] == flightrec.EXPAND_SCAN).all()
+    assert (expand == expansions(pg, ref, k)).all()
 
 
 def test_prefix_sum_exact_with_run_lengths_past_24_bits():

@@ -132,19 +132,22 @@ def _loop_ops(text, opcode):
 def _assert_no_scatter_expansion(compiled, shapes):
     """Single-source top-down: the level loop holds no sort at all; its
     dense expansion (the dst-sorted in-edge scan) holds no scatter; the
-    only scatters are the adaptive exchange's sparse receive on P > 1 and
-    the sparse push of the frontier's own out-edges, each of at most
-    ``max(vmax, K)`` updates (the root's ``set_bit`` runs before the
+    only scatters are the adaptive exchange's sparse receive on P > 1,
+    the sparse push of the frontier's own out-edges and the sparse pull's
+    slot assignment over the unvisited vertices' in-edges, each of at
+    most ``max(vmax, K)`` updates (the root's ``set_bit`` runs before the
     loop)."""
     text = compiled.as_text()
     assert _loop_ops(text, "sort") == []
     limit = max(shapes.vmax, bfs.push_capacity(shapes.emax))
     scatters = _loop_ops(text, "scatter")
-    pushed = [s for s in scatters if "traversal.expand/" in s[1]]
-    assert pushed, "the sparse push is missing"
+    for branch in ("/sparse_push/", "/sparse_pull/"):
+        assert [s for s in scatters if "traversal.expand/" in s[1]
+                and branch in s[1]], f"the {branch} scatter is missing"
     for name, where, (_, _, updates) in scatters:
         assert "traversal.exchange/" in where or (
-            "traversal.expand/" in where and "/sparse_push/" in where), (
+            "traversal.expand/" in where and (
+                "/sparse_push/" in where or "/sparse_pull/" in where)), (
             name, where)
         assert updates is not None and updates <= limit, (name, updates)
     dense = [w for _, w, _ in _loop_ops(text, "gather")
